@@ -13,7 +13,7 @@ loop), ``utils`` (profiling + numeric debug guards), ``analysis``
 
 The compute-core exports are LAZY (PEP 562): importing the package must
 not import jax, so the jax-free entry points — ``python -m
-npairloss_tpu staticcheck``, ``watch``, the bench parent, the
+npairloss_tpu staticcheck``, ``watch``, ``chip_smoke.py``'s parent, the
 bench_check gates — run in a venv with no accelerator stack installed
 at all (docs/STATICCHECK.md).  ``from npairloss_tpu import npair_loss``
 works exactly as before; it just pays the jax import at first use

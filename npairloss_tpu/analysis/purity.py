@@ -48,8 +48,8 @@ HEAVY_DEPS = frozenset({
 # table is a finding (opt in HERE, loudly).
 CONTRACT_MODULES: Dict[str, str] = {
     "npairloss_tpu/obs/sinks.py":
-        "bench.py's jax-free parent file-path-loads it to append "
-        "bench records",
+        "jax-free processes file-path-load it to append metric rows "
+        "(tests/test_obs.py pins the standalone import)",
     "npairloss_tpu/obs/fleet/stamp.py":
         "bench_check --fleet-report pre-seeds it for the aggregate "
         "loader",

@@ -298,13 +298,17 @@ def multibatch_loader(
         available = nd.native_available()  # cached; check before file I/O
         # JPEG routes native only when the build linked libjpeg.
         supported = nd.native_suffixes() if available else ()
-        if native == "require" and not available:
-            raise RuntimeError("native data runtime unavailable")
+        if native == "require":
+            # Asked for by name: fail with the loader's own reason,
+            # never fall back to the Python pipeline.
+            nd.require_native()
+            return NativeMultibatchLoader(
+                cfg, transformer, train=train, seed=seed,
+                prefetch=prefetch,
+            )
         try:
-            if available and (
-                native == "require"
-                or _list_file_all_suffixed(cfg.source, supported)
-            ):
+            if available and _list_file_all_suffixed(cfg.source,
+                                                     supported):
                 return NativeMultibatchLoader(
                     cfg, transformer, train=train, seed=seed,
                     prefetch=prefetch,

@@ -3,15 +3,23 @@
 The C++ library is the TPU-side equivalent of the reference's C++
 MultibatchData layer (SURVEY.md §1 L1, §3.5): list-file dataset,
 identity-balanced sampler, JPEG (system libjpeg)/PPM/BMP/NPY decode +
-bilinear resize, and a worker-pool prefetch ring — all off the GIL.  It is compiled on demand
-with g++ (no pip deps); when the toolchain or the library is
-unavailable, callers fall back to the pure-Python pipeline
-(``data.loader``), which has identical contract semantics.
+bilinear resize, and a worker-pool prefetch ring — all off the GIL.
+
+It is compiled on demand with g++ (no pip deps) from the checkout's own
+``native/npair_data.cpp``, and the built library is keyed by a hash of
+that source (``native/build/libnpair_data.<sha>.so``): a leftover
+binary from another source can never stand in for it, and without the
+source there is nothing to vouch for, so nothing is loaded.  When the
+runtime is unavailable, ``native="auto"`` callers use the pure-Python
+pipeline (``data.loader``, identical contract semantics) and say so
+once in the log; ``native="require"`` fails.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import tempfile
@@ -24,14 +32,23 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "npair_data.cpp")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB = os.path.join(_BUILD_DIR, "libnpair_data.so")
+
+log = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_error: Optional[str] = None
 
 
-def _build() -> str:
+def _lib_path() -> str:
+    """The library path for the source AS IT IS ON DISK NOW: its
+    content hash is in the name, so staleness is never a guess."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libnpair_data.{digest}.so")
+
+
+def _build(lib_path: str) -> str:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # Atomic build: compile to a temp name, rename over the target, so
     # concurrent processes never dlopen a half-written .so.
@@ -49,17 +66,15 @@ def _build() -> str:
         subprocess.run(
             base + ["-ljpeg"], check=True, capture_output=True, text=True
         )
-        os.replace(tmp, _LIB)
-        return _LIB
+        os.replace(tmp, lib_path)
+        return lib_path
     except (subprocess.CalledProcessError, FileNotFoundError) as exc:
         stderr = getattr(exc, "stderr", "") or str(exc)
         if "jpeg" not in stderr.lower():
             if os.path.exists(tmp):
                 os.unlink(tmp)
             raise RuntimeError(f"native build failed: {stderr}") from exc
-        import logging
-
-        logging.getLogger(__name__).warning(
+        log.warning(
             "libjpeg link failed (%s); rebuilding native runtime without "
             "JPEG — JPEG datasets will use the Python/PIL path",
             stderr.strip().splitlines()[-1] if stderr.strip() else exc,
@@ -68,8 +83,8 @@ def _build() -> str:
         subprocess.run(
             base + ["-DND_NO_JPEG"], check=True, capture_output=True, text=True
         )
-        os.replace(tmp, _LIB)
-        return _LIB
+        os.replace(tmp, lib_path)
+        return lib_path
     except (subprocess.CalledProcessError, FileNotFoundError) as exc:
         if os.path.exists(tmp):
             os.unlink(tmp)
@@ -85,25 +100,22 @@ def _load() -> ctypes.CDLL:
         if _lib_error is not None:
             raise RuntimeError(_lib_error)
         try:
-            # Rebuild when the source is newer; a prebuilt .so without the
-            # source on disk is used as-is.
-            stale = not os.path.exists(_LIB) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-            )
-            if stale:
-                _build()
+            lib_path = _lib_path()
+            built = not os.path.exists(lib_path)
+            if built:
+                _build(lib_path)
             try:
-                lib = ctypes.CDLL(_LIB)
+                lib = ctypes.CDLL(lib_path)
             except OSError:
                 # A present-but-unloadable .so (wrong arch/glibc): rebuild
                 # from source once rather than caching unavailability.
-                if stale or not os.path.exists(_SRC):
+                if built:
                     raise
-                _build()
-                lib = ctypes.CDLL(_LIB)
+                _build(lib_path)
+                lib = ctypes.CDLL(lib_path)
         except (OSError, RuntimeError) as exc:
             _lib_error = f"native data runtime unavailable: {exc}"
+            log.warning("%s", _lib_error)
             raise RuntimeError(_lib_error) from exc
         lib.nd_last_error.restype = ctypes.c_char_p
         lib.nd_has_jpeg.restype = ctypes.c_int
@@ -148,6 +160,12 @@ def native_available() -> bool:
         return True
     except RuntimeError:
         return False
+
+
+def require_native() -> None:
+    """Raise ``RuntimeError`` (with the build/load failure) unless the
+    runtime is loaded — the ``--native require`` contract."""
+    _load()
 
 
 def native_jpeg_supported() -> bool:

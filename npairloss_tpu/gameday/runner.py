@@ -18,6 +18,11 @@ remediation audits, quality windows, metric rows, the fleet report,
 the drain summary — and hands them to gameday/verdict.py, writing the
 ``npairloss-gameday-v1`` report to ``<out>/gameday.json``.
 
+This is a CPU drill: trainer and server children run CONCURRENTLY and
+every child is pinned to the CPU (``_child_env``).  A chip belongs to
+one process at a time, so this runner must not be pointed at one — the
+chip's checks are ``chip_smoke.py``'s, one process after another.
+
 This module runs the composed system, so unlike the verdict it may
 import numpy and the package freely; everything it feeds the verdict
 is plain dicts/lists.
@@ -244,7 +249,6 @@ def _serve_cmd(out: str, replicas: int) -> List[str]:
         "--snapshot", os.path.join(out, "boot", "m_iter_40.ckpt"),
         "--model", "mlp", "--input-size", "8",
         "--watch-snapshots", os.path.join(out, "snap", "m_"),
-        "--compile-cache", os.path.join(out, "xla_cache"),
         "--top-k", "10", "--buckets", "1", "--deadline-ms", "1",
         "--max-queue", "64", "--replicas", str(replicas),
         "--admission", "slo", "--admission-slos", "serve_p99",
@@ -807,7 +811,6 @@ def _tenant_workspace(out: str, cfg: tg.TrafficConfig,
 def _tenant_serve_cmd(out: str, replicas: int) -> List[str]:
     return _python() + [
         "serve", "--tenant-config", os.path.join(out, "tenants.json"),
-        "--compile-cache", os.path.join(out, "xla_cache"),
         "--top-k", "10", "--buckets", "1", "--deadline-ms", "2",
         "--poll-s", "0.02",
         "--max-queue", "64", "--replicas", str(replicas),

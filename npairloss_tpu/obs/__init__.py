@@ -24,7 +24,7 @@ tied together per run by ``obs.run.RunTelemetry`` (run dir with
 ``manifest.json`` + ``metrics.jsonl`` + ``trace.json``).
 
 ``obs.sinks`` and ``obs.tracing`` are stdlib-only modules; jax-free
-processes (bench.py's parent) load them by file path to avoid this
+processes (the offline gates) load them by file path to avoid this
 package's jax-importing ``__init__``.
 """
 

@@ -54,7 +54,7 @@ from npairloss_tpu.obs.live.slo import (
     SLOEvaluator,
     load_slo_config,
 )
-from npairloss_tpu.obs.live.watchdogs import bench_floor_emb_per_sec, default_watchdogs
+from npairloss_tpu.obs.live.watchdogs import default_watchdogs
 from npairloss_tpu.obs.live.export import prometheus_text, start_http_exporter
 from npairloss_tpu.obs.live.watch import (
     reconcile_remediation,
@@ -75,7 +75,6 @@ __all__ = [
     "SLOEvaluator",
     "SLOSpec",
     "SLOStatus",
-    "bench_floor_emb_per_sec",
     "default_watchdogs",
     "load_alert_log",
     "load_slo_config",

@@ -15,15 +15,9 @@ Stdlib-only, like the whole package.
 
 from __future__ import annotations
 
-import json
-import os
 from typing import List, Optional
 
 from npairloss_tpu.obs.live.slo import SLOSpec
-
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))))
-LAST_GOOD = os.path.join(REPO, "bench_cache", "last_good.json")
 
 
 # -- serve watchdogs ----------------------------------------------------------
@@ -146,11 +140,11 @@ def nonfinite_loss_streak(window_s: float = 120.0) -> SLOSpec:
 
 def train_throughput_floor(floor_emb_per_sec: float,
                            window_s: float = 600.0) -> SLOSpec:
-    """Throughput vs the committed BENCH bar (needs ``--perf-metrics``
-    rows): a multi-day run silently degrading to half its benched
-    emb/s is exactly the regression the post-hoc gate catches a round
-    too late.  Pass :func:`bench_floor_emb_per_sec` (with margin) as
-    the floor — on hardware that never benched, don't arm this."""
+    """Throughput vs a measured bar (needs ``--perf-metrics`` rows): a
+    multi-day run silently degrading to half its benched emb/s is
+    exactly the regression a post-hoc gate catches too late.  The
+    floor is the caller's — a ledger row for THIS hardware, with
+    margin; on hardware that never benched, don't arm this."""
     return SLOSpec(
         name="train_throughput_floor", metric="perf_emb_per_sec",
         op=">=", target=floor_emb_per_sec, window_s=window_s,
@@ -221,24 +215,6 @@ def fleet_straggler(max_step_lag: float = 2.0,
 # -- presets ------------------------------------------------------------------
 
 
-def bench_floor_emb_per_sec(margin: float = 0.5,
-                            last_good_path: str = LAST_GOOD
-                            ) -> Optional[float]:
-    """The committed bench headline (bench_cache/last_good.json) scaled
-    by ``margin`` — the default train-throughput floor.  None when no
-    committed measurement exists (fresh checkout, new hardware):
-    DON'T arm the throughput watchdog on a floor you never measured."""
-    try:
-        with open(last_good_path) as f:
-            payload = json.load(f).get("payload") or {}
-    except (OSError, ValueError):
-        return None
-    value = payload.get("value")
-    if isinstance(value, (int, float)) and value > 0:
-        return float(value) * float(margin)
-    return None
-
-
 def default_watchdogs(kind: str, max_queue: int = 256,
                       bench_floor: Optional[float] = None
                       ) -> List[SLOSpec]:
@@ -249,9 +225,8 @@ def default_watchdogs(kind: str, max_queue: int = 256,
     without shadow rows they simply never see a sample and stay ok).
     ``train``: non-finite streak, snapshot staleness, embedding
     collapse, mining-margin floor, fleet straggler lag, plus the
-    throughput floor when ``bench_floor`` is given (see
-    :func:`bench_floor_emb_per_sec` — never armed implicitly, a CPU box
-    must not page against a TPU bar).
+    throughput floor when ``bench_floor`` is given (never armed
+    implicitly — a CPU box must not page against a TPU bar).
     """
     if kind == "serve":
         return [

@@ -19,10 +19,12 @@ fewer bytes (fusion, bf16, layout).
 
 Peak numbers are public per-chip specs.  HBM/ICI figures are
 coarse (generation-level, not SKU-exact) — the CLASSIFICATION is the
-product here, not a promise of achievable GB/s; ``known=False`` specs
-(CPU, unknown kinds) fall back to the v4 reference roofline so reports
-stay deterministic everywhere, with the fallback flagged in the
-report.  Stdlib-only.
+product here, not a promise of achievable GB/s.  The CPU backend (and
+"no device": offline arithmetic, unit tests) classifies against a
+reference roofline flagged ``known=False`` so reports stay
+deterministic off-chip; an accelerator whose ``device_kind`` is not in
+the table is an ERROR — another chip's peaks would silently mis-plan
+the engine and mis-state every utilization.  Stdlib-only.
 """
 
 from __future__ import annotations
@@ -78,22 +80,28 @@ _BW_SPECS = [
     ("v2", 700.0, 62.0, 12.5),
 ]
 
-# Unknown kinds (CPU, test doubles) classify against the v4 reference
-# roofline — deterministic output everywhere, flagged via known=False.
-DEFAULT_SPEC = ChipSpec("unknown (v4 reference roofline)", 275e12,
+# The CPU backend and "no device" (kind "") classify against this
+# reference roofline (v4's figures) — deterministic output off-chip,
+# flagged via known=False.  Never used for an accelerator.
+DEFAULT_SPEC = ChipSpec("cpu (v4 reference roofline)", 275e12,
                         1228e9, 300e9, 25e9, known=False)
 
 
 def chip_peaks(device_kind: str) -> ChipSpec:
-    """Resolve a device kind to its peak spec (first substring match),
-    or the flagged v4-reference fallback."""
+    """Resolve a device kind to its peak spec (first substring match).
+    ``"cpu"``/``""`` get the flagged reference spec; any other kind
+    missing from the table raises ``ValueError``."""
     kind = (device_kind or "").lower()
     flops = {k: f for k, f in PEAK_FLOPS}
     for key, hbm, ici, dcn in _BW_SPECS:
         if key in kind and key in flops:
             return ChipSpec(device_kind, flops[key], hbm * 1e9,
                             ici * 1e9, dcn * 1e9)
-    return DEFAULT_SPEC
+    if kind in ("", "cpu"):
+        return DEFAULT_SPEC
+    raise ValueError(
+        f"no peak spec for device kind {device_kind!r}: add it to "
+        "obs.perf.costs.PEAK_FLOPS and obs.perf.roofline._BW_SPECS")
 
 
 def interconnect_peak(spec: ChipSpec, link: str) -> float:

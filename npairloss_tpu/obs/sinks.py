@@ -10,10 +10,9 @@ implementations.  Every record is a flat dict; the stamping of the
 required ``{run_id, step, wall_time, phase}`` envelope is
 ``obs.run.RunTelemetry``'s job, so sinks stay dumb and composable.
 
-IMPORTANT: this module must stay importable WITHOUT jax (stdlib only).
-``bench.py``'s parent process loads it by file path to append bench
-records — that process is jax-free by design (a hung backend import
-must never kill the bench orchestration).
+IMPORTANT: this module must stay importable WITHOUT jax (stdlib only):
+jax-free processes load it by file path (staticcheck's purity pass
+holds the proof).
 """
 
 from __future__ import annotations

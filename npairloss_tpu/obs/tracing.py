@@ -14,7 +14,7 @@ instant events for point-in-time markers.  ``write()`` emits the
 ``{"traceEvents": [...]}`` JSON Perfetto accepts.
 
 Stdlib only — no jax import (the tracer must work in jax-free
-processes like bench.py's parent).
+processes: the offline gates, a chip driver's parent).
 """
 
 from __future__ import annotations

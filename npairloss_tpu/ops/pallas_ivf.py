@@ -37,8 +37,9 @@ ragged tails, empty/padded clusters, and ``probes > n_clusters`` —
 exercised in interpret mode on CPU, so tier-1 proves the kernel
 without hardware.
 
-Like every Pallas module here: interpret mode off-TPU by default, so
-the same code path runs under CPU tests and Mosaic-compiles on TPU.
+Like every Pallas module here: interpret mode off-TPU by default
+(``ops.pallas_mode``), so the same code path runs under CPU tests and
+Mosaic-compiles on TPU.
 """
 
 from __future__ import annotations
@@ -51,6 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from npairloss_tpu.ops.pallas_mode import default_interpret
 
 # The probe-impl registry — the single source of truth the CLI flag
 # vocabulary (cli._PROBE_IMPL_CHOICES), bench rows, and tests enumerate
@@ -68,17 +71,16 @@ PROBE_IMPLS = {
 _NEG_FILL = float(-np.finfo(np.float32).max)
 
 _LANES = 128
-# Min sublane tile per scoring dtype (pallas guide: fp32 (8,128),
-# bf16 (16,128), int8 (32,128)); ``serve.ivf`` pads every packed slab's
-# cap to the lcm (32) at placement time so the per-dispatch re-pad
+# ``cap`` is the LANE axis of the kernel's (1, cap) score row and row-id
+# block, so it is lane-aligned (which also covers every scoring dtype's
+# sublane tile: fp32 8, bf16 16, int8 32).  ``serve.ivf`` pads every
+# packed slab's cap to it at placement time, so the per-dispatch re-pad
 # below is a no-op at production geometry.
-_SUBLANES = {"fp32": 8, "bf16": 16, "int8": 32}
-CAP_ALIGN = 32
-
-
-def _default_interpret() -> bool:
-    """Interpret everywhere but real TPU (the pallas_stem idiom)."""
-    return jax.default_backend() != "tpu"
+CAP_ALIGN = _LANES
+# Query rows fed to the MXU per step: the one real row broadcast to a
+# full sublane tile (16 covers the bf16 tile too), so the scoring gemm
+# never has a sub-tile M.
+_Q_ROWS = 16
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -114,7 +116,10 @@ def _probe_kernel(lids_ref, oks_ref, *rest, c: int, kl: int,
 
     ``rest`` is (scale_ref?, q_ref, tile_ref, rows_ref, out_s_ref,
     out_r_ref): the int8 per-cluster scale table travels as a third
-    scalar-prefetch operand; fp32/bf16 omit it.
+    scalar-prefetch operand; fp32/bf16 omit it.  Per-query operands
+    carry a unit middle axis — (B, 1, W) arrays in (1, 1, W) blocks —
+    so each block's last two dims equal the array's (Mosaic's block
+    rule) while the grid still walks one query row per step.
     """
     if scoring == "int8":
         scale_ref, q_ref, tile_ref, rows_ref, out_s_ref, out_r_ref = rest
@@ -126,13 +131,13 @@ def _probe_kernel(lids_ref, oks_ref, *rest, c: int, kl: int,
 
     @pl.when(j == 0)
     def _():
-        out_s_ref[:] = jnp.full((1, kl_pad), neg, jnp.float32)
-        out_r_ref[:] = jnp.zeros((1, kl_pad), jnp.int32)
+        out_s_ref[0] = jnp.full((1, kl_pad), neg, jnp.float32)
+        out_r_ref[0] = jnp.zeros((1, kl_pad), jnp.int32)
 
     flat = b * c + j
     ok = oks_ref[flat] > 0
     g = tile_ref[0]    # (cap_pad, d_pad) in the scoring dtype
-    qv = q_ref[:]      # (1, d_pad) float32
+    qv = jnp.broadcast_to(q_ref[0], (_Q_ROWS, q_ref.shape[2]))
     # The scoring gemm — same arithmetic as the scan baseline's einsum,
     # fp32-accumulated on the MXU; int8 dequants INSIDE the kernel:
     # bf16-cast gemm (+-127 is bf16-exact) x the per-cluster scale
@@ -151,7 +156,8 @@ def _probe_kernel(lids_ref, oks_ref, *rest, c: int, kl: int,
         )
         if scale_ref is not None:
             sims = sims * scale_ref[lids_ref[flat]]
-    rvals = rows_ref[:]  # (1, cap_pad) int32, -1 = pad
+    sims = sims[0:1]     # every broadcast row is the same query
+    rvals = rows_ref[0]  # (1, cap_pad) int32, -1 = pad
     vals = jnp.where((rvals >= 0) & ok, sims, neg)
     # Merge candidates in [running buffer, tile-ascending] order and
     # extract the kl largest by repeated (max, remove-ONE-occurrence)
@@ -159,28 +165,28 @@ def _probe_kernel(lids_ref, oks_ref, *rest, c: int, kl: int,
     # ids.  Lowest-index-wins among equals keeps ``lax.top_k``'s
     # tie-break: the running best beats an equal tile candidate and
     # lower cluster positions beat higher, exactly like the baseline's
-    # best-first concat.
-    work_v = jnp.concatenate([out_s_ref[:], vals], axis=1)
-    work_r = jnp.concatenate([out_r_ref[:], rvals], axis=1)
+    # best-first concat.  Both pieces are lane multiples, so the concat
+    # is tile-aligned.
+    work_v = jnp.concatenate([out_s_ref[0], vals], axis=1)
+    work_r = jnp.concatenate([out_r_ref[0], rvals], axis=1)
     w = kl_pad + cap_pad
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, kl_pad), 1)
     imin = jnp.int32(np.iinfo(np.int32).min)
-    new_s, new_r = [], []
-    for _t in range(kl):
+    new_s = jnp.full((1, kl_pad), neg, jnp.float32)
+    new_r = jnp.zeros((1, kl_pad), jnp.int32)
+    for t in range(kl):
         mx = work_v.max(axis=1, keepdims=True)
         mi = jnp.where(work_v == mx, iota, jnp.int32(w)).min(
             axis=1, keepdims=True)
         rr = jnp.where(iota == mi, work_r, imin).max(
             axis=1, keepdims=True)
         work_v = jnp.where(iota == mi, neg, work_v)
-        new_s.append(mx)
-        new_r.append(rr)
-    pad = kl_pad - kl
-    if pad:
-        new_s.append(jnp.full((1, pad), neg))
-        new_r.append(jnp.zeros((1, pad), jnp.int32))
-    out_s_ref[:] = jnp.concatenate(new_s, axis=1)
-    out_r_ref[:] = jnp.concatenate(new_r, axis=1)
+        # Slot t by select, not by a 1-lane concat Mosaic cannot tile.
+        new_s = jnp.where(slot == t, mx, new_s)
+        new_r = jnp.where(slot == t, rr, new_r)
+    out_s_ref[0] = new_s
+    out_r_ref[0] = new_r
 
 
 def fused_probe_topk(q, packed, rows, centroids, cvalid, scale=None, *,
@@ -198,7 +204,7 @@ def fused_probe_topk(q, packed, rows, centroids, cvalid, scale=None, *,
     kl = min(int(k), c * cap)
     bq = q.shape[0]
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
 
     with jax.named_scope("serve/probe"):
         # Stage 1 — identical XLA ops to the scan baseline, so the
@@ -218,8 +224,7 @@ def fused_probe_topk(q, packed, rows, centroids, cvalid, scale=None, *,
     # production geometry (D a lane multiple, cap pre-padded to
     # CAP_ALIGN by IVFIndex._place) every pad below is width zero — no
     # per-dispatch copy of the slab.
-    sub = _SUBLANES[scoring]
-    cap_pad = _round_up(cap, sub)
+    cap_pad = _round_up(cap, CAP_ALIGN)
     d_pad = _round_up(d, _LANES)
     kl_pad = _round_up(kl, _LANES)
     if cap_pad != cap or d_pad != d:
@@ -238,33 +243,32 @@ def fused_probe_topk(q, packed, rows, centroids, cvalid, scale=None, *,
     # Index maps see the scalar-prefetch refs after the grid indices:
     # the probed cluster id IS the block index — the in-kernel gather.
     tile_idx = (lambda b, j, lids_r, *_p: (lids_r[b * c + j], 0, 0))
-    rows_idx = (lambda b, j, lids_r, *_p: (lids_r[b * c + j], 0))
-    q_idx = (lambda b, j, *_p: (b, 0))
+    q_idx = (lambda b, j, *_p: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
         grid=(bq, c),  # b outer, j inner: outputs revisit consecutively
         in_specs=[
-            pl.BlockSpec((1, d_pad), q_idx),
+            pl.BlockSpec((1, 1, d_pad), q_idx),
             pl.BlockSpec((1, cap_pad, d_pad), tile_idx),
-            pl.BlockSpec((1, cap_pad), rows_idx),
+            pl.BlockSpec((1, 1, cap_pad), tile_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, kl_pad), q_idx),
-            pl.BlockSpec((1, kl_pad), q_idx),
+            pl.BlockSpec((1, 1, kl_pad), q_idx),
+            pl.BlockSpec((1, 1, kl_pad), q_idx),
         ],
     )
     args = [lids.reshape(-1), owned.astype(jnp.int32).reshape(-1)]
     if with_scale:
         args.append(scale.astype(jnp.float32))
-    args += [qp, packed, rows]
+    args += [qp[:, None, :], packed, rows[:, None, :]]
     with jax.named_scope("serve/probe_fused"):
         s, r = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((bq, kl_pad), jnp.float32),
-                jax.ShapeDtypeStruct((bq, kl_pad), jnp.int32),
+                jax.ShapeDtypeStruct((bq, 1, kl_pad), jnp.float32),
+                jax.ShapeDtypeStruct((bq, 1, kl_pad), jnp.int32),
             ],
             interpret=interpret,
         )(*args)
-    return s[:, :kl], r[:, :kl]
+    return s[:, 0, :kl], r[:, 0, :kl]

@@ -91,6 +91,7 @@ from npairloss_tpu.ops.npair_loss import (
     selection_predicates,
     topk_relative_threshold,
 )
+from npairloss_tpu.ops.pallas_mode import default_interpret
 from npairloss_tpu.ops.rank_select import (
     NUM_DIGITS,
     RADIX_BINS,
@@ -105,15 +106,17 @@ from npairloss_tpu.ops.rank_select import (
 
 _RELATIVE = (MiningMethod.RELATIVE_HARD, MiningMethod.RELATIVE_EASY)
 
+# Mosaic's default scoped-VMEM budget is 16 MiB; the flagship stats
+# sweep at 512x512 tiles (sim tile out + K-slot top-k + digit-0
+# histogram, all double-buffered) needs 16.3 MiB.  v5e has 128 MiB of
+# VMEM per core, so every sweep gets a 32 MiB budget.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 << 20)
+
 
 def blockwise_supported(cfg: NPairLossConfig) -> bool:
     """Every mining configuration streams (RELATIVE_* via radix select),
     matching the ring path's support matrix."""
     return True
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _canon_labels(labels: jax.Array) -> jax.Array:
@@ -615,6 +618,7 @@ def _run_stats(feats_p, labels_p, pool_p, pool_labels_p, scal,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(scal, feats_p, _row(labels_p), pool_p, _row(pool_labels_p))
     flat = [o[0, :] for o in out[:5]]
     sims_cache = out[-1] if emit_sims else None
@@ -651,6 +655,7 @@ def _run_hist(feats_p, labels_p, pool_p, pool_labels_p, scal,
             jax.ShapeDtypeStruct((RADIX_BINS, n_p), jnp.int32)
         ] * k,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     return [o.T for o in out]
 
@@ -676,6 +681,7 @@ def _run_loss(feats_p, labels_p, pool_p, pool_labels_p, scal,
         out_specs=[_qvec(bn, 0)] * 4,
         out_shape=[jax.ShapeDtypeStruct((1, feats_p.shape[0]), jnp.float32)] * 4,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     return tuple(o[0, :] for o in out)
 
@@ -717,6 +723,7 @@ def _run_bwd(feats_p, labels_p, pool_p, pool_labels_p, scal,
         out_specs=_qblock((bn, dim), 0),
         out_shape=jax.ShapeDtypeStruct((feats_p.shape[0], dim), jnp.float32),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(*gq_args)
     gdb = pl.pallas_call(
         _make_gdb_kernel(cfg, cached),
@@ -725,6 +732,7 @@ def _run_bwd(feats_p, labels_p, pool_p, pool_labels_p, scal,
         out_specs=_pblock((bm, dim), 0),
         out_shape=jax.ShapeDtypeStruct((pool_p.shape[0], dim), jnp.float32),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(*gdb_args)
     return gq, gdb
 
@@ -1043,7 +1051,7 @@ def blockwise_npair_loss_with_aux(
     throughput mode, not a parity mode).
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     n = features.shape[0]
     bm = int(min(block_size, max(n, 1)))
     bn = int(min(q_block_size or block_size, max(n, 1)))

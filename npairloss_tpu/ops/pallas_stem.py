@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
 
+from npairloss_tpu.ops.pallas_mode import default_interpret
+
 # fp32 bytes of the LRN denominator tensor below which the forward
 # caches it for the backward (the pallas_npair SIM_CACHE_AUTO_BYTES
 # pattern at stem-activation scale: the batch-120 pool1 site is ~385 MB
@@ -54,10 +56,6 @@ LRN_CACHE_AUTO_BYTES = 2 << 30
 
 _BLOCK_ROWS = 256
 _LANES = 128
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def resolve_lrn_cache_auto(nbytes: int, cache: Optional[bool]) -> bool:
@@ -291,7 +289,7 @@ def fused_lrn(
     ops/pallas_npair sim-cache pattern); ``interpret`` forces/forbids
     Pallas interpreter mode (None = auto: interpret off-TPU)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     # Budget the cache at the tensor the cached kernel ACTUALLY writes:
     # the padded (rpad, cpad) fp32 denominator (lane padding alone is
     # 2x at a C=64 site), not the logical x.size.
@@ -363,7 +361,7 @@ def fused_bias_relu(x: jax.Array, bias: jax.Array,
     """Conv epilogue: ``relu(x + bias)`` (bias broadcast over the last
     axis) in one fused VMEM pass, with an XLA backward."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     return _fused_bias_relu(x, bias, _EpiParams(bool(interpret)))
 
 
@@ -380,27 +378,47 @@ class _PoolParams(NamedTuple):
     interpret: bool
 
 
-def _bias_relu_pool_kernel(x_ref, b_ref, o_ref, *, p: _PoolParams,
-                           geom):
-    ho, ph_lo, ph_hi, wo, pw_lo, pw_hi = geom
-    y = jnp.maximum(
-        x_ref[:].astype(jnp.float32)
-        + b_ref[:].astype(jnp.float32).reshape(1, 1, 1, -1),
-        0.0,
-    )
-    # SAME max-pool via static shifted strided slices.  Zero fill is
-    # exact here: post-ReLU values are >= 0, so a zero pad can never
-    # beat a real in-window value (and a window is never all-padding
-    # under SAME), matching reduce_window's -inf-init semantics.
-    yp = jnp.pad(y, ((0, 0), (ph_lo, ph_hi), (pw_lo, pw_hi), (0, 0)))
+# Pool-kernel padding: below every real activation in any float dtype
+# the stem runs (bf16 and up), so a padded cell never wins a window.
+_POOL_PAD = -1e30
+_POOL_BLOCK_ROWS = 8
+
+
+def _bias_relu_pool_kernel(x_ref, halo_ref, b_ref, o_ref, *,
+                           p: _PoolParams):
+    """One (image, row-tile) step over the phase-split view.
+
+    The wrapper reshapes the padded activation to (N, HB, s, WB, s*C):
+    input row ``s*i + ph`` is block row ``i`` phase ``ph``; input
+    column ``s*j + pw`` is sublane ``j``, lane group ``pw``.  Window
+    offset (di, dj) of output (i, j) is then block row ``i + di//s``
+    phase ``di%s``, sublane ``j + dj//s`` lane group ``dj%s`` — every
+    access a contiguous static slice of the block: no strided value
+    gather, and the one-row overhang (``di//s == 1``) comes from the
+    single-row ``halo_ref`` block that follows this tile.
+
+    bias + ReLU is monotone, so it commutes with max: pool the raw
+    tile, then apply the epilogue once to the pooled values —
+    bit-identical to relu(x + b) followed by reduce_window.
+    """
     s = p.stride
+    th, wo, c = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
+
+    def tap(ref, ph, dj):
+        return ref[0, :, ph, pl.ds(dj // s, wo),
+                   pl.ds((dj % s) * c, c)].astype(jnp.float32)
+
     m = None
     for di in range(p.window):
         for dj in range(p.window):
-            tile = yp[:, di:di + (ho - 1) * s + 1:s,
-                      dj:dj + (wo - 1) * s + 1:s, :]
-            m = tile if m is None else jnp.maximum(m, tile)
-    o_ref[:] = m.astype(o_ref.dtype)
+            t = tap(x_ref, di % s, dj)
+            if di // s:  # one block row down: shift up, halo row last
+                below = tap(halo_ref, di % s, dj)
+                t = (jnp.concatenate([t[1:], below], axis=0)
+                     if th > 1 else below)
+            m = t if m is None else jnp.maximum(m, t)
+    b = b_ref[:].astype(jnp.float32).reshape(1, 1, c)
+    o_ref[0] = jnp.maximum(m + b, 0.0).astype(o_ref.dtype)
 
 
 def _reference_bias_relu_pool(x, bias, window: int, stride: int):
@@ -417,25 +435,44 @@ def _reference_bias_relu_pool(x, bias, window: int, stride: int):
 def _fused_bias_relu_pool(x: jax.Array, bias: jax.Array,
                           p: _PoolParams) -> jax.Array:
     n, h, w, c = x.shape
-    ho, ph_lo, ph_hi = _same_pads(h, p.window, p.stride)
-    wo, pw_lo, pw_hi = _same_pads(w, p.window, p.stride)
+    s = p.stride
+    over = (p.window - 1) // s  # block rows/cols a window overhangs
+    if over > 1:
+        raise ValueError(
+            f"fused pool needs window <= 2*stride, got window="
+            f"{p.window} stride={s}")
+    ho, ph_lo, _ = _same_pads(h, p.window, s)
+    wo, pw_lo, _ = _same_pads(w, p.window, s)
+    hb, wb = ho + over, wo + over
     cpad = _round_up(c, _LANES)
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cpad - c)))
+    # ONE pad: SAME's leading pad, trailing fill to whole s-blocks (+
+    # the overhang block), and the lane pad.
+    xp = jnp.pad(
+        x,
+        ((0, 0), (ph_lo, hb * s - h - ph_lo),
+         (pw_lo, wb * s - w - pw_lo), (0, cpad - c)),
+        constant_values=_POOL_PAD,
+    ).reshape(n, hb, s, wb, s * cpad)
     b2 = _pad2d(bias.reshape(1, c), 1, cpad)
+    th = max(t for t in range(1, _POOL_BLOCK_ROWS + 1) if ho % t == 0)
     out = pl.pallas_call(
-        functools.partial(
-            _bias_relu_pool_kernel, p=p,
-            geom=(ho, ph_lo, ph_hi, wo, pw_lo, pw_hi),
-        ),
-        grid=(n,),
+        functools.partial(_bias_relu_pool_kernel, p=p),
+        grid=(n, ho // th),
         in_specs=[
-            pl.BlockSpec((1, h, w, cpad), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, cpad), lambda i: (0, 0)),
+            pl.BlockSpec((1, th, s, wb, s * cpad),
+                         lambda i, t: (i, t, 0, 0, 0)),
+            # The row below the tile (block size 1: the index IS the
+            # row); the overhang block keeps it in range on the last
+            # tile, and with no overhang it is loaded but unused.
+            pl.BlockSpec((1, 1, s, wb, s * cpad),
+                         lambda i, t: (i, (t + 1) * th * over, 0, 0, 0)),
+            pl.BlockSpec((1, cpad), lambda i, t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, ho, wo, cpad), lambda i: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, th, wo, cpad),
+                               lambda i, t: (i, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, cpad), x.dtype),
         interpret=p.interpret,
-    )(xp, b2)
+    )(xp, xp, b2)
     return out[..., :c]
 
 
@@ -473,6 +510,6 @@ def fused_bias_relu_pool(
     NHWC) in one fused pass — the pre-pool activation never leaves
     VMEM.  Backward recomputes through the XLA reference (remat-style)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     return _fused_bias_relu_pool(
         x, bias, _PoolParams(int(window), int(stride), bool(interpret)))

@@ -1,7 +1,8 @@
 """Distribution: multi-process runtime, device-mesh plumbing +
 ring-blockwise negative pooling."""
 
-from npairloss_tpu.parallel._compat import shard_map
+from jax import shard_map
+
 from npairloss_tpu.parallel.distributed import (
     initialize_distributed,
     process_local_batch,

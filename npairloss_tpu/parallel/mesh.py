@@ -19,7 +19,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from npairloss_tpu.ops.npair_loss import NPairLossConfig, npair_loss_with_aux
-from npairloss_tpu.parallel._compat import shard_map
 
 DEFAULT_AXIS = "dp"
 
@@ -125,7 +124,7 @@ def sharded_npair_loss_fn(
         stack = lambda x: jnp.asarray(x)[None]
         return stack(loss), jax.tree_util.tree_map(stack, aux)
 
-    return shard_map(
+    return jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),

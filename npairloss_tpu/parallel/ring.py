@@ -66,7 +66,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from npairloss_tpu.parallel._compat import axis_size, pvary
 from npairloss_tpu.ops.npair_loss import (
     FLT_MAX,
     SIM_CACHE_AUTO_BYTES,
@@ -143,7 +142,7 @@ def _pvary(tree, axis_name: str):
     """Mark fresh (replicated) carry values as device-varying so the scan
     carry type stays stable under shard_map's manual-axes tracking."""
     return jax.tree_util.tree_map(
-        lambda x: pvary(x, (axis_name,)), tree
+        lambda x: jax.lax.pcast(x, (axis_name,), to="varying"), tree
     )
 
 
@@ -152,7 +151,7 @@ def _ring_scan(axis_name: str, body, carry, rotating):
     ppermuting ``rotating`` one hop forward between steps.  Shard r
     therefore sees block (r - step) mod G at step ``step``; after G hops
     every rotating value is back at its owner."""
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % g) for i in range(g)]
     carry = _pvary(carry, axis_name)
 
@@ -210,7 +209,7 @@ def _stats_pass(
     cache instead of recomputing tiles — and the selection/loss passes
     then need no ppermute at all."""
     n_local = feats.shape[0]
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     neg = jnp.float32(-FLT_MAX)
     pos = jnp.float32(FLT_MAX)
     zero_prefix = jnp.zeros((n_local,), jnp.uint32)
@@ -391,7 +390,7 @@ def _ring_thresholds(
 
         def fast(_):
             n_local = feats.shape[0]
-            g = axis_size(axis_name)
+            g = jax.lax.axis_size(axis_name)
             p = topk_relative_threshold(
                 stats["topk_same"], stats["count_same"], cfg.identsn,
                 cfg.ap_mining_region,
@@ -422,7 +421,7 @@ def _ring_radix_thresholds(
         return pos_thr, neg_thr
 
     n_local = feats.shape[0]
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
 
     def prep_hist(side, hist):
         """Global-region sides rank over the whole block: sum the
@@ -527,7 +526,7 @@ def _backward_pass(
     cache=None,
 ):
     n_local, dim = feats.shape
-    num_shards = axis_size(axis_name)
+    num_shards = jax.lax.axis_size(axis_name)
 
     def weight_tile(sims, same, diff):
         sel = selection_mask(sims, same, diff, pos_thr, neg_thr, cfg)
@@ -564,7 +563,8 @@ def _backward_pass(
         "grad_db": jnp.zeros((n_local, dim), jnp.float32),
     }
 
-    rotating["grad_db"] = pvary(rotating["grad_db"], (axis_name,))
+    rotating["grad_db"] = jax.lax.pcast(
+        rotating["grad_db"], (axis_name,), to="varying")
 
     def body(c, rot, step):
         # The block still has to rotate (its feats feed the two gemms and
@@ -663,7 +663,7 @@ def _ring_fwd_traced(features, labels, cfg, axis_name, top_ks, sim_cache,
     # Recall@k from the streamed top-(k+1) lists.  Threshold = the
     # descending-sorted value at index min(k, size-1) over the exp'd row
     # (cu:190); exp is monotone, so raw-sim comparison is equivalent.
-    n_total_minus1 = n_local * axis_size(axis_name) - 1
+    n_total_minus1 = n_local * jax.lax.axis_size(axis_name) - 1
     metrics: Dict[str, jax.Array] = {}
     for k in top_ks:
         thr_idx = jnp.minimum(k, n_total_minus1 - 1)
@@ -786,7 +786,7 @@ def ring_npair_loss_and_metrics(
     """
     _check_cfg(cfg)
     if sim_cache is None:
-        g = axis_size(axis_name)
+        g = jax.lax.axis_size(axis_name)
         n = features.shape[0]
         sim_cache = resolve_sim_cache_auto(g * n * n * 4, "ring")
     pos_topk = 8 if pos_topk is None else int(pos_topk)

@@ -11,14 +11,15 @@ dispatch pipeline.  This package removes the steady-state host taxes:
     loader batches onto the mesh with the step's input sharding ahead of
     need, so the jitted step consumes already-resident, donated buffers;
   * :class:`DispatchController` — a semaphore on in-flight dispatched
-    steps, so async dispatch cannot queue unboundedly against a backend
-    that wedges under pressure;
+    steps, so async dispatch cannot queue unboundedly (every queued
+    step pins its donated inputs) when the device falls behind;
   * :class:`MetricWindow` — a device-side metric ring written inside the
     jitted step (plus an in-graph consecutive-non-finite loss counter),
     read back by the host only at display/eval/snapshot window
     boundaries;
-  * :func:`enable_compile_cache` — the persistent XLA compilation cache,
-    so no process recompiles a program another process already compiled;
+  * :func:`enable_compile_cache` — the persistent XLA compilation cache
+    at ``JAX_COMPILATION_CACHE_DIR`` or ``<repo>/.jax_cache/``, so no
+    process recompiles a program another process already compiled;
   * :class:`HostSyncMonitor` — a counting ``device_put``/``device_get``
     shim that proves (or enforces) the no-mid-window-host-sync contract.
 
@@ -28,8 +29,9 @@ bit-identical to the synchronous one (tests/test_pipeline.py).
 """
 
 from npairloss_tpu.pipeline.compile_cache import (
+    CacheCounter,
+    cache_entries,
     compile_cache_dir,
-    disable_compile_cache,
     enable_compile_cache,
 )
 from npairloss_tpu.pipeline.controller import DispatchController
@@ -45,14 +47,15 @@ from npairloss_tpu.pipeline.syncguard import (
 from npairloss_tpu.pipeline.window import MetricWindow
 
 __all__ = [
+    "CacheCounter",
     "DevicePrefetcher",
     "DispatchController",
     "HostSyncMonitor",
     "MetricWindow",
     "PrefetchStageError",
     "SyncGuardViolation",
+    "cache_entries",
     "compile_cache_dir",
-    "disable_compile_cache",
     "enable_compile_cache",
     "monitor_from_env",
 ]

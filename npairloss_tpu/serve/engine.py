@@ -55,7 +55,6 @@ from npairloss_tpu.ops.pallas_ivf import (
     fused_probe_topk,
     resolve_probe_impl,
 )
-from npairloss_tpu.parallel._compat import REP_CHECK_OFF, shard_map
 from npairloss_tpu.resilience import failpoints
 from npairloss_tpu.serve.index import GalleryIndex, l2_normalize_rows
 from npairloss_tpu.serve.ivf import SCORINGS, IVFIndex
@@ -443,11 +442,17 @@ class QueryEngine:
                 offset = jax.lax.axis_index(axis) * shard_n
                 return s[None], (r + offset)[None]
 
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 per_shard,
                 mesh=mesh,
                 in_specs=(P(), P(axis), P(axis), P(axis)),
                 out_specs=(P(axis), P(axis)),
+                # Every output is P(axis); the per-shard scan starts
+                # from a replicated (-inf, 0) carry that turns varying
+                # on the first block, which the varying-axes checker
+                # rejects as a carry type change, and it has no rule
+                # for the fused probe's pallas_call either.
+                check_vma=False,
             )
 
             def topk(q, emb, labels, valid):
@@ -505,13 +510,11 @@ class QueryEngine:
             specs = [P(), P(axis), P(axis), P(), P()]
             if with_scale:
                 specs.append(P(axis))
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 per_shard, mesh=mesh,
                 in_specs=tuple(specs),
                 out_specs=(P(axis), P(axis)),
-                # The replication checker has no pallas_call rule; the
-                # fused kernel's outputs are all P(axis)-varying anyway.
-                **(REP_CHECK_OFF if self.probe_impl == "fused" else {}),
+                check_vma=False,
             )
 
             def topk(q, packed, rows, cents, cvalid, scale=None):

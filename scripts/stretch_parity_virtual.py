@@ -78,7 +78,7 @@ def main() -> int:
 
     from npairloss_tpu import REFERENCE_CONFIG
     from npairloss_tpu.ops.npair_loss import npair_loss
-    from npairloss_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from npairloss_tpu.parallel.mesh import data_parallel_mesh
     from npairloss_tpu.parallel.ring import ring_npair_loss_and_metrics
 

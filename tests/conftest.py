@@ -8,6 +8,10 @@ SURVEY.md §4 ("Distributed without a cluster").  Must run before jax imports.
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+# train/serve/index turn the persistent compile cache on by default;
+# the suite keeps it off (JAX's own switch, inherited by CLI children)
+# except where a test turns it on to test it.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -19,6 +23,7 @@ import jax
 # jax may already be imported (e.g. by the jaxtyping pytest plugin) with
 # JAX_PLATFORMS captured from the shell env — override via config too.
 jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np
 import pytest

@@ -199,16 +199,19 @@ def test_cli_time_command(capsys):
     as one JSON record."""
     import json
 
-    rc = main([
-        "time", "--net", "examples/tiny_net.prototxt", "--model", "mlp",
-        "--iterations", "2",
-    ])
+    argv = ["time", "--net", "examples/tiny_net.prototxt", "--model",
+            "mlp", "--iterations", "2"]
+    # A measuring command refuses a CPU it was not pointed at by name.
+    assert main(argv) == 2
+    assert capsys.readouterr().out == ""
+    rc = main(["--platform", "cpu", *argv])
     assert rc == 0
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     for key in ("trunk_forward_ms", "forward_ms", "loss_forward_ms",
                 "forward_backward_ms", "backward_ms", "emb_per_sec"):
         assert key in rec, key
         assert rec[key] >= 0
+    assert rec["device"].startswith("cpu:") and "fetch_floor_ms" not in rec
     assert rec["batch"] == 16  # tiny_net.prototxt: 8 ids x 2 imgs
     assert rec["iterations"] == 2
 
@@ -227,6 +230,7 @@ def test_cli_time_forward_only_engines(capsys):
         ("blockwise", [], 1),
     ):
         rc = main([
+            "--platform", "cpu",
             "time", "--net", "examples/tiny_net.prototxt", "--model",
             "mlp", "--iterations", "2", "--forward-only",
             "--engine", engine, *extra,

@@ -138,3 +138,18 @@ def test_rank_blocks_ordered_like_mpi_allgather(mesh):
     total = np.asarray(jax.jit(fn)(*shard_batch(mesh, (gf, gl))))
     for r in range(G):
         np.testing.assert_array_equal(total[r], gl)
+
+
+def test_parallel_imports_clean_under_deprecation_errors():
+    """The package speaks the installed JAX: importing it with
+    DeprecationWarning promoted to an error (a fresh interpreter, so
+    nothing is cached) must succeed — no jax.lax.pvary, no
+    experimental.shard_map."""
+    import subprocess
+    import sys
+
+    proc = subprocess.run(
+        [sys.executable, "-W", "error::DeprecationWarning", "-c",
+         "import npairloss_tpu.parallel, npairloss_tpu.parallel.ring"],
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -373,7 +373,7 @@ def test_interconnect_peak_specs():
     assert interconnect_peak(spec, "dcn") == 25e9
     with pytest.raises(ValueError):
         interconnect_peak(spec, "pcie")
-    # Unknown kinds keep the flagged fallback with a DCN column too.
+    # The CPU reference spec is flagged and has a DCN column too.
     fb = chip_peaks("cpu")
     assert not fb.known and fb.dcn_bytes_per_s > 0
 

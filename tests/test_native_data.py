@@ -400,3 +400,41 @@ def test_worker_error_surfaces(tmp_path, rng):
         for _ in range(50):
             next(pf)
     pf.close()
+
+
+def test_stale_library_never_stands_in(tmp_path, monkeypatch):
+    """The built library is keyed by a hash of the source on disk: a
+    planted leftover .so under any other name (the old fixed name, an
+    older hash) is never opened, and editing the source moves the key."""
+    src = tmp_path / "npair_data.cpp"
+    build = tmp_path / "build"
+    build.mkdir()
+    src.write_bytes(open(nd._SRC, "rb").read())
+    monkeypatch.setattr(nd, "_SRC", str(src))
+    monkeypatch.setattr(nd, "_BUILD_DIR", str(build))
+    monkeypatch.setattr(nd, "_lib", None)
+    monkeypatch.setattr(nd, "_lib_error", None)
+    for stale in ("libnpair_data.so", "libnpair_data.0123456789abcdef.so"):
+        (build / stale).write_bytes(b"not a shared object")
+    opened = []
+    real_cdll = nd.ctypes.CDLL
+    monkeypatch.setattr(nd.ctypes, "CDLL",
+                        lambda p: opened.append(p) or real_cdll(p))
+    nd._load()
+    want = nd._lib_path()
+    assert opened == [want] and os.path.exists(want)
+    assert os.path.basename(want) not in (
+        "libnpair_data.so", "libnpair_data.0123456789abcdef.so")
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    assert nd._lib_path() != want
+
+
+def test_require_fails_loudly_without_a_toolchain(tmp_path, monkeypatch):
+    """native='require' raises with the build failure; 'auto' falls
+    back to the Python pipeline but says why in the log."""
+    monkeypatch.setattr(nd, "_SRC", str(tmp_path / "missing.cpp"))
+    monkeypatch.setattr(nd, "_lib", None)
+    monkeypatch.setattr(nd, "_lib_error", None)
+    with pytest.raises(RuntimeError, match="native data runtime unavailable"):
+        nd.require_native()
+    assert nd.native_available() is False

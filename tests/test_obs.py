@@ -389,8 +389,8 @@ def test_check_no_print_flags_offender(tmp_path):
     assert "lib.py:2" in err and "cli.py" not in err
 
 
-def test_bench_parent_sinks_load_without_package():
-    """bench.py's parent loads obs/sinks.py by file path — that module
+def test_sinks_load_without_package():
+    """Jax-free processes load obs/sinks.py by file path — that module
     must import cleanly WITHOUT jax or the npairloss_tpu package."""
     code = (
         "import importlib.util, sys\n"

@@ -417,3 +417,63 @@ def test_blockwise_under_jit(rng):
     )(f)
     np.testing.assert_allclose(loss, loss_d, rtol=1e-5)
     np.testing.assert_allclose(g, g_d, rtol=1e-5, atol=1e-7)
+
+
+# -- sim-cache auto gate -------------------------------------------------------
+
+
+class _FakeDev:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "fake", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_sim_cache_auto_is_budgeted_and_logged(caplog):
+    import logging
+
+    from npairloss_tpu.ops.npair_loss import (
+        SIM_CACHE_AUTO_BYTES,
+        _SIM_CACHE_LOGGED,
+        resolve_sim_cache_auto,
+    )
+
+    _SIM_CACHE_LOGGED.clear()
+    with caplog.at_level(logging.INFO, logger="npairloss_tpu"):
+        assert resolve_sim_cache_auto(1 << 20, "testengine") is True
+    assert any("auto-enabling" in r.message for r in caplog.records)
+    # Beyond any budget: never auto-enables.
+    assert resolve_sim_cache_auto(SIM_CACHE_AUTO_BYTES + 1, "t2") is False
+    # Logged once per (engine, size): a second identical call is silent.
+    caplog.clear()
+    with caplog.at_level(logging.INFO, logger="npairloss_tpu"):
+        resolve_sim_cache_auto(1 << 20, "testengine")
+    assert not caplog.records
+
+
+def test_sim_cache_auto_sizes_against_reported_memory(monkeypatch):
+    """1/5 of the device's reported bytes_limit (16 GiB: rejects the
+    32k pool's 4.0 GiB slice, admits the 24k pool's 2.25 GiB); the CPU
+    gets its fixed reference budget; an ACCELERATOR that reports no
+    memory is an error, not a 2 GiB guess."""
+    from npairloss_tpu.ops.npair_loss import (
+        SIM_CACHE_CPU_BYTES,
+        resolve_sim_cache_auto,
+    )
+
+    def with_dev(platform, stats):
+        monkeypatch.setattr(jax, "devices",
+                            lambda: [_FakeDev(platform, stats)])
+
+    gib = 1 << 30
+    with_dev("tpu", {"bytes_limit": 16 * gib})
+    assert resolve_sim_cache_auto(32768 * 32768 * 4, "t") is False
+    assert resolve_sim_cache_auto(24576 * 24576 * 4, "t") is True
+    with_dev("cpu", None)
+    assert resolve_sim_cache_auto(SIM_CACHE_CPU_BYTES + 1, "t") is False
+    assert resolve_sim_cache_auto(1 * gib, "t") is True
+    for stats in (None, {}, {"bytes_limit": 0}):
+        with_dev("tpu", stats)
+        with pytest.raises(RuntimeError, match="no memory bytes_limit"):
+            resolve_sim_cache_auto(1 * gib, "t")

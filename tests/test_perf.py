@@ -33,9 +33,9 @@ class _Stage:
         return self._ret
 
 
-def test_cost_helper_list_vs_dict_and_missing():
-    """The cross-version return shapes and missing keys are handled in
-    ONE place (the dedup satellite's whole point)."""
+def test_cost_helper_missing_keys_degrade():
+    """Missing keys, non-numeric values and failing analyses are handled
+    in ONE place and degrade to None, never raise."""
     from npairloss_tpu.obs.perf.costs import (
         cost_analysis_dict,
         cost_flops,
@@ -43,11 +43,9 @@ def test_cost_helper_list_vs_dict_and_missing():
     )
 
     assert cost_flops(_Stage({"flops": 10.0})) == 10.0
-    assert cost_flops(_Stage([{"flops": 7.0}])) == 7.0  # older jax: [dict]
     assert cost_flops(_Stage({})) is None               # missing key
     assert cost_flops(_Stage({"flops": 0.0})) is None   # non-positive
     assert cost_flops(_Stage(raise_=True)) is None      # degrade, not raise
-    assert cost_analysis_dict(_Stage([])) == {}
     assert cost_analysis_dict(
         _Stage({"flops": 1.0, "bad": "x"})) == {"flops": 1.0}
 
@@ -211,9 +209,27 @@ def test_roofline_classification_fixtures():
     assert classify(0.0, 0.0, 0.0, spec)["bound"] == "unknown"
     assert all(x in BOUND_CLASSES
                for x in ("compute", "memory", "collective", "unknown"))
-    # Unknown device kinds fall back, flagged.
+    # The CPU backend (and "no device") get the flagged reference spec.
     assert not chip_peaks("cpu").known
     assert not chip_peaks("").known
+    assert chip_peaks("TPU v5 lite").hbm_bytes_per_s == 819e9
+
+
+def test_unknown_accelerator_is_an_error_not_v4():
+    """A device kind missing from the table must not borrow another
+    chip's peaks — in the roofline or in the engine plan that reads it
+    (parallel.plan picks dense vs ring from these numbers)."""
+    from npairloss_tpu.obs.perf.roofline import chip_peaks
+    from npairloss_tpu.parallel.plan import plan_engine
+
+    with pytest.raises(ValueError, match="no peak spec"):
+        chip_peaks("TPU v9 hyper")
+    with pytest.raises(ValueError, match="no peak spec"):
+        plan_engine(n_devices=4, n_hosts=1, shard_rows=30, emb_dim=1024,
+                    device_kind="TPU v9 hyper")
+    plan = plan_engine(n_devices=4, n_hosts=1, shard_rows=30,
+                       emb_dim=1024, device_kind="TPU v5 lite")
+    assert plan.to_dict()["peak_known"] is True
 
 
 # -- decompose ----------------------------------------------------------------
@@ -578,17 +594,6 @@ def test_bench_check_rows_and_p99():
     assert not any("batch_scaling" in x for x in v)
 
 
-def test_bench_check_offline_on_committed_artifacts():
-    """The ci.sh wiring: the committed BENCH_r01..r05 trajectory must
-    pass the gate (it improved every measured round)."""
-    bc = _load_bench_check()
-    records = bc.load_offline_records()
-    assert len(records) >= 2  # r02 + last_good at minimum
-    assert bc.check(records) == []
-    # And main() agrees end to end.
-    assert bc.main(["--offline"]) == 0
-
-
 def test_bench_check_ivf_hard_gates():
     """The approximate-index row's ABSOLUTE gates (ISSUE 11): recall@1
     below the hard floor or an IVF/flat qps ratio under the speedup
@@ -616,19 +621,22 @@ def test_bench_check_ivf_hard_gates():
     assert any("flat qps" in x for x in v), v
     # IVF row absent: coverage unchanged, nothing to gate.
     assert bc.check([("r1", base), ("r2", base)]) == []
-    # The committed BENCH_r07 evidence must clear both hard gates.
-    records = bc.load_offline_records()
-    rows = bc._walk_rows(records[-1][1])
-    assert "ivf_qps_1m" in rows, "committed ivf_qps_1m row missing"
-    assert bc._ivf_hard_gates(rows) == []
 
 
-def test_bench_check_skips_degraded_and_reused():
+def test_bench_check_history_mode(tmp_path):
+    """--history gates a JSONL trajectory of bench.py records; a failed
+    headline (value 0 / absent) or a smoke record is not a measurement;
+    with no mode at all the gate refuses to guess."""
     bc = _load_bench_check()
-    assert not bc._is_measurement(
-        {"value": 4000.0, "degraded": True, "stale": True})
-    assert not bc._is_measurement({"value": 4000.0,
-                                   "headline_reused": True})
     assert not bc._is_measurement({"value": 0.0})
     assert not bc._is_measurement({"value": 100.0, "mode": "smoke"})
     assert bc._is_measurement({"value": 4000.0})
+    hist = tmp_path / "h.jsonl"
+    hist.write_text("\n".join(json.dumps(r) for r in (
+        _rec(4300.0), {"mode": "smoke", "value": 9.0}, _rec(3000.0))))
+    assert bc.main(["--history", str(hist)]) == 1
+    hist.write_text("\n".join(json.dumps(r) for r in (
+        _rec(4300.0), _rec(4400.0))))
+    assert bc.main(["--history", str(hist)]) == 0
+    with pytest.raises(SystemExit):
+        bc.main([])

@@ -1,7 +1,7 @@
 """Sync-free stepping tests (docs/PIPELINE.md): parity against the
 synchronous loop (bit-identical params, byte-identical metric-key
 streams), prefetcher drain/crash/resume, dispatch-depth bounding, the
-no-mid-window-host-sync contract, and the compile-cache warmup path."""
+no-mid-window-host-sync contract, and the compile-cache resolver."""
 
 import json
 import os
@@ -21,7 +21,8 @@ from npairloss_tpu.pipeline import (
     HostSyncMonitor,
     MetricWindow,
     PrefetchStageError,
-    disable_compile_cache,
+    compile_cache,
+    compile_cache_dir,
     enable_compile_cache,
 )
 from npairloss_tpu.resilience import DivergenceConfig, failpoints
@@ -441,57 +442,97 @@ def test_pipelined_preempt_flushes_partial_window(tmp_path):
     assert os.path.isdir(ei.value.snapshot_path)
 
 
-# -- compile cache / warmup ------------------------------------------------
+# -- compile cache resolver --------------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
-def compile_cache_off_after():
-    """The cache is process-global jax config; a test must not leak it
-    into the rest of the suite (a cache-HIT executable enforces
-    donations a fresh CPU compile prunes — zero-copy np views of
-    donated state then mutate, see disable_compile_cache's docstring)."""
-    yield
-    disable_compile_cache()
+def config_updates(monkeypatch):
+    """Record (not apply) jax.config.update calls: the resolver's
+    decisions are visible without touching process-global state."""
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    return calls
 
 
-def test_warmup_populates_compile_cache(tmp_path, compile_cache_off_after):
-    cache = tmp_path / "xla_cache"
-    solver, _ = _make_solver(False, compile_cache=str(cache))
-    dt = solver.warmup(4)
-    assert dt > 0
-    entries = [f for f in os.listdir(cache) if f.endswith("-cache")]
-    assert entries, "warmup did not populate the compilation cache"
-    # warmup is AOT: nothing dispatched, no training state consumed.
+def test_cache_dir_env_var_is_the_whole_story(monkeypatch, tmp_path,
+                                              config_updates):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own handling places the
+    cache — no code path sets jax_compilation_cache_dir."""
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv(compile_cache.CACHE_DIR_ENV, outside)
+    assert enable_compile_cache() == outside
+    assert compile_cache_dir() == outside
+    assert "jax_compilation_cache_dir" not in dict(config_updates)
+    # The thresholds are still zeroed: every program is cached, so a
+    # second run can prove it compiled nothing.
+    assert ("jax_persistent_cache_min_compile_time_secs", 0.0) \
+        in config_updates
+
+
+def test_cache_dir_default_is_one_fixed_path_in_the_checkout(
+        monkeypatch, tmp_path, config_updates):
+    """Unset: one fixed in-checkout path — never a temp name, a pid, a
+    time or a run directory (the path is part of every entry's key)."""
+    monkeypatch.delenv(compile_cache.CACHE_DIR_ENV, raising=False)
+    want = os.path.join(REPO, ".jax_cache")
+    monkeypatch.chdir(tmp_path)  # a run dir is not an input
+    assert enable_compile_cache() == want
+    assert compile_cache_dir() == want
+    assert dict(config_updates)["jax_compilation_cache_dir"] == want
+    # Exactly ONE place in the tree sets the knob.
+    hits = []
+    for root in ("npairloss_tpu", "scripts"):
+        for dirpath, _, files in os.walk(os.path.join(REPO, root)):
+            hits += [os.path.join(dirpath, f) for f in files
+                     if f.endswith(".py") and '"jax_compilation_cache_dir"'
+                     in open(os.path.join(dirpath, f)).read()]
+    for f in ("bench.py", "chip_smoke.py"):
+        if '"jax_compilation_cache_dir"' in open(
+                os.path.join(REPO, f)).read():
+            hits.append(f)
+    assert [os.path.relpath(h, REPO) for h in hits] == [
+        os.path.join("npairloss_tpu", "pipeline", "compile_cache.py")]
+
+
+def test_second_process_compiles_nothing(tmp_path):
+    """The acceptance round-trip, through the CLI: two fresh train
+    processes share an outside cache dir; the second one reads every
+    program (hits, no misses, no new entries) and nothing appears
+    under the checkout because of it."""
+    import subprocess
+    import sys
+
+    outside = tmp_path / "cc"
+    env = dict(os.environ, JAX_ENABLE_COMPILATION_CACHE="true")
+    env[compile_cache.CACHE_DIR_ENV] = str(outside)
+    before = compile_cache.cache_entries(compile_cache.DEFAULT_CACHE_DIR)
+    stats = []
+    for _ in range(2):
+        proc = subprocess.run(
+            [sys.executable, "-m", "npairloss_tpu", "--platform", "cpu",
+             "train", "--solver", "examples/tiny_solver.prototxt",
+             "--model", "mlp", "--synthetic", "--max_iter", "2"],
+            cwd=REPO, env=env, capture_output=True, text=True,
+            timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        line = [ln for ln in proc.stderr.splitlines()
+                if ln.startswith("compile_cache ")][-1]
+        stats.append(json.loads(line[len("compile_cache "):]))
+    cold, warm = stats
+    assert cold["dir"] == str(outside)
+    assert cold["misses"] > 0 and cold["entries_after"] > 0
+    assert warm["hits"] > 0 and warm["misses"] == 0
+    assert warm["entries_after"] == cold["entries_after"]
+    assert compile_cache.cache_entries(
+        compile_cache.DEFAULT_CACHE_DIR) == before
+
+
+def test_warmup_is_aot():
+    """Solver.warmup compiles without dispatching: no training state
+    consumed."""
+    solver, _ = _make_solver(False)
+    assert solver.warmup(4) > 0
     assert solver.iteration == 0
-
-
-def test_enable_compile_cache_idempotent(tmp_path, compile_cache_off_after):
-    p1 = enable_compile_cache(str(tmp_path / "cc"))
-    p2 = enable_compile_cache(str(tmp_path / "cc"))
-    assert p1 == p2 and os.path.isdir(p1)
-
-
-def test_cache_hit_executable_enforces_donation(tmp_path,
-                                                compile_cache_off_after):
-    """Pin the sharp edge disable_compile_cache documents: a cache-HIT
-    executable donates where a fresh CPU compile pruned, so zero-copy
-    views of donated inputs mutate.  If a jax upgrade changes this,
-    the docstring should be updated too."""
-    import jax.numpy as jnp
-
-    enable_compile_cache(str(tmp_path / "cc"))
-
-    def probe():
-        f = jax.jit(lambda s: s * 2.0, donate_argnums=0)
-        s = f(jnp.arange(4, dtype=jnp.float32))
-        view = np.asarray(s)
-        ref = view.copy()
-        jax.block_until_ready(f(s))  # donates s's buffer
-        return bool(np.array_equal(view, ref))
-
-    probe()  # miss: compiles + writes the entry
-    stable_on_hit = probe()
-    # Whichever way jax behaves, the FRAMEWORK contract holds: nothing
-    # in Solver retains zero-copy views across steps.  Record the
-    # current jax behavior so a silent change is visible.
-    assert stable_on_hit is False

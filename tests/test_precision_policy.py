@@ -111,7 +111,7 @@ def test_describe_is_jsonable():
 
 def test_cli_choices_pinned_to_registry():
     """cli._PRECISION_CHOICES is hardcoded (argparse must stay jax-free
-    for the bench parent contract); this pin makes drift a failure."""
+    for the jax-free entry points); this pin makes drift a failure."""
     from npairloss_tpu.cli import _PRECISION_CHOICES
 
     assert sorted(_PRECISION_CHOICES) == available_policies()
@@ -191,7 +191,6 @@ def test_default_policy_hlo_contains_bf16_convolutions():
     feeding conv ops), while fp32_parity lowers none.  Lowering only —
     no XLA compile — so this stays cheap."""
     from npairloss_tpu.models import FLAGSHIP_POLICY, flagship_model
-    from npairloss_tpu.parallel._compat import lowered_text
 
     assert FLAGSHIP_POLICY == DEFAULT_POLICY
     x_sds = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
@@ -206,7 +205,7 @@ def test_default_policy_hlo_contains_bf16_convolutions():
         # Op lines only ("stablehlo.convolution"/HLO "convolution(") —
         # NOT MLIR #loc debug lines, which quote Python names (this
         # test's own name contains both "convolution" and "bf16"...).
-        lines = [ln for ln in lowered_text(low).splitlines()
+        lines = [ln for ln in low.as_text(debug_info=True).splitlines()
                  if re.search(r"\bconvolution\b\s*\(|stablehlo\."
                               r"convolution", ln)]
         assert lines, "no convolutions in the lowered trunk?"

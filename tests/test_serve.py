@@ -782,9 +782,7 @@ def test_warmup_compiles_each_bucket_exactly_once(rng):
     try:
         engine.warmup()
     finally:
-        from jax._src import monitoring as _mon
-
-        _mon._unregister_event_duration_listener_by_callback(_listener)
+        jax.monitoring.unregister_event_duration_listener(_listener)
     assert len(compiles) == len(engine.cfg.buckets), (
         f"{len(compiles)} backend compiles for "
         f"{len(engine.cfg.buckets)} buckets"
