@@ -157,14 +157,16 @@ class GoogLeNetEmbedding(nn.Module):
                 fuse_pool=(3, 2) if fuse_stem else None,
                 name="conv1",
             )(x, train)
+        # named_scope: the pools between the blocks and the LRNs are
+        # trunk-top-level code (not flax submodules), so without a
+        # scope their cost — the max-pool backward's select-and-scatter
+        # above all — would land in the root region of the trace
+        # instead of being attributable.  The prototxt's layer names;
+        # metadata only, the program is unchanged.
         if not fuse_stem:
-            x = max_pool(x, 3, 2)
+            with jax.named_scope("pool1"):
+                x = max_pool(x, 3, 2)
         if use_lrn:
-            # named_scope: LRN is trunk-top-level code (not a flax
-            # submodule), so without a scope its cost would land in the
-            # root region of the prof report (obs.perf) instead of
-            # being attributable — metadata only, the program is
-            # unchanged.
             with jax.named_scope("lrn"):
                 x = local_response_norm(x, impl=lrn_impl)
         x = ConvBlock(
@@ -179,7 +181,8 @@ class GoogLeNetEmbedding(nn.Module):
         if use_lrn:
             with jax.named_scope("lrn"):
                 x = local_response_norm(x, impl=lrn_impl)
-        x = max_pool(x, 3, 2)
+        with jax.named_scope("pool2"):
+            x = max_pool(x, 3, 2)
         # nn.remat checkpoints the block boundary: only each block's
         # input survives to the backward, its internals recompute.
         # ``train`` (argnum 2; 0 is the module) must be static — it
@@ -195,10 +198,12 @@ class GoogLeNetEmbedding(nn.Module):
         )
         x = incep("3a")(x, train)
         x = incep("3b")(x, train)
-        x = max_pool(x, 3, 2)
+        with jax.named_scope("pool3"):
+            x = max_pool(x, 3, 2)
         for key in ("4a", "4b", "4c", "4d", "4e"):
             x = incep(key)(x, train)
-        x = max_pool(x, 3, 2)
+        with jax.named_scope("pool4"):
+            x = max_pool(x, 3, 2)
         x = incep("5a")(x, train)
         x = incep("5b")(x, train)
         x = global_avg_pool(x)  # pool5/7x7_s1 -> (N, 1024)

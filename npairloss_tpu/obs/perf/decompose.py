@@ -2,7 +2,10 @@
 
 The span tracer already records where the loop thread's wall clock goes
 (``data/next_batch``, ``step/dispatch``, ``step/compile``, ``eval``,
-``snapshot``, ``step/window_sync``, the ``serve/*`` request path).  This
+``snapshot``, ``step/window_sync``, the ``serve/*`` request path; a
+name with no row here — ``step/window_rows``, ``serve/idle``,
+``serve/gather``, ``serve/assemble``, ``serve/reply`` — lands in
+``other_span``, never dropped).  This
 module turns one run's Chrome-trace events into the per-category
 breakdown the reports publish, with two hard rules:
 
@@ -37,9 +40,17 @@ SPAN_CATEGORIES = [
     ("comm/price", "compile"),
     ("step/compile", "compile"),
     ("eval/compile", "compile"),
-    ("step/recompile", "compile"),
+    # The perf rows' client-side lowering (a re-trace once per
+    # signature): obs overhead of the compile kind, like comm/price.
+    ("step/cost_analysis", "compile"),
     ("step/dispatch", "dispatch"),
+    # The host blocked on the device: the pipelined loop's wait for the
+    # oldest in-flight step, `prof`'s per-step block, and a dispatch's
+    # wait for its encode / top-k result (the longer prefixes keep the
+    # waits out of the serve/encode and serve/topk latency splits).
     ("step/device_wait", "device_compute"),
+    ("serve/encode/wait", "device_compute"),
+    ("serve/topk/wait", "device_compute"),
     ("step/window_sync", "window_sync"),
     ("eval", "eval"),
     ("snapshot", "snapshot"),

@@ -4,9 +4,10 @@ One trace id is assigned per query at INGESTION (the stdin FIFO loop
 and the HTTP front end alike) and rides the record through admission,
 the replica router, the micro-batcher, and the engine; each pipeline
 stage records a span (``admit_wait``, ``queue_wait``,
-``batch_assemble``, ``dispatch``, ``score``, ``topk_merge``) in the
-stdlib ``SpanTracer`` event shape, so exemplar trees drop straight
-into Perfetto next to the host spans and fleet lanes
+``batch_assemble``, ``dispatch``, ``score``, ``topk_merge``) through
+the process's ``SpanTracer`` — its clock, its origin and its event
+shape (obs.tracing: one tracer per process) — so exemplar trees drop
+straight into Perfetto next to the host spans and fleet lanes
 (docs/OBSERVABILITY.md §Query tracing).
 
 Two consumers sit on top of the raw spans:
@@ -41,9 +42,9 @@ import dataclasses
 import json
 import os
 import threading
-import time
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.obs.qtrace.report import (
     MARKER_NAMES,
     PROBE_FUSED_SPAN,
@@ -104,7 +105,7 @@ class QueryTrace:
 
     __slots__ = ("trace_id", "qid", "wall_time", "t_ingest",
                  "t_admitted", "t_picked", "t_dispatch", "stage_us",
-                 "events", "replica", "probe", "tenant", "done")
+                 "events", "replica", "batch", "probe", "tenant", "done")
 
     def __init__(self, trace_id: str, qid: Any, wall_time: float,
                  t_ingest: float):
@@ -118,6 +119,10 @@ class QueryTrace:
         self.stage_us: Dict[str, float] = {}
         self.events: List[Dict[str, Any]] = []
         self.replica: Optional[str] = None
+        # The dispatch this query rode (the batcher's sequence number,
+        # carried by every serve/* span of that dispatch): the cause of
+        # the tree's dispatch/score/topk_merge spans.
+        self.batch: Optional[int] = None
         self.probe = False
         # Multi-tenant serving stamps the owning tenant id at ingestion;
         # it rides into the root span's args so an exemplar tree is
@@ -129,24 +134,34 @@ class QueryTrace:
 class QueryTracer:
     """Assigns trace ids, records stage spans, aggregates, samples.
 
-    ``clock``/``wall`` are injectable for deterministic tests (seeded
-    monotonic time); defaults are the real clocks.  All shared state is
+    Time and the event shape come from a ``SpanTracer``: the one passed,
+    else the process's (``tracing.current()``), else a new one, which is
+    then installed — so the server's ``serve/*`` spans and the query
+    trees share one origin.  ``clock``/``wall`` stay injectable for
+    deterministic tests (seeded monotonic time): they build a private
+    tracer on those clocks, which is NOT installed.  All shared state is
     mutated under ``_lock`` — per-stage record calls arrive from the
     front-end, batcher, and replica dispatcher threads concurrently.
     """
 
     def __init__(self, cfg: QTraceConfig = QTraceConfig(),
                  registry=None, out_path: Optional[str] = None,
-                 clock: Callable[[], float] = time.perf_counter,
-                 wall: Callable[[], float] = time.time):
+                 clock: Optional[Callable[[], float]] = None,
+                 wall: Optional[Callable[[], float]] = None,
+                 tracer: Optional[tracing.SpanTracer] = None):
         self.cfg = cfg
         self.registry = registry
         self.out_path = out_path
-        self._clock = clock
-        self._wall = wall
-        self._t0 = clock()
-        self.wall_time_origin = wall()
-        self._pid = os.getpid()
+        if tracer is None and (clock is not None or wall is not None):
+            tracer = tracing.SpanTracer(clock=clock, wall=wall)
+        elif tracer is None:
+            tracer = tracing.current()
+            if tracer is None:
+                tracer = tracing.SpanTracer()
+                tracing.install(tracer)
+        self.tracer = tracer
+        self._now_us = tracer.now_us
+        self.wall_time_origin = tracer.wall_time_origin
         self._lock = threading.Lock()
         self._seq = 0            # guarded-by: _lock
         self._queries = 0        # guarded-by: _lock
@@ -167,25 +182,13 @@ class QueryTracer:
         self._exemplars: List[Dict[str, Any]] = []  # guarded-by: _lock
         self._markers: List[Dict[str, Any]] = []    # guarded-by: _lock
 
-    # -- clock -------------------------------------------------------------
-
-    def _now_us(self) -> float:
-        return (self._clock() - self._t0) * 1e6
-
-    def _tid(self) -> int:
-        return threading.get_ident() & 0xFFFFFFFF
-
-    def _span_event(self, qt: QueryTrace, name: str, t0_us: float,
-                    t1_us: float, **args) -> None:
-        qt.events.append({
-            "name": name,
-            "ph": "X",
-            "ts": t0_us,
-            "dur": max(t1_us - t0_us, 0.0),
-            "pid": self._pid,
-            "tid": self._tid(),
-            "args": {"trace_id": qt.trace_id, **args},
-        })
+    def _record(self, qt: QueryTrace, name: str, t0_us: float,
+                t1_us: float, **args) -> None:
+        """One span of the query's own tree (kept on the context and
+        retained only for exemplars — never a per-query flight recorder
+        in the shared buffer at full qps)."""
+        qt.events.append(self.tracer.complete_event(
+            name, t0_us, t1_us, trace_id=qt.trace_id, **args))
 
     # -- per-stage recording ----------------------------------------------
 
@@ -194,7 +197,7 @@ class QueryTracer:
         with self._lock:
             self._seq += 1
             seq = self._seq
-        return QueryTrace(f"q-{seq:08d}", qid, self._wall(),
+        return QueryTrace(f"q-{seq:08d}", qid, self.tracer.wall(),
                           self._now_us())
 
     def admitted(self, qt: QueryTrace, probe: bool = False) -> None:
@@ -203,34 +206,46 @@ class QueryTracer:
         now = self._now_us()
         qt.probe = qt.probe or probe
         qt.t_admitted = now
-        self._span_event(qt, f"qtrace/{STAGES[0]}", qt.t_ingest, now)
+        self._record(qt, f"qtrace/{STAGES[0]}", qt.t_ingest, now)
 
     def picked(self, qt: QueryTrace) -> None:
         """The replica's dispatcher pulled the query off its admission
         queue; ``queue_wait`` ends here."""
         now = self._now_us()
         qt.t_picked = now
-        self._span_event(qt, f"qtrace/{STAGES[1]}", qt.t_admitted, now)
+        self._record(qt, f"qtrace/{STAGES[1]}", qt.t_admitted, now)
 
     def dispatch_begin(self, qts: List[QueryTrace],
-                       replica: Optional[str] = None) -> None:
+                       replica: Optional[str] = None,
+                       batch: Optional[int] = None) -> None:
         """The coalesced batch entered the dispatch path;
-        ``batch_assemble`` is the co-rider wait since pick."""
+        ``batch_assemble`` is the co-rider wait since pick.  ``batch``
+        is the dispatch's sequence number, the one its ``serve/*``
+        spans carry."""
         now = self._now_us()
         for qt in qts:
             qt.replica = replica
+            qt.batch = batch
             qt.t_dispatch = now
-            self._span_event(qt, f"qtrace/{STAGES[2]}", qt.t_picked,
-                             now, **({"replica": replica} if replica
-                                     else {}))
+            self._record(qt, f"qtrace/{STAGES[2]}", qt.t_picked, now,
+                         **({"replica": replica} if replica else {}),
+                         **({"batch": batch} if batch is not None
+                            else {}))
 
     def dispatch_end(self, qts: List[QueryTrace], score_us: float = 0.0,
-                     merge_us: float = 0.0,
-                     fused: bool = False) -> None:
-        """The batch's answers exist.  ``score``/``topk_merge`` spans
-        are placed back-to-back at the tail of the dispatch span from
-        the engine's measured durations; ``dispatch`` keeps the
-        remainder (parse, encode, failpoint stalls) as self time.
+                     merge_us: float = 0.0, fused: bool = False,
+                     score_at: Optional[Tuple[float, float]] = None,
+                     merge_at: Optional[Tuple[float, float]] = None,
+                     ) -> None:
+        """The batch's answers exist.  ``score_at``/``merge_at`` are
+        the MEASURED ``(start_us, end_us)`` of the engine's top-k call
+        and of the host gather + answer assembly, on this tracer's
+        clock: the ``score``/``topk_merge`` spans sit where the work
+        happened, cut to the dispatch span.  An engine that reports
+        durations only (``score_us``/``merge_us``: stand-ins, external
+        adapters) gets them laid back-to-back at the tail of the
+        dispatch span.  ``dispatch`` keeps the remainder (parse,
+        encode, failpoint stalls) as self time.
 
         ``fused`` marks a fused-Pallas IVF probe dispatch: the
         score/merge clocks then came out of ONE kernel, so a wrapping
@@ -241,22 +256,31 @@ class QueryTracer:
         score_us = max(float(score_us), 0.0)
         merge_us = max(float(merge_us), 0.0)
         for qt in qts:
-            total = max(now - qt.t_dispatch, 0.0)
-            inner = min(score_us + merge_us, total)
-            scale = inner / (score_us + merge_us) \
-                if score_us + merge_us > 0 else 0.0
-            s_us, m_us = score_us * scale, merge_us * scale
-            self._span_event(qt, f"qtrace/{STAGES[3]}", qt.t_dispatch,
-                             now)
+            t0 = qt.t_dispatch
+            total = max(now - t0, 0.0)
+            if score_at is not None or merge_at is not None:
+                cut = lambda at: (0.0, 0.0) if at is None else (
+                    min(max(at[0], t0), now), min(max(at[1], t0), now))
+                (s0, s1), (m0, m1) = cut(score_at), cut(merge_at)
+                m0 = max(m0, s1)  # one instant, one stage
+                m1 = max(m1, m0)
+            else:
+                inner = min(score_us + merge_us, total)
+                scale = inner / (score_us + merge_us) \
+                    if score_us + merge_us > 0 else 0.0
+                m0, m1 = now - merge_us * scale, now
+                s0, s1 = m0 - score_us * scale, m0
+            s_us, m_us = s1 - s0, m1 - m0
+            self._record(qt, f"qtrace/{STAGES[3]}", t0, now,
+                         **({"batch": qt.batch} if qt.batch is not None
+                            else {}))
             if fused and s_us + m_us > 0:
-                self._span_event(qt, PROBE_FUSED_SPAN,
-                                 now - m_us - s_us, now)
+                self._record(qt, PROBE_FUSED_SPAN,
+                             s0 if s_us > 0 else m0, m1 if m_us > 0 else s1)
             if s_us > 0:
-                self._span_event(qt, f"qtrace/{STAGES[4]}",
-                                 now - m_us - s_us, now - m_us)
+                self._record(qt, f"qtrace/{STAGES[4]}", s0, s1)
             if m_us > 0:
-                self._span_event(qt, f"qtrace/{STAGES[5]}", now - m_us,
-                                 now)
+                self._record(qt, f"qtrace/{STAGES[5]}", m0, m1)
             qt.stage_us[STAGES[3]] = total - s_us - m_us
             qt.stage_us[STAGES[4]] = s_us
             qt.stage_us[STAGES[5]] = m_us
@@ -268,15 +292,8 @@ class QueryTracer:
         the artifact and on the merged timeline's serve lane."""
         if name not in MARKER_NAMES:
             raise ValueError(f"unknown qtrace marker {name!r}")
-        ev = {
-            "name": name,
-            "ph": "i",
-            "s": "t",
-            "ts": self._now_us(),
-            "pid": self._pid,
-            "tid": self._tid(),
-            "args": dict(args),
-        }
+        ev = self.tracer.instant_event(name)
+        ev["args"] = dict(args)
         with self._lock:
             if name == "crash_reroute":
                 self._reroutes += 1
@@ -320,12 +337,10 @@ class QueryTracer:
             STAGES[5]: qt.stage_us.get(STAGES[5], 0.0) / 1e3,
         }
         total_ms = max(now - qt.t_ingest, 0.0) / 1e3
-        self._span_event(qt, ROOT_SPAN, qt.t_ingest, now,
-                         **({"qid": qt.qid} if qt.qid is not None
-                            else {}),
-                         **({"probe": True} if qt.probe else {}),
-                         **({"tenant": qt.tenant} if qt.tenant
-                            else {}))
+        self._record(qt, ROOT_SPAN, qt.t_ingest, now,
+                     **({"qid": qt.qid} if qt.qid is not None else {}),
+                     **({"probe": True} if qt.probe else {}),
+                     **({"tenant": qt.tenant} if qt.tenant else {}))
         if self.registry is not None:
             for stage, ms in stage_ms.items():
                 self.registry.observe(f"qtrace_{stage}_ms", ms)

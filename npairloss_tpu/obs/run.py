@@ -15,7 +15,6 @@ instead of bespoke callbacks and hand-rolled JSON.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import time
 from typing import Any, Dict, Iterator, Optional, Sequence
@@ -27,6 +26,7 @@ from npairloss_tpu.obs.sinks import (
     MultiSink,
     RingBufferSink,
 )
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.obs.tracing import SpanTracer
 
 METRICS_FILENAME = "metrics.jsonl"
@@ -49,7 +49,11 @@ class RunTelemetry:
     (flushes sinks, writes trace.json).  Usable as a context manager.
 
     ``metrics=False`` gives a trace-only instance (the CLI's
-    ``--trace-dir``); ``trace=False`` a metrics-only one.  ``ring``
+    ``--trace-dir``); ``trace=False`` a metrics-only one.  A tracing
+    instance's ``tracer`` IS the process's tracer (``obs.tracing``:
+    constructing the telemetry, or assigning ``.tracer``, installs it;
+    ``close`` uninstalls it), so every ``tracing.span`` in the program
+    lands in this run's ``trace.json``.  ``ring``
     records stay readable via ``.ring.records()`` for live
     introspection either way.
 
@@ -97,11 +101,23 @@ class RunTelemetry:
             )
         children.extend(extra_sinks)
         self.sink: MetricLogger = MultiSink(children)
-        self.tracer: Optional[SpanTracer] = SpanTracer() if trace else None
-        if self.tracer is not None and self._stamp is not None:
-            self.tracer.stamp = dict(self._stamp)
+        self._tracer: Optional[SpanTracer] = None
+        if trace:
+            self.tracer = SpanTracer()
+            if self._stamp is not None:
+                self.tracer.stamp = dict(self._stamp)
         self.manifest: Optional[RunManifest] = None
         self._closed = False
+
+    @property
+    def tracer(self) -> Optional[SpanTracer]:
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer: Optional[SpanTracer]) -> None:
+        self._tracer = tracer
+        if tracer is not None:
+            tracing.install(tracer)
 
     # -- rank-aware path scheme -------------------------------------------
 
@@ -178,15 +194,15 @@ class RunTelemetry:
     # -- spans ------------------------------------------------------------
 
     def span(self, name: str, **args: Any):
-        """Tracer span, or a no-op context when tracing is disabled —
-        call sites never need to branch."""
-        if self.tracer is None:
-            return contextlib.nullcontext()
-        return self.tracer.span(name, **args)
+        """A span in this run's tracer; with tracing disabled here,
+        ``tracing.span`` — call sites never need to branch."""
+        if self._tracer is None:
+            return tracing.span(name, **args)
+        return self._tracer.span(name, **args)
 
     def instant(self, name: str, **args: Any) -> None:
-        if self.tracer is not None:
-            self.tracer.instant(name, **args)
+        if self._tracer is not None:
+            self._tracer.instant(name, **args)
 
     # -- lifecycle --------------------------------------------------------
 
@@ -200,6 +216,8 @@ class RunTelemetry:
         if self._closed:
             return
         self._closed = True
+        if self._tracer is not None and tracing.current() is self._tracer:
+            tracing.install(None)
         try:
             self.flush()
         finally:

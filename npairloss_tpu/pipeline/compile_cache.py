@@ -17,7 +17,9 @@ how the test suite keeps the cache off (tests/conftest.py).
 
 Thresholds are zeroed so EVERY program is cached: a second run of the
 same command then compiles nothing, which :class:`CacheCounter` lets a
-caller prove (entries read / written) instead of assume.
+caller prove (entries read / written) instead of assume.  The key
+includes the operations' metadata (scope names, source lines), so a
+profile never shows a cached executable under stale region names.
 """
 
 from __future__ import annotations
@@ -49,6 +51,14 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # By default the key leaves the operations' metadata out, so an
+    # executable cached before a ``named_scope`` was added or renamed
+    # comes back with the OLD region names — and device time by region
+    # is read off exactly those names in a profile (PERF.md, PR 25:
+    # the new pool scopes were invisible on a warm cache).  With the
+    # metadata in the key a scope edit recompiles the program it
+    # touches; the price is that moving a traced line does too.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = compile_cache_dir()
     log.info("persistent compilation cache: %s", path)
     return path

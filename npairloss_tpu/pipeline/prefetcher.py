@@ -20,12 +20,12 @@ resume can reason about exactly which batch index the pipeline died on.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import queue
 import threading
-from typing import Callable, Iterator, Optional
+from typing import Callable, Iterator
 
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.resilience import failpoints
 
 log = logging.getLogger("npairloss_tpu.pipeline")
@@ -65,10 +65,9 @@ class DevicePrefetcher:
       depth: staged batches held ready (>=1).  Device memory cost is
         ``depth`` extra batches — the price of never waiting on a
         transfer.
-      span: optional ``(name, **args) -> context`` (Solver._span /
-        RunTelemetry.span, both thread-safe) — each staging put is
-        recorded as a ``pipeline/stage`` span on the staging thread's
-        timeline.
+
+    Each staging put is recorded as a ``pipeline/stage`` span
+    (``obs.tracing.span``) on the staging thread's timeline.
     """
 
     def __init__(
@@ -76,13 +75,11 @@ class DevicePrefetcher:
         batches: Iterator,
         place: Callable,
         depth: int = 2,
-        span: Optional[Callable] = None,
     ):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self._it = batches
         self._place = place
-        self._span = span
         self._queue: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self.staged = 0  # written by the staging thread only
@@ -112,9 +109,7 @@ class DevicePrefetcher:
                     put(_EndOfData())
                     return
                 failpoints.fire("pipeline.stage")
-                ctx = (self._span("pipeline/stage", batch_index=self.staged)
-                       if self._span is not None else contextlib.nullcontext())
-                with ctx:
+                with tracing.span("pipeline/stage", batch_index=self.staged):
                     dev = self._place(*host)
                 self.staged += 1
             except BaseException as exc:  # surfaced in get(), never silent

@@ -32,6 +32,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.resilience import failpoints
 
 log = logging.getLogger("npairloss_tpu.serve")
@@ -68,26 +69,32 @@ class MicroBatcher:
     one result per item, in order; an exception fails every future in
     the batch (the server answers each with an error record).
     ``on_batch`` (optional) receives a stats dict per dispatched batch;
-    ``span_fn`` (optional) is a telemetry ``span(name, **args)``
-    factory for ``serve/batch``/``serve/dispatch`` spans; ``on_pick``
-    (optional) receives each item the instant the dispatcher pulls it
-    off the queue into the forming batch — the queue-wait/assemble
-    boundary per-query tracing needs (obs.qtrace), a no-op when unset.
+    ``on_pick`` (optional) receives each item the instant the dispatcher
+    pulls it off the queue into the forming batch — the queue-wait/
+    assemble boundary per-query tracing needs (obs.qtrace), a no-op
+    when unset.  ``name`` is the replica this batcher feeds.
+
+    The dispatcher thread's time is spanned whole (obs.tracing):
+    ``serve/idle`` (waiting for a head: nothing was queued) ->
+    ``serve/batch`` (waiting for co-riders) -> ``serve/dispatch`` ->
+    ``serve/reply`` (the futures' done-callbacks run inline here).
+    Every span of one turn, the engine's included, carries the turn's
+    sequence number ``batch`` (and ``replica``).
     """
 
     def __init__(
         self,
         dispatch_fn: Callable[[List[Any]], Sequence[Any]],
         cfg: BatcherConfig = BatcherConfig(),
-        span_fn=None,
         on_batch: Optional[Callable[[Dict[str, Any]], None]] = None,
         on_pick: Optional[Callable[[Any], None]] = None,
+        name: Optional[str] = None,
     ):
         self.cfg = cfg
         self._dispatch_fn = dispatch_fn
-        self._span_fn = span_fn
         self._on_batch = on_batch
         self._on_pick = on_pick
+        self._tags = {"replica": name} if name else {}
         self._q: queue.Queue = queue.Queue(maxsize=cfg.max_queue)
         self._thread: Optional[threading.Thread] = None
         self._closed = threading.Event()
@@ -171,58 +178,58 @@ class MicroBatcher:
 
     # -- dispatcher --------------------------------------------------------
 
-    def _span(self, name: str, **args):
-        if self._span_fn is None:
-            return contextlib.nullcontext()
-        return self._span_fn(name, **args)
-
     def _loop(self) -> None:
-        delay = max(self.cfg.max_delay_ms, 0.0) / 1e3
+        seq = 0
         while True:
-            try:
-                head = self._q.get(timeout=0.1)
-            except queue.Empty:
-                continue
-            if head is _STOP:
-                return
-            if failpoints.should_fire("serve.queue_stall"):
-                # Deterministic dispatcher stall (docs/RESILIENCE.md):
-                # admissions pile up behind the held queue, driving the
-                # queue-saturation watchdog and, past max_queue, the
-                # QueueFullError backpressure path — without touching
-                # the dispatch math.
-                time.sleep(failpoints.SERVE_QUEUE_STALL_S)
-            if self._on_pick is not None:
-                # After the stall, before coalescing: a stalled
-                # dispatcher is queue wait, not assemble time.
-                self._on_pick(head[0])
-            batch = [head]
-            deadline = head[2] + delay
-            stop_after = False
-            with self._span("serve/batch"):
-                while len(batch) < self.cfg.max_batch:
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0:
-                        break
-                    try:
-                        item = self._q.get(timeout=remaining)
-                    except queue.Empty:
-                        break
-                    if item is _STOP:
-                        stop_after = True
-                        break
-                    if self._on_pick is not None:
-                        self._on_pick(item[0])
-                    batch.append(item)
-            self._run_batch(batch)
-            if stop_after:
-                return
+            seq += 1
+            with tracing.tagged(batch=seq, **self._tags):
+                if self._turn():
+                    return
+
+    def _turn(self) -> bool:
+        """One head, its co-riders, their dispatch; True = stop."""
+        delay = max(self.cfg.max_delay_ms, 0.0) / 1e3
+        with tracing.span("serve/idle"):
+            head = self._q.get()
+        if head is _STOP:
+            return True
+        if failpoints.should_fire("serve.queue_stall"):
+            # Deterministic dispatcher stall (docs/RESILIENCE.md):
+            # admissions pile up behind the held queue, driving the
+            # queue-saturation watchdog and, past max_queue, the
+            # QueueFullError backpressure path — without touching
+            # the dispatch math.
+            time.sleep(failpoints.SERVE_QUEUE_STALL_S)
+        if self._on_pick is not None:
+            # After the stall, before coalescing: a stalled
+            # dispatcher is queue wait, not assemble time.
+            self._on_pick(head[0])
+        batch = [head]
+        deadline = head[2] + delay
+        stop_after = False
+        with tracing.span("serve/batch"):
+            while len(batch) < self.cfg.max_batch:
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                try:
+                    item = self._q.get(timeout=remaining)
+                except queue.Empty:
+                    break
+                if item is _STOP:
+                    stop_after = True
+                    break
+                if self._on_pick is not None:
+                    self._on_pick(item[0])
+                batch.append(item)
+        self._run_batch(batch)
+        return stop_after
 
     def _run_batch(self, batch) -> None:
         items = [b[0] for b in batch]
         t0 = time.perf_counter()
         try:
-            with self._span("serve/dispatch", size=len(items)):
+            with tracing.span("serve/dispatch", size=len(items)):
                 results = self._dispatch_fn(items)
             if len(results) != len(items):
                 raise RuntimeError(
@@ -237,8 +244,11 @@ class MicroBatcher:
                       len(items), e)
             return
         now = time.perf_counter()
-        for (_, fut, _), res in zip(batch, results):
-            fut.set_result(res)
+        # Done-callbacks (a closed-loop caller's next submit) run
+        # inline on this thread: the time belongs to the dispatcher.
+        with tracing.span("serve/reply", size=len(items)):
+            for (_, fut, _), res in zip(batch, results):
+                fut.set_result(res)
         self.batches += 1
         self.dispatched += len(items)
         if self._on_batch is not None:

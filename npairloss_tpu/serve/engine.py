@@ -55,6 +55,7 @@ from npairloss_tpu.ops.pallas_ivf import (
     fused_probe_topk,
     resolve_probe_impl,
 )
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.resilience import failpoints
 from npairloss_tpu.serve.index import GalleryIndex, l2_normalize_rows
 from npairloss_tpu.serve.ivf import SCORINGS, IVFIndex
@@ -302,8 +303,11 @@ class QueryEngine:
 
     ``model``/``state`` (a Flax module + the ``restore_for_inference``
     tree) enable :meth:`encode` for raw-input queries; embedding-only
-    serving needs neither.  ``telemetry`` records a ``serve/topk`` span
-    per dispatch.  Thread-safety: dispatches are serialized by the
+    serving needs neither.  Every dispatch records ``serve/encode`` /
+    ``serve/topk`` spans (each with its ``/wait`` child around the
+    blocking device-to-host copy) and ``serve/gather`` through
+    ``obs.tracing.span``; ``telemetry`` is accepted for the callers
+    that pass it and reads nothing.  Thread-safety: dispatches are serialized by the
     MicroBatcher (one dispatcher thread); the engine itself keeps no
     per-call mutable state beyond the compile counters.
     """
@@ -548,13 +552,6 @@ class QueryEngine:
         else:
             self._encode_fn = None
 
-    def _span(self, name: str, **args):
-        if self.telemetry is None:
-            import contextlib
-
-            return contextlib.nullcontext()
-        return self.telemetry.span(name, **args)
-
     def _cache_size(self) -> Optional[int]:
         sizes = []
         for fn in (self._topk_fn, self._encode_fn):
@@ -588,8 +585,7 @@ class QueryEngine:
         if not self.warmed:
             return
         self.compiles_after_warmup += 1
-        if self.telemetry is not None:
-            self.telemetry.instant("serve/recompile", sig=str(sig))
+        tracing.instant("serve/recompile", sig=str(sig))
         log.warning("serve: post-warmup XLA compile (sig=%s)", sig)
         if self._guard == "strict":
             raise ServeCompileError(
@@ -620,16 +616,20 @@ class QueryEngine:
         x = np.asarray(inputs, np.float32)
         n = x.shape[0]
         bucket = self.bucket_for(n)
-        if bucket > n:
-            x = np.concatenate(
-                [x, np.zeros((bucket - n, *x.shape[1:]), np.float32)]
-            )
-        sig = ("encode", tuple(x.shape))
         n_before = self._cache_size()
-        with self._span("serve/encode", batch=n, bucket=bucket):
+        # The span is the whole encode as the dispatcher pays it: pad,
+        # host->device, launch, the wait for the device and the
+        # device->host copy (the jitted call alone returns at launch).
+        with tracing.span("serve/encode", rows=n, bucket=bucket):
+            if bucket > n:
+                x = np.concatenate(
+                    [x, np.zeros((bucket - n, *x.shape[1:]), np.float32)]
+                )
             emb = self._encode_fn(self.state, jnp.asarray(x))
-        self._count_compiles(sig, n_before)
-        return np.asarray(emb)[:n]
+            with tracing.span("serve/encode/wait"):
+                emb = np.asarray(emb)
+        self._count_compiles(("encode", tuple(x.shape)), n_before)
+        return emb[:n]
 
     def query(
         self, embeddings: np.ndarray, normalize: bool = True,
@@ -643,9 +643,11 @@ class QueryEngine:
         ``{"scores", "rows", "labels", "ids"}``, each (B, top_k).
 
         ``stages`` (optional) is a per-call accumulator the qtrace
-        layer passes in: the device top-k wall time lands in
-        ``score_us`` and the host label/id gather in ``merge_us``,
-        summed across bucket chunks.  Per-call (not an engine
+        layer passes in: WHEN the top-k call (host->device, launch,
+        device, device->host) and the host label/id gather ran lands in
+        ``score_at`` / ``gather_at``, each ``(start, end)`` in
+        ``perf_counter`` seconds, first bucket chunk's start to last
+        chunk's end.  Per-call (not an engine
         attribute) on purpose — a crash reroute dispatches two batches
         on one engine concurrently, and racing attributes would charge
         one batch's score time to the other's trace.
@@ -719,25 +721,29 @@ class QueryEngine:
         args, sig = self._topk_call(bucket)
         n_before = self._cache_size()
         t_score = time.perf_counter()
-        with self._span("serve/topk", batch=n, bucket=bucket):
+        with tracing.span("serve/topk", rows=n, bucket=bucket):
             scores, rows = self._topk_fn(jnp.asarray(q), *args)
-            scores = np.asarray(scores)[:n]
-            rows = np.asarray(rows)[:n]
+            with tracing.span("serve/topk/wait"):
+                scores = np.asarray(scores)[:n]
+                rows = np.asarray(rows)[:n]
+        t_score1 = time.perf_counter()
         self._count_compiles(sig, n_before)
-        t_merge = time.perf_counter()
-        out = {
-            "scores": scores,
-            "rows": rows,
-            "labels": idx._host_labels[rows],
-            "ids": idx.ids[rows],
-        }
+        t_gather = time.perf_counter()
+        with tracing.span("serve/gather", rows=n):
+            out = {
+                "scores": scores,
+                "rows": rows,
+                "labels": idx._host_labels[rows],
+                "ids": idx.ids[rows],
+            }
         if stages is not None:
-            # Device scoring vs host gather, accumulated across bucket
-            # chunks (the qtrace score/topk_merge split).
-            stages["score_us"] = stages.get("score_us", 0.0) \
-                + (t_merge - t_score) * 1e6
-            stages["merge_us"] = stages.get("merge_us", 0.0) \
-                + (time.perf_counter() - t_merge) * 1e6
+            # Device scoring vs host gather (the qtrace score /
+            # topk_merge split), widened across bucket chunks.
+            stages["score_at"] = (
+                stages.get("score_at", (t_score,))[0], t_score1)
+            stages["gather_at"] = (
+                stages.get("gather_at", (t_gather,))[0],
+                time.perf_counter())
         return out
 
     # -- warmup ------------------------------------------------------------
@@ -757,7 +763,7 @@ class QueryEngine:
         idx = self.index
         t0 = _time.perf_counter()
         for bucket in self.cfg.buckets:
-            with self._span("serve/warmup", bucket=bucket, kind="topk"):
+            with tracing.span("serve/warmup", bucket=bucket, kind="topk"):
                 self._query_bucketed(np.zeros((bucket, idx.dim),
                                               np.float32))
             if self._encode_fn is not None:
@@ -765,8 +771,8 @@ class QueryEngine:
                     raise ValueError(
                         "warmup needs input_shape to warm the encode path"
                     )
-                with self._span("serve/warmup", bucket=bucket,
-                                kind="encode"):
+                with tracing.span("serve/warmup", bucket=bucket,
+                                  kind="encode"):
                     self.encode(np.zeros((bucket, *tuple(input_shape)),
                                          np.float32))
         self.warmed = True

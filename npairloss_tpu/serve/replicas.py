@@ -74,7 +74,6 @@ class ReplicaSet:
         engines: List[Any],
         batcher_cfg: BatcherConfig,
         dispatch_factory: Callable[[Replica], Callable],
-        span_fn=None,
         on_batch=None,
         on_pick=None,
     ):
@@ -85,7 +84,7 @@ class ReplicaSet:
             rep = Replica(name=f"r{i}", engine=engine)
             rep.batcher = MicroBatcher(
                 dispatch_factory(rep), batcher_cfg,
-                span_fn=span_fn, on_batch=on_batch, on_pick=on_pick,
+                on_batch=on_batch, on_pick=on_pick, name=rep.name,
             )
             self.replicas.append(rep)
         # Rejections that never reached a batcher (no live replica) —

@@ -47,6 +47,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.resilience import failpoints
 from npairloss_tpu.resilience.preempt import EXIT_PREEMPTED, PreemptionSignal
 from npairloss_tpu.serve.batcher import BatcherConfig, QueueFullError
@@ -289,7 +290,7 @@ class RetrievalServer:
         self._replica_idx: Dict[str, int] = {}
         self.replicaset = ReplicaSet(
             engines, batcher_cfg, self._replica_dispatch,
-            span_fn=self._span, on_batch=self._record_batch,
+            on_batch=self._record_batch,
             on_pick=self._qtrace_pick if qtrace is not None else None,
         )
         self._lat = collections.deque(maxlen=max(cfg.latency_window, 1))
@@ -399,11 +400,6 @@ class RetrievalServer:
                               replica=target.name)
 
     # -- telemetry ---------------------------------------------------------
-
-    def _span(self, name: str, **args):
-        if self.telemetry is None:
-            return contextlib.nullcontext()
-        return self.telemetry.span(name, **args)
 
     def _record_batch(self, stats: Dict[str, Any]) -> None:
         self._last_batch = stats
@@ -698,8 +694,9 @@ class RetrievalServer:
             # to the answers — parse, encode, failpoint stalls, the
             # engine call — is the ``dispatch`` stage (score/topk_merge
             # are split back out of it below).
-            self.qtrace.dispatch_begin(qts, replica=replica)
-        stages: Optional[Dict[str, float]] = {} if qts else None
+            self.qtrace.dispatch_begin(
+                qts, replica=replica, batch=tracing.tags().get("batch"))
+        stages: Optional[Dict[str, Any]] = {} if qts else None
         if failpoints.should_fire("serve.latency"):
             # Deterministic latency fault (docs/RESILIENCE.md): every
             # query in this batch pays the stall — the p99 spike the
@@ -745,7 +742,7 @@ class RetrievalServer:
                 for i, _ in enc_rows:
                     answers[i] = {"id": items[i].get("id"), **tstamp,
                                   "error": str(e)}
-        t_merge = 0.0
+        t_asm = (0.0, 0.0)
         if emb_rows:
             batch = np.stack([x for _, x in emb_rows])
             # Only thread the stage-clock dict through when tracing is
@@ -753,33 +750,35 @@ class RetrievalServer:
             # grow the kwarg to serve an untraced tier.
             out = (engine.query(batch) if stages is None
                    else engine.query(batch, stages=stages))
-            t_asm0 = time.perf_counter()
-            fresh = (entry.freshness if entry is not None
-                     else self.freshness)
-            ages = fresh.ages() if fresh is not None else {}
-            for j, (i, _) in enumerate(emb_rows):
-                answers[i] = {
-                    "id": items[i].get("id"),
-                    **tstamp,
-                    # Per-answer freshness stamp (ROADMAP item 4): how
-                    # old the model/index behind THIS answer is — the
-                    # TENANT'S freshness in tenant mode.
-                    **ages,
-                    "neighbors": [
-                        {
-                            "rank": r,
-                            "row": int(out["rows"][j, r]),
-                            "gallery_id": int(out["ids"][j, r]),
-                            "label": int(out["labels"][j, r]),
-                            "score": round(float(out["scores"][j, r]), 6),
-                        }
-                        for r in range(out["scores"].shape[1])
-                    ],
-                }
             # Host-side answer assembly is merge work: it joins the
             # device top-K with labels/ids/freshness into the wire
             # shape, so it lands in ``topk_merge``, not dispatch self.
-            t_merge = time.perf_counter() - t_asm0
+            t_asm0 = time.perf_counter()
+            with tracing.span("serve/assemble", rows=len(emb_rows)):
+                fresh = (entry.freshness if entry is not None
+                         else self.freshness)
+                ages = fresh.ages() if fresh is not None else {}
+                for j, (i, _) in enumerate(emb_rows):
+                    answers[i] = {
+                        "id": items[i].get("id"),
+                        **tstamp,
+                        # Per-answer freshness stamp (ROADMAP item 4):
+                        # how old the model/index behind THIS answer is
+                        # — the TENANT'S freshness in tenant mode.
+                        **ages,
+                        "neighbors": [
+                            {
+                                "rank": r,
+                                "row": int(out["rows"][j, r]),
+                                "gallery_id": int(out["ids"][j, r]),
+                                "label": int(out["labels"][j, r]),
+                                "score": round(
+                                    float(out["scores"][j, r]), 6),
+                            }
+                            for r in range(out["scores"].shape[1])
+                        ],
+                    }
+            t_asm = (t_asm0, time.perf_counter())
             shadow = (entry.shadow if entry is not None
                       else self.shadow)
             if shadow is not None:
@@ -798,11 +797,22 @@ class RetrievalServer:
                 except Exception as e:  # noqa: BLE001 — shadow must not fail answers
                     log.error("shadow offer failed: %s", e)
         if qts:
+            # The engine's MEASURED top-k and gather intervals
+            # (perf_counter seconds; a stand-in may report the
+            # durations ``score_us``/``merge_us`` alone); the assembly
+            # above joins the gather.
+            to_us = self.qtrace.tracer.to_us
+            score_at, gather_at = stages.get("score_at"), \
+                stages.get("gather_at")
             self.qtrace.dispatch_end(
                 qts,
-                score_us=(stages or {}).get("score_us", 0.0),
-                merge_us=((stages or {}).get("merge_us", 0.0)
-                          + t_merge * 1e6),
+                score_us=stages.get("score_us", 0.0),
+                merge_us=(stages.get("merge_us", 0.0)
+                          + (t_asm[1] - t_asm[0]) * 1e6),
+                score_at=score_at and (to_us(score_at[0]),
+                                       to_us(score_at[1])),
+                merge_at=gather_at and (to_us(gather_at[0]),
+                                        to_us(t_asm[1])),
                 # Fused probe path: the score/merge clocks came out of
                 # ONE Pallas dispatch, so the trace wraps them in a
                 # probe_fused span (the stage vocabulary is unchanged).
@@ -1181,7 +1191,7 @@ class RetrievalServer:
         entry = self._tenant_entry(record) if self.tenants else None
         if entry is not None and qt is not None:
             qt.tenant = entry.tenant_id
-        with self._span("serve/admit"):
+        with tracing.span("serve/admit"):
             with self._lock:  # HTTP front end submits from many threads
                 self.queries += 1
                 if entry is not None:

@@ -37,6 +37,7 @@ from npairloss_tpu.obs.health import (
     pair_hardness_health,
     update_health,
 )
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.obs.run import RunTelemetry
 from npairloss_tpu.ops.metrics import retrieval_metrics
 from npairloss_tpu.resilience import failpoints
@@ -717,7 +718,7 @@ class Solver:
         if self.mesh is not None and jax.process_count() > 1:
             from npairloss_tpu.parallel.distributed import process_local_batch
 
-            with self._span("comm/assemble", staged=True):
+            with tracing.span("comm/assemble", staged=True):
                 return process_local_batch(
                     self.mesh, (np.asarray(inputs), np.asarray(labels)),
                     self.axis,
@@ -754,15 +755,9 @@ class Solver:
         )
         lab_sds = jax.ShapeDtypeStruct((int(batch_size),), jnp.int32)
         t0 = _time.perf_counter()
-        with self._span("step/compile", batch=int(batch_size), aot=True):
+        with tracing.span("step/compile", batch=int(batch_size), aot=True):
             self._step_fn.lower(self.state, x_sds, lab_sds).compile()
         return _time.perf_counter() - t0
-
-    def _span(self, name: str, **args):
-        """Telemetry span, or a no-op context when none is attached."""
-        if self.telemetry is None:
-            return contextlib.nullcontext()
-        return self.telemetry.span(name, **args)
 
     def _tel_log(self, phase: str, step: int, metrics, **extra) -> None:
         """Metric emission that can never abort training: a sink-write
@@ -798,7 +793,7 @@ class Solver:
             # Spanned: the client-side lowering costs a full re-trace
             # (once per signature) and must show in the host timeline
             # as obs overhead, not as unattributed wall time.
-            with self._span("step/cost_analysis"):
+            with tracing.span("step/cost_analysis"):
                 lowered = fn.lower(*args)
                 self._step_flops = cost_flops(lowered)
             return lowered
@@ -856,7 +851,7 @@ class Solver:
                 stage_hlo_text,
             )
 
-            with self._span("comm/price", aot=True):
+            with tracing.span("comm/price", aot=True):
                 per_opcode = collective_bytes_by_opcode(
                     stage_hlo_text(
                         lowered if lowered is not None
@@ -966,7 +961,7 @@ class Solver:
             # in-graph collectives (accounting marks only), this has a
             # real host duration — spanned as comm/ so the fleet
             # decomposition sees it.
-            with self._span("comm/assemble"):
+            with tracing.span("comm/assemble"):
                 return process_local_batch(
                     self.mesh, (np.asarray(inputs), np.asarray(labels)),
                     self.axis,
@@ -1009,7 +1004,7 @@ class Solver:
         # compile (a warmed solver re-dispatches the same signature).
         self._capture_fleet_comms(self._step_fn, (self.state, x, lab),
                                   lowered=lowered)
-        with self._span(
+        with tracing.span(
             "step/compile" if compiling else "step/dispatch",
             **self._step_span_args(int(np.shape(x)[0])),
         ):
@@ -1028,7 +1023,7 @@ class Solver:
         """TEST phase: average loss+metrics over ``num_iters`` batches."""
         acc: Dict[str, float] = collections.defaultdict(float)
         n = 0
-        with self._span("eval", num_iters=num_iters):
+        with tracing.span("eval", num_iters=num_iters):
             for _ in range(num_iters):
                 inputs, labels = next(batches)
                 if self.state is None:
@@ -1040,7 +1035,7 @@ class Solver:
                 compiling = sig not in self._seen_eval_shapes
                 self._seen_eval_shapes.add(sig)
                 if compiling:
-                    with self._span("eval/compile",
+                    with tracing.span("eval/compile",
                                     batch=int(np.shape(x)[0])):
                         m = self._eval_fn(self.state, x, lab)
                 else:
@@ -1102,7 +1097,7 @@ class Solver:
         try:
             it = start
             while it < num_iters:
-                with self._span("data/next_batch"):
+                with tracing.span("data/next_batch"):
                     inputs, labels = next(train_batches)
                 # Keep metrics as device scalars so the loop never blocks
                 # on a host sync; floats are materialized only at display/
@@ -1332,7 +1327,7 @@ class Solver:
         window_cap = self._pipeline_window_capacity(test_batches is not None)
         controller = DispatchController(depth)
         prefetcher = DevicePrefetcher(
-            train_batches, self._stage_batch, depth=depth, span=self._span
+            train_batches, self._stage_batch, depth=depth
         )
         last: Dict[str, Any] = {}
         ring = None
@@ -1354,7 +1349,7 @@ class Solver:
                     message="Some donated buffers were not usable",
                 )
                 while it < num_iters:
-                    with self._span("data/next_batch", staged=True):
+                    with tracing.span("data/next_batch", staged=True):
                         x, lab = prefetcher.get()
                     if self._pipe_step_fn is None:
                         with allowed():
@@ -1362,7 +1357,10 @@ class Solver:
                     if ring is None:
                         with allowed():
                             ring = self._init_ring()
-                    controller.reserve()
+                    # The loop's one blocking point in steady state:
+                    # the oldest in-flight step's completion token.
+                    with tracing.span("step/device_wait"):
+                        controller.reserve()
                     sig = (tuple(np.shape(x)), tuple(np.shape(lab)))
                     compiling = sig not in self._seen_step_shapes
                     self._seen_step_shapes.add(sig)
@@ -1385,7 +1383,7 @@ class Solver:
                     cache_size = getattr(self._pipe_step_fn,
                                          "_cache_size", lambda: None)
                     n_before = cache_size()
-                    with self._span(
+                    with tracing.span(
                         "step/compile" if compiling else "step/dispatch",
                         pipeline=True,
                         **self._step_span_args(int(np.shape(x)[0])),
@@ -1429,39 +1427,43 @@ class Solver:
                         continue
                     # ---- window boundary: the ONE host sync ----------
                     with allowed():
-                        with self._span(
+                        with tracing.span(
                             "step/window_sync",
                             steps=step_num - window_start + 1,
                         ):
                             host_ring = jax.device_get(ring)
                             ring = self._ring_reset_fn(ring)
-                        rows = self._metric_window.read(host_ring)
-                        for s in poisoned:
-                            rows[s - window_start]["loss"] = \
-                                np.float32("nan")
-                        # The in-graph counter IS the window-edge trip
-                        # check: max_streak == 0 proves every loss in
-                        # (or carried into) this window was finite, so
-                        # the guard's per-row replay below can be
-                        # skipped wholesale.  Host-side poison
-                        # (step.nan_loss) is invisible to the device
-                        # counter, hence the OR on ``poisoned`` — and
-                        # on guard.streak, so an all-finite window
-                        # still replays to RESET a streak a previous
-                        # window's poison left in flight.
-                        nonfinite_seen = bool(poisoned) or \
-                            int(host_ring["max_streak"]) > 0 or \
-                            (guard is not None and guard.streak > 0)
-                        tripped = None
-                        for off, row in enumerate(rows):
-                            s = window_start + off
-                            self._loss_window.append(row["loss"])
-                            last = row
-                            if guard is not None and nonfinite_seen and \
-                                    guard.observe(float(row["loss"])):
-                                tripped = s
-                                break
-                            self._emit_step_row(s, row, log_fn, record_fn)
+                        with tracing.span(
+                            "step/window_rows",
+                            steps=step_num - window_start + 1,
+                        ):
+                            rows = self._metric_window.read(host_ring)
+                            for s in poisoned:
+                                rows[s - window_start]["loss"] = \
+                                    np.float32("nan")
+                            # The in-graph counter IS the window-edge trip
+                            # check: max_streak == 0 proves every loss in
+                            # (or carried into) this window was finite, so
+                            # the guard's per-row replay below can be
+                            # skipped wholesale.  Host-side poison
+                            # (step.nan_loss) is invisible to the device
+                            # counter, hence the OR on ``poisoned`` — and
+                            # on guard.streak, so an all-finite window
+                            # still replays to RESET a streak a previous
+                            # window's poison left in flight.
+                            nonfinite_seen = bool(poisoned) or \
+                                int(host_ring["max_streak"]) > 0 or \
+                                (guard is not None and guard.streak > 0)
+                            tripped = None
+                            for off, row in enumerate(rows):
+                                s = window_start + off
+                                self._loss_window.append(row["loss"])
+                                last = row
+                                if guard is not None and nonfinite_seen and \
+                                        guard.observe(float(row["loss"])):
+                                    tripped = s
+                                    break
+                                self._emit_step_row(s, row, log_fn, record_fn)
                         if tripped is not None:
                             # In-graph counter + window replay agreed the
                             # streak crossed patience; the steps already
@@ -1514,15 +1516,20 @@ class Solver:
         if ring is None or self._metric_window is None:
             return last
         try:
-            rows = self._metric_window.read(jax.device_get(ring))
-            for s in poisoned:
-                if 0 <= s - window_start < len(rows):
-                    rows[s - window_start]["loss"] = np.float32("nan")
-            for off, row in enumerate(rows):
-                s = window_start + off
-                self._loss_window.append(row["loss"])
-                last = row
-                self._emit_step_row(s, row)
+            # The same two spans as the in-loop boundary: the read-back
+            # waits for every step still in flight.
+            with tracing.span("step/window_sync", tail=True):
+                host_ring = jax.device_get(ring)
+            with tracing.span("step/window_rows", tail=True):
+                rows = self._metric_window.read(host_ring)
+                for s in poisoned:
+                    if 0 <= s - window_start < len(rows):
+                        rows[s - window_start]["loss"] = np.float32("nan")
+                for off, row in enumerate(rows):
+                    s = window_start + off
+                    self._loss_window.append(row["loss"])
+                    last = row
+                    self._emit_step_row(s, row)
         except Exception as e:  # noqa: BLE001
             log.error("pending-window flush failed: %s", e)
         return last
@@ -1699,7 +1706,7 @@ class Solver:
         """
         path = self.snapshot_path(step)
         if jax.process_count() > 1:
-            with self._span("snapshot", step=step):
+            with tracing.span("snapshot", step=step):
                 self._ckpt().save(path, self.state, force=True)
                 self._ckpt().wait_until_finished()
                 if jax.process_index() == 0:
@@ -1714,7 +1721,7 @@ class Solver:
                             attempt=attempt, delay_s=round(delay, 3),
                             error=str(exc))
 
-        with self._span("snapshot", step=step):
+        with tracing.span("snapshot", step=step):
             commit_snapshot(
                 self._ckpt(), path, self.state, step,
                 policy=self.snapshot_retry, on_retry=on_retry,

@@ -190,7 +190,7 @@ def _tiny_solver(**kw):
     from npairloss_tpu.models import get_model
     from npairloss_tpu.train import Solver, SolverConfig
 
-    cfg = SolverConfig(
+    cfg = kw.pop("cfg", None) or SolverConfig(
         base_lr=0.1, lr_policy="fixed", momentum=0.9, weight_decay=0.0,
         display=0, test_interval=0, snapshot=0,
     )
@@ -406,3 +406,218 @@ def test_sinks_load_without_package():
     )
     rc = subprocess.run([sys.executable, "-c", code], capture_output=True)
     assert rc.returncode == 0, rc.stderr.decode()
+
+
+# -- one tracer per process, one span function (obs.tracing) ---------------
+
+
+@pytest.fixture
+def no_tracer():
+    """The test starts with no tracer installed and leaves the process
+    as it found it."""
+    from npairloss_tpu.obs import tracing
+
+    prev = tracing.install(None)
+    yield tracing
+    tracing.install(prev)
+
+
+def test_one_tracer_is_shared_by_telemetry_qtrace_and_span(tmp_path, no_tracer):
+    from npairloss_tpu.obs.qtrace import QueryTracer
+
+    tracing = no_tracer
+    with tracing.span("nobody/listens"):  # neither tracer nor session: no-op
+        pass
+    tel = RunTelemetry(str(tmp_path / "r"), metrics=False)
+    assert tracing.current() is tel.tracer
+    qtracer = QueryTracer()
+    assert qtracer.tracer is tel.tracer
+    assert not hasattr(qtracer, "_t0")  # no clock of its own
+    assert qtracer.wall_time_origin == tel.tracer.wall_time_origin
+    with tracing.span("serve/dispatch", size=1):
+        tracing.instant("serve/recompile", sig="s")
+    with tel.span("step/dispatch"):
+        pass
+    qt = qtracer.begin("q")
+    qtracer.admitted(qt)
+    events = tel.tracer.events_since(0)[0]
+    assert [e["name"] for e in events] == [
+        "serve/recompile", "serve/dispatch", "step/dispatch"]
+    # the query's own span reads on the same origin as the buffer's
+    assert events[-1]["ts"] <= qt.events[0]["ts"] + qt.events[0]["dur"]
+    assert set(qt.events[0]) == set(events[1])  # one event shape
+    # a window in perf_counter seconds clips by the public origin
+    import time
+
+    assert tel.tracer.to_us(time.perf_counter()) >= events[-1]["ts"]
+    assert tel.tracer.origin <= time.perf_counter()
+    # a tracer assigned later is the installed one (the cap test's way)
+    tel.tracer = SpanTracer(max_events=4)
+    assert tracing.current() is tel.tracer
+    tel.close()
+    assert tracing.current() is None  # close takes its own tracer away
+    # nothing installed: a QueryTracer brings and installs one; a seeded
+    # clock stays private to its tracer
+    made = QueryTracer()
+    assert tracing.current() is made.tracer
+    seeded = QueryTracer(clock=lambda: 5.0, wall=lambda: 9.0)
+    assert seeded.tracer is not made.tracer
+    assert tracing.current() is made.tracer
+    assert seeded.wall_time_origin == 9.0 and seeded.tracer.origin == 5.0
+
+
+def test_tagged_spans_carry_the_shared_identifier(no_tracer):
+    import threading
+
+    tracing = no_tracer
+    tr = SpanTracer()
+    tracing.install(tr)
+    seen = []
+
+    def other():  # tags are the calling thread's alone
+        with tracing.span("other/thread"):
+            seen.append(tracing.tags())
+
+    with tracing.tagged(batch=7, replica="r0"):
+        with tracing.span("serve/dispatch", size=2):
+            with tracing.tagged(chunk=1):
+                with tracing.span("serve/topk", rows=2):
+                    pass
+        tracing.instant("serve/recompile")
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=10)
+    with tracing.span("serve/idle"):
+        pass
+    by = {e["name"]: e.get("args") for e in tr.events_since(0)[0]}
+    assert by["serve/dispatch"] == {"batch": 7, "replica": "r0", "size": 2}
+    assert by["serve/topk"] == {"batch": 7, "replica": "r0", "chunk": 1,
+                                "rows": 2}
+    assert by["serve/recompile"] == {"batch": 7, "replica": "r0"}
+    assert by["other/thread"] is None and seen == [{}]
+    assert by["serve/idle"] is None and tracing.tags() == {}
+
+
+class _SlowToHost:
+    """A device array's stand-in: the jitted call has returned, the
+    result reaches the host only after ``__array__`` has waited."""
+
+    def __init__(self, rows, wait_s):
+        self.rows, self.wait_s = rows, wait_s
+
+    def __array__(self, dtype=None, copy=None):
+        import time
+
+        time.sleep(self.wait_s)
+        return self.rows
+
+
+def _encoding_server(wait_s=0.0, qtrace=None):
+    """A real engine over a tiny gallery whose encode is the identity
+    on ``dim``-wide inputs (handed back as a slow-to-host array)."""
+    from npairloss_tpu.serve import (
+        BatcherConfig, EngineConfig, GalleryIndex, QueryEngine,
+        RetrievalServer, ServerConfig)
+
+    rng = np.random.default_rng(0)
+    emb = rng.standard_normal((24, 8)).astype(np.float32)
+    emb /= np.linalg.norm(emb, axis=1, keepdims=True)
+    idx = GalleryIndex.build(emb, np.arange(24, dtype=np.int32) // 4)
+    engine = QueryEngine(idx, EngineConfig(top_k=3, buckets=(1, 4)))
+    engine._encode_fn = lambda _state, x: _SlowToHost(np.asarray(x), wait_s)
+    engine.warmup((8,))
+    server = RetrievalServer(
+        engine, BatcherConfig(max_batch=4, max_delay_ms=1.0, max_queue=16),
+        ServerConfig(metrics_window=0), input_shape=(8,), qtrace=qtrace)
+    return emb, server
+
+
+def test_dispatch_spans_share_a_batch_and_encode_ends_on_the_host(no_tracer):
+    """Every span of one dispatcher turn carries the turn's ``batch``;
+    ``serve/encode`` holds its ``wait`` child and ends only when the
+    result is on the host (the old span closed at launch: ~0 ms here)."""
+    tracing = no_tracer
+    tr = SpanTracer()
+    tracing.install(tr)
+    emb, server = _encoding_server(wait_s=0.05)
+    start = tr.num_events  # past the warm-up's spans
+    server.replicaset.start()
+    try:
+        (ans,) = server.handle_many([{"id": 0, "input": emb[5].tolist()}])
+    finally:
+        server.replicaset.close(drain=True)
+    assert ans["neighbors"][0]["row"] == 5
+    events = tr.events_since(start)[0]
+    turn = [e for e in events if e.get("args", {}).get("batch") == 1]
+    by = {e["name"]: e for e in turn}
+    assert set(by) == {
+        "serve/idle", "serve/batch", "serve/dispatch", "serve/encode",
+        "serve/encode/wait", "serve/topk", "serve/topk/wait",
+        "serve/gather", "serve/assemble", "serve/reply"}
+    assert all(e["args"]["replica"] == "r0" for e in turn)
+    assert by["serve/dispatch"]["args"]["size"] == 1
+    end = lambda e: e["ts"] + e["dur"]
+    inside = lambda child, parent: (
+        by[parent]["ts"] <= by[child]["ts"] and end(by[child]) <= end(by[parent]))
+    assert inside("serve/encode/wait", "serve/encode")
+    assert inside("serve/topk/wait", "serve/topk")
+    for child in ("serve/encode", "serve/topk", "serve/gather",
+                  "serve/assemble"):
+        assert inside(child, "serve/dispatch")
+    assert by["serve/encode/wait"]["dur"] >= 50e3  # the copy's wait, in us
+    assert by["serve/encode"]["dur"] >= by["serve/encode/wait"]["dur"]
+    # the thread's turn in order, nothing overlapping
+    order = ["serve/idle", "serve/batch", "serve/dispatch", "serve/reply"]
+    assert all(end(by[a]) <= by[b]["ts"] for a, b in zip(order, order[1:]))
+    # the turn after it (the drain's) has another number
+    assert {e["args"]["batch"] for e in events
+            if e["name"] == "serve/idle"} == {1, 2}
+
+
+def test_profiler_session_holds_the_program_spans_in_the_host_plane(
+        tmp_path, no_tracer):
+    """Any ``jax.profiler`` session — no tracer installed — finds the
+    program's host spans beside the device operations, on its clock."""
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmarks.harness import trace_reduce, xplane
+
+    from npairloss_tpu.data import synthetic_identity_batches
+    from npairloss_tpu.train import SolverConfig
+
+    emb, server = _encoding_server()
+    solver = _tiny_solver(cfg=SolverConfig(
+        base_lr=0.1, lr_policy="fixed", momentum=0.9, weight_decay=0.0,
+        display=0, test_interval=0, snapshot=0, pipeline=True,
+        pipeline_depth=1))
+    batches = synthetic_identity_batches(8, 8, 2, (8,), noise=0.5)
+    solver.train(batches, num_iters=2)  # compile outside the session
+    server.replicaset.start()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        server.handle_many([{"id": 0, "input": emb[3].tolist()}])
+        solver.train(batches, num_iters=5)
+    finally:
+        jax.profiler.stop_trace()
+        server.replicaset.close(drain=True)
+    assert no_tracer.current() is None
+    found = {}
+    for plane in xplane.read(trace_reduce.newest_xplane(str(tmp_path))):
+        if not plane["name"].startswith("/host:"):
+            continue
+        for thread, line in enumerate(plane["lines"]):  # a line is a thread
+            for name, _start, dur, stats in line["events"]:
+                if name.startswith(("serve/", "step/", "data/", "pipeline/")):
+                    found.setdefault(name, []).append((thread, dur, stats))
+    for name in ("serve/dispatch", "serve/encode/wait", "step/device_wait",
+                 "step/dispatch", "data/next_batch", "pipeline/stage"):
+        assert name in found, sorted(found)
+    (_thread, dur, stats), = found["serve/dispatch"]
+    assert dur > 0 and int(stats["size"]) == 1 and int(stats["batch"]) == 1
+    # the dispatch's children sit on the dispatcher's line, the staging
+    # thread's span on its own
+    assert found["serve/encode/wait"][0][0] == found["serve/dispatch"][0][0]
+    assert found["pipeline/stage"][0][0] != found["step/device_wait"][0][0]

@@ -470,6 +470,10 @@ def test_cache_dir_env_var_is_the_whole_story(monkeypatch, tmp_path,
     # second run can prove it compiled nothing.
     assert ("jax_persistent_cache_min_compile_time_secs", 0.0) \
         in config_updates
+    # The operations' metadata is part of the key: a cached executable
+    # never shows a profile stale ``named_scope`` region names.
+    assert ("jax_compilation_cache_include_metadata_in_key", True) \
+        in config_updates
 
 
 def test_cache_dir_default_is_one_fixed_path_in_the_checkout(

@@ -567,3 +567,87 @@ def test_merge_timeline_lanes_and_instants(tmp_path):
                  if e["name"] == "alert:serve_p99 firing")
     base = merged["otherData"]["wall_time_origin"]
     assert fired["ts"] == pytest.approx((1000.5 - base) * 1e6)
+
+
+# -- measured stage positions, on the process's tracer ---------------------
+
+
+def test_measured_positions_place_score_and_merge_where_they_ran():
+    """``dispatch_end`` with measured intervals puts the spans where the
+    work happened (cut to the dispatch span), not at its tail; durations
+    alone still lay them back-to-back at the tail."""
+    clk = SeededClock()
+    tr = _tracer(clk, exemplars=4, slo_ms=100.0)
+    qt = tr.begin("q1")
+    tr.admitted(qt)
+    tr.picked(qt)
+    tr.dispatch_begin([qt], replica="r0", batch=7)
+    assert qt.batch == 7
+    clk.advance(0.010)  # dispatch is [0, 10 ms]
+    tr.dispatch_end([qt], score_us=999.0, merge_us=999.0,
+                    score_at=(2000.0, 5000.0), merge_at=(5500.0, 12000.0))
+    tr.finish(qt)
+    by = {e["name"]: e for e in qt.events}
+    assert (by["qtrace/score"]["ts"], by["qtrace/score"]["dur"]) == \
+        (2000.0, 3000.0)
+    # the merge is cut at the dispatch span's end
+    assert (by["qtrace/topk_merge"]["ts"], by["qtrace/topk_merge"]["dur"]) \
+        == (5500.0, 4500.0)
+    assert by["qtrace/dispatch"]["args"]["batch"] == 7
+    assert by["qtrace/batch_assemble"]["args"]["batch"] == 7
+    assert qt.stage_us == {"dispatch": 2500.0, "score": 3000.0,
+                           "topk_merge": 4500.0}
+    assert validate_qtrace_report(tr.report()) is None
+
+
+def test_server_trees_carry_the_engine_measured_times():
+    """Through a real engine: a rider's ``score`` and ``topk_merge``
+    events lie inside its ``dispatch`` event at the times the engine's
+    ``serve/topk`` and ``serve/gather`` .. ``serve/assemble`` spans
+    read, and the tree names the ``batch`` those spans carry."""
+    from npairloss_tpu.obs import tracing
+    from npairloss_tpu.obs.tracing import SpanTracer
+    from npairloss_tpu.serve import EngineConfig, GalleryIndex, QueryEngine
+
+    rng = np.random.default_rng(3)
+    emb = rng.standard_normal((32, 8)).astype(np.float32)
+    idx = GalleryIndex.build(emb, np.arange(32, dtype=np.int32) // 4)
+    engine = QueryEngine(idx, EngineConfig(top_k=3, buckets=(1, 4)))
+    engine.warmup()
+    shared = SpanTracer()
+    prev = tracing.install(shared)
+    try:
+        tracer = QueryTracer(QTraceConfig(exemplars=8, slo_ms=0.0))
+        assert tracer.tracer is shared
+        srv = RetrievalServer(
+            engine, BatcherConfig(max_batch=4, max_delay_ms=1.0),
+            ServerConfig(metrics_window=0), qtrace=tracer)
+        srv.replicaset.start()
+        try:
+            (ans,) = srv.handle_many(
+                [{"id": "a", "embedding": emb[9].tolist()}])
+        finally:
+            srv.replicaset.close(drain=True)
+    finally:
+        tracing.install(prev)
+    assert ans["neighbors"][0]["row"] == 9
+    (ex,) = tracer.report()["exemplars"]
+    tree = {e["name"]: e for e in ex["events"]}
+    batch = tree["qtrace/dispatch"]["args"]["batch"]
+    spans = {e["name"]: e for e in shared.events_since(0)[0]
+             if e.get("args", {}).get("batch") == batch}
+    end = lambda e: e["ts"] + e["dur"]
+    disp = tree["qtrace/dispatch"]
+    for name in ("qtrace/score", "qtrace/topk_merge"):
+        assert disp["ts"] <= tree[name]["ts"]
+        assert end(tree[name]) <= end(disp)
+    # the engine reads its clock just outside each span: the tree's
+    # stage holds the span, a clock read apart
+    score, merge = tree["qtrace/score"], tree["qtrace/topk_merge"]
+    assert score["ts"] <= spans["serve/topk"]["ts"]
+    assert end(spans["serve/topk"]) <= end(score)
+    assert merge["ts"] <= spans["serve/gather"]["ts"]
+    assert end(spans["serve/assemble"]) <= end(merge)
+    assert score["dur"] - spans["serve/topk"]["dur"] < 2000.0  # us
+    # not at the tail: the reply and bookkeeping follow the merge
+    assert end(tree["qtrace/score"]) <= tree["qtrace/topk_merge"]["ts"]
