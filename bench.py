@@ -603,7 +603,8 @@ def engine_rows(jax, jnp, np, selected, extras, run_row):
 # *_policy rows are the flagship recipe's 240/480/960 scaling curve
 # (the 120 point is the headline itself), googlenet_fp32_parity keeps
 # the prototxt-parity fp32 delta measured, and 120_pallas_stem times
-# the fused-stem Pallas kernels (Mosaic-compiled on TPU).  The vit_b16
+# the Pallas conv epilogues (Mosaic-compiled on TPU; LRN is a kernel in
+# every row there).  The vit_b16
 # rows time BASELINE.json config 5's trunk (real ViT-B/16) through the
 # blockwise (stretch-path) engine; the 256 row probes the largest batch
 # and runs LAST.  The row_key column is the other half of the --rows

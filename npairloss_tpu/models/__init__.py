@@ -63,9 +63,10 @@ _REGISTRY: Dict[str, Callable[..., Any]] = {
     "googlenet_mxu": lambda **kw: GoogLeNetEmbedding(
         stem_s2d=True, fuse_1x1=True, **kw
     ),
-    # Pallas stem fusion on top of the MXU rewrites: fused LRN +
-    # conv-bias-ReLU(+pool) epilogues (ops.pallas_stem; interpret-mode
-    # parity-tested).  Parameter tree identical to googlenet_mxu.
+    # Pallas conv epilogues on top of the MXU rewrites: conv bias + ReLU
+    # (+ pool) in one pass (ops.pallas_stem; interpret-mode
+    # parity-tested, in no benchmark cell).  Parameter tree identical to
+    # googlenet_mxu.
     "googlenet_pallas": lambda **kw: GoogLeNetEmbedding(
         stem_s2d=True, fuse_1x1=True, pallas_stem=True, **kw
     ),

@@ -124,11 +124,12 @@ class GoogLeNetEmbedding(nn.Module):
     # its compute/output dtypes.  None keeps the pre-policy ``dtype``
     # behavior (HLO-identical).
     policy: Optional[PrecisionPolicy] = None
-    # Pallas stem fusion (ops.pallas_stem): route the VPU-bound stem
-    # tail — both LRN layers plus the conv1/conv2 bias+ReLU(+pool)
-    # epilogues — through the fused one-VMEM-pass kernels.  Bias-LRN
-    # trunks only (the BN trunk has neither LRN nor conv biases);
-    # parameter tree unchanged, interpret-mode parity-tested on CPU.
+    # Pallas conv epilogues (ops.pallas_stem): conv1's bias + ReLU +
+    # max-pool and conv2_reduce / conv2's bias + ReLU, each in one VMEM
+    # pass.  Bias-LRN trunks only (the BN trunk has no conv biases);
+    # parameter tree unchanged, interpret-mode parity-tested on CPU; in
+    # no benchmark cell, speed on the chip: not measured.  (LRN picks
+    # its own kernel by backend: layers.local_response_norm.)
     pallas_stem: bool = False
 
     @nn.compact
@@ -137,7 +138,6 @@ class GoogLeNetEmbedding(nn.Module):
         fuse_stem = self.pallas_stem and not self.use_bn
         compute_dtype = (self.policy.compute_dtype
                          if self.policy is not None else self.dtype)
-        lrn_impl = "pallas" if fuse_stem else "xla"
         x = x.astype(compute_dtype)
         if self.stem_s2d:
             x = space_to_depth(x, 2)
@@ -168,7 +168,7 @@ class GoogLeNetEmbedding(nn.Module):
                 x = max_pool(x, 3, 2)
         if use_lrn:
             with jax.named_scope("lrn"):
-                x = local_response_norm(x, impl=lrn_impl)
+                x = local_response_norm(x)
         x = ConvBlock(
             64, (1, 1), dtype=self.dtype, use_bn=self.use_bn,
             policy=self.policy, fused_epilogue=fuse_stem,
@@ -180,7 +180,7 @@ class GoogLeNetEmbedding(nn.Module):
         )(x, train)
         if use_lrn:
             with jax.named_scope("lrn"):
-                x = local_response_norm(x, impl=lrn_impl)
+                x = local_response_norm(x)
         with jax.named_scope("pool2"):
             x = max_pool(x, 3, 2)
         # nn.remat checkpoints the block boundary: only each block's

@@ -22,24 +22,33 @@ def local_response_norm(
     alpha: float = 1e-4,
     beta: float = 0.75,
     k: float = 1.0,
-    impl: str = "xla",
-    cache: Optional[bool] = None,
 ) -> jax.Array:
     """Across-channel LRN (the classic GoogLeNet/AlexNet normalization).
 
     x: NHWC.  Matches Caffe LRN semantics: denominator
-    (k + alpha/size * sum_{window} x^2)^beta over a channel window.
+    (k + alpha/size * sum_{window} x^2)^beta over a channel window,
+    float32 inside whatever the input's dtype.
 
-    ``impl="pallas"`` routes through the fused one-VMEM-pass kernel
-    (ops.pallas_stem.fused_lrn — parity-tested against this reference);
-    ``cache`` is its denominator-cache knob (None = auto by size).
+    On a TPU backend this is the fused kernel (ops.pallas_stem.fused_lrn:
+    one pass each way in the tensor's own dtype); elsewhere the
+    ``reduce_window`` body below, the kernel's parity reference.
     """
-    if impl == "pallas":
+    if jax.default_backend() == "tpu":
         from npairloss_tpu.ops.pallas_stem import fused_lrn
 
-        return fused_lrn(x, size, alpha, beta, k, cache=cache)
-    if impl != "xla":
-        raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
+        return fused_lrn(x, size, alpha, beta, k)
+    return local_response_norm_xla(x, size, alpha, beta, k)
+
+
+def local_response_norm_xla(
+    x: jax.Array,
+    size: int = 5,
+    alpha: float = 1e-4,
+    beta: float = 0.75,
+    k: float = 1.0,
+) -> jax.Array:
+    """LRN over plain XLA ops: what every non-TPU backend runs, and the
+    parity reference of the kernel (tests, chip_kernel_check)."""
     xf = x.astype(jnp.float32)
     sq = xf * xf
     win = jax.lax.reduce_window(
@@ -54,11 +63,8 @@ def local_response_norm(
     if beta == 0.75:
         # The reference's beta: d^-0.75 == (sqrt(rsqrt(d)))^3, two fast
         # VPU ops + two mults instead of the exp+log a generic pow
-        # lowers to.  LRN is ~25% of the flagship step
-        # (profile/flagship.json: full - no_lrn = 6.9 ms), so the
-        # transcendental on every activation element matters.  Differs
-        # from pow by a few float32 ulp — inside oracle tolerance
-        # (tests/test_models.py LRN parity).
+        # lowers to.  Differs from pow by a few float32 ulp — inside
+        # oracle tolerance (tests/test_models.py LRN parity).
         r = jnp.sqrt(jax.lax.rsqrt(d))
         out = xf * (r * r * r)
     else:

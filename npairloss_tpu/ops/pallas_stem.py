@@ -1,73 +1,287 @@
 """Pallas TPU kernels for the GoogLeNet stem's VPU-bound tail.
 
-The perf observatory (``prof --step train``, obs/perf) attributes the
-flagship trunk's non-MXU time to the stem's elementwise chain: the two
-across-channel LRN layers (square -> windowed sum -> pow -> scale — a
-VPU reduce XLA cannot fuse into any matmul, measured at ~25% of the
-prototxt-parity step, PROFILE.md) and the conv epilogues (bias + ReLU,
-bias + ReLU + 3x3/s2 max-pool) whose intermediates XLA materializes to
-HBM between the conv gemm and the pool reduce.  These kernels fuse each
-chain into ONE VMEM pass:
+* :func:`fused_lrn` — across-channel LRN, forward and backward each ONE
+  pass over the activation: x^2 -> channel-window sum -> pow -> scale in
+  float32 registers, only the tensor's own dtype (bf16 under ``mxu``)
+  crossing HBM.  ``models.layers.local_response_norm`` runs it on a TPU
+  backend in place of XLA's ``reduce_window`` body (PERF.md, PR 26).
+* :func:`fused_bias_relu` / :func:`fused_bias_relu_pool` — the conv
+  epilogues behind ``GoogLeNetEmbedding.pallas_stem`` (bias + ReLU
+  (+ 3x3/s2 max-pool) in one VMEM pass; the conv stays an XLA gemm).
+  In no benchmark cell; speed on the chip: not measured.
 
-* :func:`fused_lrn`        — x^2 -> channel-window sum -> rsqrt-pow ->
-  scale in a single tile visit, with an analytic custom VJP whose
-  backward is a second one-pass kernel (the transpose window).
-* :func:`fused_bias_relu`  — conv epilogue: bias add + ReLU fused (the
-  conv itself stays an XLA gemm — the MXU half is already optimal).
-* :func:`fused_bias_relu_pool` — stem epilogue: bias + ReLU + max-pool
-  in one pass, so the pre-pool activation never round-trips HBM.
-
-**Denominator cache** (the ``sim_cache`` pattern of
-``ops/pallas_npair.py`` transplanted): the LRN backward needs the
-forward's denominator ``d = k + a*W(x^2)``.  When the fp32 ``d`` tensor
-fits the auto budget (``LRN_CACHE_AUTO_BYTES``), the forward kernel
-writes it out once and the backward streams it back (``cache=True``);
-beyond the budget the backward recomputes the window sum from ``x``
-(``cache=False``) — one extra VPU pass instead of an HBM-resident
-tensor.  Cached and recompute paths are bit-identical (the cache stores
-exactly the fp32 values the forward produced); ``cache=None`` picks by
-size, mirroring ``resolve_sim_cache_auto``.
-
-On non-TPU backends every kernel runs in Pallas interpreter mode, which
-is how the CPU suite checks parity against the XLA reference
-(``models.layers.local_response_norm`` / bias+relu+``reduce_window``)
-— forward AND backward, including ragged row/channel tiles
-(tests/test_pallas_stem.py).
+Off the TPU the kernels run in Pallas interpreter mode: the CPU suite's
+parity harness (tests/test_pallas_stem.py); LRN is never a model path there.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
+from jax.experimental.custom_partitioning import custom_partitioning
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import NamedSharding, PartitionSpec
 
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.ops.pallas_mode import default_interpret
 
-# fp32 bytes of the LRN denominator tensor below which the forward
-# caches it for the backward (the pallas_npair SIM_CACHE_AUTO_BYTES
-# pattern at stem-activation scale: the batch-120 pool1 site is ~385 MB
-# — cached on a 16 GB chip, recomputed only when an operator forces
-# cache=False or the tensor outgrows the budget at very large batch).
-LRN_CACHE_AUTO_BYTES = 2 << 30
-
-_BLOCK_ROWS = 256
 _LANES = 128
-
-
-def resolve_lrn_cache_auto(nbytes: int, cache: Optional[bool]) -> bool:
-    """Explicit ``cache`` wins; None = auto by the fp32 denominator
-    size (same contract shape as ops.npair_loss.resolve_sim_cache_auto)."""
-    if cache is not None:
-        return bool(cache)
-    return nbytes <= LRN_CACHE_AUTO_BYTES
 
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
+
+
+# -- LRN ---------------------------------------------------------------------
+#
+# LRN is independent per pixel, so the kernel may take the activation in
+# the order the neighbouring XLA ops keep it in HBM; any other order costs
+# a relayout copy of the whole tensor on each side of the call.  XLA's TPU
+# layout assignment puts on the 128 lanes whichever of batch and channels
+# pads them less (compiled HLO at batch 1..480, PERF.md PR 26): training
+# is ``bf16[480,56,56,64]{0,3,2,1:T(8,128)(2,1)}``, serving channel-minor.
+# Two views of one algorithm, by the same rule:
+# * ``cols`` — (pixels, C, N) blocks: the batch on the lanes, the window
+#   along the SUBLANES (the transpose is a bitcast of that layout);
+# * ``rows`` — (N * pixels, C) blocks, the whole channel dimension as the
+#   block's last: the window along the LANES (any rank, any C).
+# Both cover ragged extents with a ``cdiv`` grid (no padded copy), move
+# blocks of about ``_BLOCK_BYTES`` and compute on register-sized chunks.
+
+_BLOCK_BYTES = 1 << 20   # one operand's block of a grid step
+_CHUNK_ELEMS = 8 * 1024  # float32 elements of one in-register chunk
+_COLS_CHUNK_ROWS = 64    # channels of one chunk in the ``cols`` view
+
+
+class _LRNParams(NamedTuple):
+    """Hashable static bundle (trace-time config)."""
+    size: int
+    alpha: float
+    beta: float
+    k: float
+    interpret: bool
+
+    @property
+    def window(self) -> Tuple[int, int]:
+        """(lo, hi): channels before / after the centre, Caffe's split."""
+        return self.size // 2, self.size - 1 - self.size // 2
+
+
+def _win_lanes(v: jax.Array, lo: int, hi: int) -> jax.Array:
+    """out[:, i] = sum_{o=-lo..hi} v[:, i+o], zero fill at both ends:
+    the in-register form of the reference's ``reduce_window``."""
+    c = v.shape[1]
+    vp = jnp.pad(v, ((0, 0), (lo, hi)))
+    return functools.reduce(
+        jnp.add, [vp[:, o:o + c] for o in range(lo + hi + 1)])
+
+
+def _win_sublanes(v: jax.Array, lo: int, hi: int) -> jax.Array:
+    """out[i, :] = sum_{o=-lo..hi} v[i+o, :] by sublane rotations, which
+    wrap: the caller brings halo rows (neighbours, or zeros at the channel
+    edges) and reads the centre.  Pairs are summed once and shifted
+    together: 3 rotations for a window of 5."""
+    rows = v.shape[0]
+    shift = lambda a, o: pltpu.roll(a, (-o) % rows, 0) if o else a
+    pair = v + shift(v, 1)  # pair[i] = v[i] + v[i+1]
+    terms = [shift(pair, o) for o in range(-lo, hi, 2)]
+    if (lo + hi) % 2 == 0:
+        terms.append(shift(v, hi))
+    return functools.reduce(jnp.add, terms)
+
+
+def _lrn_math(x, g, p: _LRNParams, win, exact: bool):
+    """y (``g`` None) or dx, float32 in and out.  With y_i = x_i d_i^-b,
+    d_i = k + a W(x^2)_i, a = alpha/size and W the forward window:
+        dx_j = g_j d_j^-b - 2ab x_j W^T(g x d^(-b-1))_j
+    where W^T is the window with (lo, hi) swapped.  ``d`` is recomputed
+    from x (float32 ``d`` in HBM: 4 B an element each way against 2).
+    The powers are exp2 of a multiple of log d (one transcendental-unit
+    op and a multiply each; 2e-6 of float64 on the chip) unless the
+    result is float32 (``exact``): then the reference's
+    (sqrt(rsqrt(d)))^3, 5e-7, at twice the vector ops — Mosaic expands
+    rsqrt / sqrt into a dozen apiece, and the pass is VALU-bound."""
+    lo, hi = p.window
+    d = p.k + (p.alpha / p.size) * win(x * x, lo, hi)
+    if exact and p.beta == 0.75:
+        r2 = jax.lax.rsqrt(d)
+        s = jnp.sqrt(r2)
+        f = s * s * s
+        f_over_d = f * (r2 * r2)
+    else:
+        t, log2e = jnp.log(d), 1.4426950408889634
+        f = jnp.exp2(jnp.float32(-p.beta * log2e) * t)
+        f_over_d = jnp.exp2(jnp.float32(-(p.beta + 1) * log2e) * t)
+    if g is None:
+        return x * f
+    u = g * x * f_over_d
+    return g * f - (2.0 * p.alpha / p.size * p.beta) * x * win(u, hi, lo)
+
+
+def _sublane_tile(dtype) -> int:
+    """Rows of one (sublane, lane) tile: 8 at 4 bytes, 16 at 2."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _lrn_cols_kernel(*refs, p: _LRNParams):
+    """Blocks (pixels, C, N).  Per pixel and 128-lane column, channels go
+    through the registers in chunks of ``_COLS_CHUNK_ROWS`` with a halo of
+    sublane tiles: the neighbouring chunk's rows, or zeros past the ends."""
+    *in_refs, o_ref = refs
+    nb, c, n = o_ref.shape
+    halo = _round_up(2 * max(p.window), 8)
+    load = _round_up(halo, _sublane_tile(o_ref.dtype))
+
+    def haloed(ref, i, c0, cr, l0, ln):
+        r0, r1 = max(c0 - load, 0), min(c0 + cr + load, c)
+        v = ref[i, r0:r1, l0:l0 + ln].astype(jnp.float32)
+        v = jnp.pad(v, ((load - (c0 - r0), load - (r1 - c0 - cr)), (0, 0)))
+        return v[load - halo:load + cr + halo]
+
+    def pixel(i, carry):
+        for l0 in range(0, n, _LANES):
+            ln = min(_LANES, n - l0)
+            for c0 in range(0, c, _COLS_CHUNK_ROWS):
+                cr = min(_COLS_CHUNK_ROWS, c - c0)
+                x, *g = (haloed(r, i, c0, cr, l0, ln) for r in in_refs)
+                out = _lrn_math(x, g[0] if g else None, p, _win_sublanes,
+                                o_ref.dtype == jnp.float32)
+                o_ref[i, c0:c0 + cr, l0:l0 + ln] = (
+                    out[halo:halo + cr].astype(o_ref.dtype))
+        return carry
+
+    jax.lax.fori_loop(0, nb, pixel, 0)
+
+
+def _rows_chunk(c: int, dtype) -> int:
+    tile = _sublane_tile(dtype)
+    return max(tile, _CHUNK_ELEMS // _round_up(c, _LANES) // tile * tile)
+
+
+def _lrn_rows_kernel(*refs, p: _LRNParams):
+    """Blocks (rows, C), a pixel a row."""
+    *in_refs, o_ref = refs
+    br, c = o_ref.shape
+    rs = min(_rows_chunk(c, o_ref.dtype), br)
+
+    def chunk(j, carry):
+        rows = pl.ds(pl.multiple_of(j * rs, rs), rs)
+        x, *g = (r[rows, :].astype(jnp.float32) for r in in_refs)
+        out = _lrn_math(x, g[0] if g else None, p, _win_lanes,
+                        o_ref.dtype == jnp.float32)
+        o_ref[rows, :] = out.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, br // rs, chunk, 0)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _lrn_call(p: _LRNParams, *operands: jax.Array) -> jax.Array:
+    """y from (x,), dx from (x, g): one ``pallas_call`` over the view the
+    shape picks.  Jitted for its trace cache (a step is traced 3 times)."""
+    x = operands[0]
+    shape, n, c, item = x.shape, x.shape[0], x.shape[-1], x.dtype.itemsize
+    cols = (x.ndim > 2 and c % _sublane_tile(x.dtype) == 0
+            and _round_up(n, _LANES) * c < _round_up(c, _LANES) * n)
+    if cols:
+        rows, lanes, kernel = math.prod(shape[1:-1]), n, _lrn_cols_kernel
+        view = lambda a: jnp.moveaxis(a, 0, -1).reshape(rows, c, n)
+        unview = lambda a: jnp.moveaxis(a.reshape(shape[1:] + (n,)), -1, 0)
+        per = c * _round_up(n, _LANES) * item
+        block = (min(rows, max(1, _BLOCK_BYTES // per)), c, n)
+    else:
+        rows, lanes, kernel = math.prod(shape[:-1]), c, _lrn_rows_kernel
+        view = lambda a: a.reshape(rows, c)
+        unview = lambda a: a.reshape(shape)
+        rs = _rows_chunk(c, x.dtype)
+        per = _round_up(c, _LANES) * item
+        block = (rows if rows <= rs else min(
+            max(rs, _BLOCK_BYTES // per // rs * rs), rows // rs * rs), c)
+    tracing.instant("lrn/kernel", view="cols" if cols else "rows",
+                    rows=rows, lanes=lanes, block_rows=block[0],
+                    backward=len(operands) > 1)
+    spec = pl.BlockSpec(block, lambda i: (i,) + (0,) * (len(block) - 1))
+    out = pl.pallas_call(
+        functools.partial(kernel, p=p),
+        grid=(pl.cdiv(rows, block[0]),),
+        in_specs=[spec] * len(operands),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((rows,) + block[1:], x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=p.interpret,
+    )(*(view(a) for a in operands))
+    return unview(out)
+
+
+# Under GSPMD each device runs the kernel on its own pixels (a bare
+# ``pallas_call`` is gathered whole): the leading dimensions' sharding
+# stays, the channels are whole on every device.
+def _pixel_sharding(p, mesh, arg_shapes, result_shape):
+    spec = tuple(arg_shapes[0].sharding.spec)[:len(arg_shapes[0].shape) - 1]
+    return NamedSharding(mesh, PartitionSpec(*spec))
+
+
+def _partition(p, mesh, arg_shapes, result_shape):
+    s = _pixel_sharding(p, mesh, arg_shapes, result_shape)
+    return mesh, functools.partial(_lrn_call, p), s, (s,) * len(arg_shapes)
+
+
+@functools.partial(custom_partitioning, static_argnums=(1,))
+def _lrn_fwd_op(x, p: _LRNParams):
+    return _lrn_call(p, x)
+
+
+@functools.partial(custom_partitioning, static_argnums=(2,))
+def _lrn_bwd_op(x, g, p: _LRNParams):
+    return _lrn_call(p, x, g)
+
+
+for _op, _rule in ((_lrn_fwd_op, "... c -> ... c"),
+                   (_lrn_bwd_op, "... c, ... c -> ... c")):
+    _op.def_partition(
+        partition=_partition, infer_sharding_from_operands=_pixel_sharding,
+        sharding_rule=_rule, need_replication_factors=("c",))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _fused_lrn(x: jax.Array, p: _LRNParams) -> jax.Array:
+    return _lrn_fwd_op(x, p)
+
+
+def _fused_lrn_vjp_fwd(x, p: _LRNParams):
+    return _lrn_fwd_op(x, p), x  # the residual: x alone
+
+
+def _fused_lrn_vjp_bwd(p: _LRNParams, x, g):
+    return (_lrn_bwd_op(x, g.astype(x.dtype), p),)
+
+
+_fused_lrn.defvjp(_fused_lrn_vjp_fwd, _fused_lrn_vjp_bwd)
+
+
+def fused_lrn(x: jax.Array, size: int = 5, alpha: float = 1e-4,
+              beta: float = 0.75, k: float = 1.0,
+              interpret: Optional[bool] = None) -> jax.Array:
+    """Across-channel LRN (Caffe semantics, channels-last) as one fused
+    Pallas pass each way — what ``models.layers.local_response_norm``
+    runs on a TPU.  ``interpret`` forces / forbids Pallas interpreter
+    mode (None = auto: interpret off-TPU)."""
+    if interpret is None:
+        interpret = default_interpret()
+    p = _LRNParams(int(size), float(alpha), float(beta), float(k),
+                   bool(interpret))
+    return _fused_lrn(x, p)
+
+
+# -- conv epilogues ----------------------------------------------------------
+
+_BLOCK_ROWS = 256
 
 
 def _pad2d(x: jax.Array, rows: int, cols: int) -> jax.Array:
@@ -77,230 +291,14 @@ def _pad2d(x: jax.Array, rows: int, cols: int) -> jax.Array:
     return jnp.pad(x, ((0, rows - r), (0, cols - c)))
 
 
-def _win_sum(v: jax.Array, lo: int, hi: int) -> jax.Array:
-    """Channel-axis windowed sum with zero fill: out[:, i] =
-    sum_{d=-lo..hi} v[:, i+d].  Static shapes (lo+hi+1 shifted adds) —
-    the in-register form of the reduce_window the XLA reference uses.
-    Zero fill matches reduce_window's zero padding, and the zero-padded
-    channel tail (c..cpad) contributes zeros exactly like the columns
-    beyond the real C would."""
-    c = v.shape[1]
-    vp = jnp.pad(v, ((0, 0), (lo, hi)))
-    out = vp[:, 0:c]
-    for o in range(1, lo + hi + 1):
-        out = out + vp[:, o:o + c]
-    return out
-
-
-def _d_pow_negbeta(d: jax.Array, beta: float) -> jax.Array:
-    """d^-beta; beta=0.75 uses the two-fast-VPU-op identity
-    (sqrt(rsqrt(d)))^3 the XLA reference uses (models/layers.py), so
-    the kernel stays bit-comparable to it."""
-    if beta == 0.75:
-        r = jnp.sqrt(jax.lax.rsqrt(d))
-        return r * r * r
-    return jnp.exp(jnp.float32(-beta) * jnp.log(d))
-
-
-class _LRNParams(NamedTuple):
-    """Hashable nondiff bundle for the custom_vjp (trace-time config)."""
-
-    size: int
-    alpha: float
-    beta: float
-    k: float
-    cached: bool
-    interpret: bool
-
-
-# -- LRN forward/backward kernels -------------------------------------------
-
-
-def _lrn_fwd_kernel(x_ref, o_ref, *, p: _LRNParams):
-    x = x_ref[:].astype(jnp.float32)
-    win = _win_sum(x * x, p.size // 2, p.size - 1 - p.size // 2)
-    d = p.k + (p.alpha / p.size) * win
-    o_ref[:] = (x * _d_pow_negbeta(d, p.beta)).astype(o_ref.dtype)
-
-
-def _lrn_fwd_cached_kernel(x_ref, o_ref, d_ref, *, p: _LRNParams):
-    x = x_ref[:].astype(jnp.float32)
-    win = _win_sum(x * x, p.size // 2, p.size - 1 - p.size // 2)
-    d = p.k + (p.alpha / p.size) * win
-    d_ref[:] = d
-    o_ref[:] = (x * _d_pow_negbeta(d, p.beta)).astype(o_ref.dtype)
-
-
-def _lrn_bwd_kernel(x_ref, g_ref, o_ref, *, p: _LRNParams):
-    """dx from (x, g), recomputing d (cache=False).
-
-    With y_i = x_i d_i^-b and d_i = k + a * W(x^2)_i (W the forward
-    window, a = alpha/size):
-        dx_j = g_j d_j^-b - 2ab x_j * W^T(g x d^{-b-1})_j
-    where W^T is the window with (lo, hi) swapped — symmetric for odd
-    sizes, exact either way."""
-    x = x_ref[:].astype(jnp.float32)
-    g = g_ref[:].astype(jnp.float32)
-    win = _win_sum(x * x, p.size // 2, p.size - 1 - p.size // 2)
-    d = p.k + (p.alpha / p.size) * win
-    o_ref[:] = _lrn_bwd_math(x, g, d, p).astype(o_ref.dtype)
-
-
-def _lrn_bwd_cached_kernel(x_ref, g_ref, d_ref, o_ref, *, p: _LRNParams):
-    x = x_ref[:].astype(jnp.float32)
-    g = g_ref[:].astype(jnp.float32)
-    o_ref[:] = _lrn_bwd_math(x, g, d_ref[:], p).astype(o_ref.dtype)
-
-
-def _lrn_bwd_math(x, g, d, p: _LRNParams):
-    f = _d_pow_negbeta(d, p.beta)
-    # g * x * d^{-b-1}, then the TRANSPOSE window (hi, lo swapped).
-    t = _win_sum(g * x * (f / d),
-                 p.size - 1 - p.size // 2, p.size // 2)
-    return g * f - (2.0 * p.alpha / p.size * p.beta) * x * t
-
-
-def _lrn_grid(rpad: int, cpad: int):
-    """(grid, block_rows) over the PADDED row count (``_lrn_pad_geometry``
-    guarantees rpad is either < _BLOCK_ROWS or a multiple of it)."""
-    br = _BLOCK_ROWS if rpad >= _BLOCK_ROWS else rpad
-    return (rpad // br,), br
-
-
-def _lrn_fwd_call(x2: jax.Array, p: _LRNParams):
-    """Padded 2-D forward dispatch; returns (out2, d2_or_None) at the
-    PADDED geometry (the caller slices)."""
-    rows, cpad = x2.shape
-    grid, br = _lrn_grid(rows, cpad)
-    spec = pl.BlockSpec((br, cpad), lambda i: (i, 0))
-    if p.cached:
-        out2, d2 = pl.pallas_call(
-            functools.partial(_lrn_fwd_cached_kernel, p=p),
-            grid=grid,
-            in_specs=[spec],
-            out_specs=(spec, spec),
-            out_shape=(
-                jax.ShapeDtypeStruct((rows, cpad), x2.dtype),
-                jax.ShapeDtypeStruct((rows, cpad), jnp.float32),
-            ),
-            interpret=p.interpret,
-        )(x2)
-        return out2, d2
-    out2 = pl.pallas_call(
-        functools.partial(_lrn_fwd_kernel, p=p),
-        grid=grid,
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((rows, cpad), x2.dtype),
-        interpret=p.interpret,
-    )(x2)
-    return out2, None
-
-
-def _lrn_bwd_call(x2: jax.Array, g2: jax.Array, d2: Optional[jax.Array],
-                  p: _LRNParams) -> jax.Array:
-    rows, cpad = x2.shape
-    grid, br = _lrn_grid(rows, cpad)
-    spec = pl.BlockSpec((br, cpad), lambda i: (i, 0))
-    if d2 is not None:
-        return pl.pallas_call(
-            functools.partial(_lrn_bwd_cached_kernel, p=p),
-            grid=grid,
-            in_specs=[spec, spec, spec],
-            out_specs=spec,
-            out_shape=jax.ShapeDtypeStruct((rows, cpad), x2.dtype),
-            interpret=p.interpret,
-        )(x2, g2, d2)
-    return pl.pallas_call(
-        functools.partial(_lrn_bwd_kernel, p=p),
-        grid=grid,
-        in_specs=[spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((rows, cpad), x2.dtype),
-        interpret=p.interpret,
-    )(x2, g2)
-
-
-def _lrn_pad_geometry(shape) -> Tuple[int, int, int, int]:
+def _pad_geometry(shape) -> Tuple[int, int, int, int]:
     """(rows, c, rpad, cpad) of the 2-D channels-last view: channels
-    lane-padded to 128, rows padded to one 16-sublane block (small
-    inputs) or a _BLOCK_ROWS multiple (16 divides _BLOCK_ROWS, so both
-    shapes satisfy the bf16 (16, 128) min tile)."""
+    lane-padded to 128, rows to 16 sublanes (small inputs) or a
+    _BLOCK_ROWS multiple (both satisfy the bf16 (16, 128) min tile)."""
     c = shape[-1]
-    rows = 1
-    for s in shape[:-1]:
-        rows *= s
-    rows = max(rows, 1)
-    cpad = _round_up(c, _LANES)
-    if rows >= _BLOCK_ROWS:
-        rpad = _round_up(rows, _BLOCK_ROWS)
-    else:
-        rpad = _round_up(rows, 16)
-    return rows, c, rpad, cpad
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _fused_lrn(x: jax.Array, p: _LRNParams) -> jax.Array:
-    # The PRIMAL body (no-grad forwards: extract/test/eval/serve) —
-    # the denominator cache is purely a backward residual, so dispatch
-    # uncached here; only the vjp fwd below pays for (and keeps) d.
-    out, _ = _fused_lrn_fwd_impl(x, p._replace(cached=False))
-    return out
-
-
-def _fused_lrn_fwd_impl(x: jax.Array, p: _LRNParams):
-    rows, c, rpad, cpad = _lrn_pad_geometry(x.shape)
-    x2 = _pad2d(x.reshape(rows, c), rpad, cpad)
-    out2, d2 = _lrn_fwd_call(x2, p)
-    out = out2[:rows, :c].reshape(x.shape)
-    return out, d2  # d2 stays padded — the backward re-uses it as-is
-
-
-def _fused_lrn_vjp_fwd(x, p: _LRNParams):
-    out, d2 = _fused_lrn_fwd_impl(x, p)
-    return out, (x, d2)
-
-
-def _fused_lrn_vjp_bwd(p: _LRNParams, res, g):
-    x, d2 = res
-    rows, c, rpad, cpad = _lrn_pad_geometry(x.shape)
-    x2 = _pad2d(x.reshape(rows, c), rpad, cpad)
-    g2 = _pad2d(g.reshape(rows, c).astype(x.dtype), rpad, cpad)
-    dx2 = _lrn_bwd_call(x2, g2, d2, p)
-    return (dx2[:rows, :c].reshape(x.shape),)
-
-
-_fused_lrn.defvjp(_fused_lrn_vjp_fwd, _fused_lrn_vjp_bwd)
-
-
-def fused_lrn(
-    x: jax.Array,
-    size: int = 5,
-    alpha: float = 1e-4,
-    beta: float = 0.75,
-    k: float = 1.0,
-    cache: Optional[bool] = None,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Across-channel LRN (Caffe semantics, channels-last) as one fused
-    Pallas pass — drop-in for ``models.layers.local_response_norm``.
-
-    ``cache`` controls the denominator cache (None = auto by size, the
-    ops/pallas_npair sim-cache pattern); ``interpret`` forces/forbids
-    Pallas interpreter mode (None = auto: interpret off-TPU)."""
-    if interpret is None:
-        interpret = default_interpret()
-    # Budget the cache at the tensor the cached kernel ACTUALLY writes:
-    # the padded (rpad, cpad) fp32 denominator (lane padding alone is
-    # 2x at a C=64 site), not the logical x.size.
-    _, _, rpad, cpad = _lrn_pad_geometry(x.shape)
-    cached = resolve_lrn_cache_auto(rpad * cpad * 4, cache)
-    p = _LRNParams(int(size), float(alpha), float(beta), float(k),
-                   bool(cached), bool(interpret))
-    return _fused_lrn(x, p)
-
-
-# -- conv epilogues ----------------------------------------------------------
+    rows = max(math.prod(shape[:-1]), 1)
+    rpad = _round_up(rows, _BLOCK_ROWS if rows >= _BLOCK_ROWS else 16)
+    return rows, c, rpad, _round_up(c, _LANES)
 
 
 def _bias_relu_kernel(x_ref, b_ref, o_ref):
@@ -315,13 +313,13 @@ class _EpiParams(NamedTuple):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _fused_bias_relu(x: jax.Array, bias: jax.Array,
                      p: _EpiParams) -> jax.Array:
-    rows, c, rpad, cpad = _lrn_pad_geometry(x.shape)
+    rows, c, rpad, cpad = _pad_geometry(x.shape)
     x2 = _pad2d(x.reshape(rows, c), rpad, cpad)
     b2 = _pad2d(bias.reshape(1, c), 1, cpad)
-    grid, br = _lrn_grid(rpad, cpad)
+    br = min(rpad, _BLOCK_ROWS)
     out2 = pl.pallas_call(
         _bias_relu_kernel,
-        grid=grid,
+        grid=(rpad // br,),
         in_specs=[
             pl.BlockSpec((br, cpad), lambda i: (i, 0)),
             pl.BlockSpec((1, cpad), lambda i: (0, 0)),

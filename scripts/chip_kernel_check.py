@@ -4,11 +4,16 @@ the chip, and check each against its XLA reference.
     chiprun -- python scripts/chip_kernel_check.py [--only SUBSTR ...]
 
 The cases are ``npairloss_tpu.testing.pallas_cases`` (blockwise pool
-4096x512 fwd+grad x4 configs, the stem kernels at batch 120, the IVF
-probe at the 1M geometry x3 dtypes + its shard-local form).  Exits
+4096x512 fwd+grad x4 configs, LRN at the benchmark's batch 480 and
+batch 1, the conv epilogues at batch 120, the IVF probe at the 1M
+geometry x3 dtypes + its shard-local form).  Exits
 non-zero when the backend is not a TPU or any case fails to compile or
 misses parity; one JSON line per case, a summary line last, and the
 whole record under ``chiprun_out/kernel_check.json``.
+
+``--sharded`` (several chips) instead runs LRN forward + backward with
+the batch sharded over every device: no collective in the compiled
+program, every device its own kernel, the one-device result.
 
 ``--aot TOPOLOGY`` (e.g. ``v5e:2x2``) needs no chip: it compiles every
 case against libtpu's description of that topology, so Mosaic accepts
@@ -27,8 +32,47 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
+def sharded_lrn(seed: int) -> dict:
+    """LRN under GSPMD on every device: the custom_partitioning rule of
+    ``ops.pallas_stem`` on real chips."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from npairloss_tpu.ops.pallas_stem import fused_lrn
+
+    devs = jax.devices()
+    sh = NamedSharding(Mesh(np.asarray(devs), ("dp",)), P("dp"))
+
+    def fwd_bwd(x, g):
+        y, vjp = jax.vjp(lambda v: fused_lrn(v, interpret=False), x)
+        return y, vjp(g)[0]
+
+    out = {}
+    rng = np.random.default_rng(seed)
+    for shape in ((480, 56, 56, 64), (8 * len(devs), 56, 56, 192)):
+        x, g = (rng.standard_normal(shape, dtype=np.float32)
+                .astype(jnp.bfloat16) for _ in range(2))
+        fn = jax.jit(fwd_bwd, in_shardings=(sh, sh), out_shardings=(sh, sh))
+        text = fn.lower(x, g).compile().as_text()
+        assert "tpu_custom_call" in text, "no Mosaic kernel"
+        for op in ("all-gather", "all-reduce", "all-to-all"):
+            assert op not in text, f"{op} around the sharded LRN"
+        got = fn(jax.device_put(x, sh), jax.device_put(g, sh))
+        want = jax.jit(fwd_bwd)(x, g)
+        err = max(float(jnp.abs(a.astype(jnp.float32)
+                                - b.astype(jnp.float32)).max())
+                  for a, b in zip(got, want))
+        assert err <= 2 ** -5, (shape, err)  # a bf16 ulp
+        out["x".join(map(str, shape))] = err
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--sharded", action="store_true",
+                    help="LRN with the batch sharded over every device")
     ap.add_argument("--only", nargs="*", default=[],
                     help="run cases whose name contains any of these")
     ap.add_argument("--aot", metavar="TOPOLOGY", default=None,
@@ -64,6 +108,13 @@ def main() -> int:
             print(json.dumps({"ok": False, "error": "no TPU found",
                               **record["device"]}))
             return 1
+
+    if args.sharded:
+        if args.aot or len(jax.devices()) < 2:
+            sys.exit("--sharded needs several chips (and no --aot)")
+        print(json.dumps({"sharded_lrn_max_abs": sharded_lrn(args.seed),
+                          **record["device"]}))
+        return 0
 
     failed = 0
     for case in cases:

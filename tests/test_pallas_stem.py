@@ -1,26 +1,52 @@
 """Interpret-mode parity tests for the fused stem kernels
-(ops.pallas_stem vs the XLA references) — forward AND backward, ragged
-tile shapes included, plus the ConvBlock/GoogLeNet wiring contracts
-(parameter-tree interchange with the plain path).
+(ops.pallas_stem vs the XLA references) — forward AND backward, both LRN
+views, ragged shapes included, plus the ConvBlock/GoogLeNet wiring
+contracts (parameter-tree interchange with the plain path).
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from npairloss_tpu.models.layers import ConvBlock, local_response_norm
+from npairloss_tpu.models import layers
+from npairloss_tpu.models.layers import (
+    ConvBlock,
+    local_response_norm,
+    local_response_norm_xla,
+)
+from npairloss_tpu.obs import tracing
 from npairloss_tpu.ops import pallas_stem as ps
 
-# Shapes chosen to hit: full lane tiles (64->128 pad), multi-lane-tile
-# channels with a ragged edge (130), sub-tile channels (24), ragged row
-# counts (odd H*W products), and a row count above one block (>256).
-LRN_SHAPES = [
+# ``rows`` view (channels on the lanes, a pixel a row): sub-tile, exact
+# and ragged lane counts (24, 64, 130, 192, 200), channels that do not
+# divide 128 (48, 200), row counts of one chunk (7), several chunks and
+# a ragged last block (300; serving's 3,136 x 1 and x 3).
+ROWS_SHAPES = [
     (2, 7, 7, 24),
     (1, 5, 3, 64),
     (2, 3, 9, 130),
-    (3, 10, 10, 8),  # 300 rows > one 256-row block
+    (3, 10, 10, 8),
+    (2, 4, 4, 192),
+    (2, 3, 3, 200),
+    (7, 1, 1, 48),
+    (1, 56, 56, 64),
+    (3, 56, 56, 64),
 ]
+# ``cols`` view (the batch on the lanes, channels on the sublanes): one
+# channel chunk (48, 64), three with real halos (192), a ragged last
+# chunk (200), a batch that is not a lane multiple (100), more pixels
+# than one block holds (6 x 6 at C = 192).
+COLS_SHAPES = [
+    (128, 2, 2, 64),
+    (100, 1, 3, 192),
+    (128, 1, 2, 48),
+    (128, 2, 1, 200),
+    (256, 6, 6, 192),
+]
+LRN_SHAPES = ROWS_SHAPES + COLS_SHAPES
+DTYPES = [jnp.float32, jnp.bfloat16]
 
 
 def _rand(shape, seed=0, dtype=np.float32):
@@ -28,54 +54,191 @@ def _rand(shape, seed=0, dtype=np.float32):
         np.random.default_rng(seed).standard_normal(shape).astype(dtype))
 
 
-@pytest.mark.parametrize("shape", LRN_SHAPES)
-def test_fused_lrn_forward_parity(shape):
-    x = _rand(shape)
-    ref = local_response_norm(x)
-    for cache in (True, False):
-        out = ps.fused_lrn(x, cache=cache)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-6, rtol=1e-6)
+def _lrn(x, **kw):
+    return ps.fused_lrn(x, interpret=True, **kw)
 
 
+def _f32(a):
+    return np.asarray(a, dtype=np.float32)
+
+
+def _tol(dtype):
+    # float32: the reference's own power, summed in another order;
+    # bf16: one ulp of the rounded result (the power there is exp2 of log).
+    return dict(atol=2e-6, rtol=2e-6) if dtype == jnp.float32 else dict(
+        atol=2 ** -8, rtol=2 ** -7)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
 @pytest.mark.parametrize("shape", LRN_SHAPES)
-def test_fused_lrn_backward_parity_and_cache_bitparity(shape):
-    x = _rand(shape, seed=1)
+def test_fused_lrn_forward_parity(shape, dtype):
+    x = _rand(shape).astype(dtype)
+    out = _lrn(x)
+    assert out.dtype == dtype and out.shape == x.shape
+    np.testing.assert_allclose(_f32(out), _f32(local_response_norm_xla(x)),
+                               **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+@pytest.mark.parametrize("shape", LRN_SHAPES)
+def test_fused_lrn_backward_parity(shape, dtype):
+    x = _rand(shape, seed=1).astype(dtype)
     w = jnp.cos(jnp.arange(x.size, dtype=jnp.float32).reshape(x.shape))
-    g_ref = jax.grad(lambda v: (local_response_norm(v) * w).sum())(x)
-    g_c = jax.grad(lambda v: (ps.fused_lrn(v, cache=True) * w).sum())(x)
-    g_n = jax.grad(lambda v: (ps.fused_lrn(v, cache=False) * w).sum())(x)
-    np.testing.assert_allclose(np.asarray(g_c), np.asarray(g_ref),
-                               atol=1e-5, rtol=1e-4)
-    # Cached and recompute backward are BIT-identical (the cache stores
-    # exactly the fp32 d the forward produced — the sim-cache contract).
-    np.testing.assert_array_equal(np.asarray(g_c), np.asarray(g_n))
+    loss = lambda f: lambda v: (f(v).astype(jnp.float32) * w).sum()
+    g_ref = jax.grad(loss(local_response_norm_xla))(x)
+    g = jax.grad(loss(_lrn))(x)
+    assert g.dtype == dtype
+    tol = dict(atol=1e-5, rtol=1e-4) if dtype == jnp.float32 else _tol(dtype)
+    np.testing.assert_allclose(_f32(g), _f32(g_ref), **tol)
 
 
-def test_fused_lrn_generic_beta_and_params():
-    """The non-0.75-beta path (exp/log pow) and non-default size/k."""
-    x = _rand((2, 4, 4, 24), seed=2)
-    ref = local_response_norm(x, size=3, alpha=2e-3, beta=0.5, k=2.0)
-    out = ps.fused_lrn(x, size=3, alpha=2e-3, beta=0.5, k=2.0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-6, rtol=1e-5)
+@pytest.mark.parametrize("shape", [(2, 4, 4, 24), (128, 2, 2, 24)],
+                         ids=["rows", "cols"])
+def test_fused_lrn_generic_beta_and_params(shape):
+    """Non-default beta / k, and an EVEN window (lo != hi: the backward
+    needs the transposed window, not the same one)."""
+    x = _rand(shape, seed=2)
+    w = _rand(shape, seed=3)
+    for kw in (dict(size=3, alpha=2e-3, beta=0.5, k=2.0),
+               dict(size=4, alpha=5e-2, beta=1.25, k=1.5)):
+        ref = lambda v: local_response_norm_xla(v, **kw)
+        np.testing.assert_allclose(_f32(_lrn(x, **kw)), _f32(ref(x)),
+                                   atol=2e-6, rtol=1e-5)
+        g = jax.grad(lambda v: (_lrn(v, **kw) * w).sum())(x)
+        g_ref = jax.grad(lambda v: (ref(v) * w).sum())(x)
+        np.testing.assert_allclose(_f32(g), _f32(g_ref),
+                                   atol=1e-5, rtol=1e-4)
 
 
-def test_fused_lrn_bf16_dtype_roundtrip():
+@pytest.mark.parametrize("batch", [2, 128], ids=["rows", "cols"])
+@pytest.mark.parametrize("channel", [0, 63])
+def test_fused_lrn_window_stops_at_the_pixel(batch, channel):
+    """A one-hot pixel at the first / last channel: the window is zero
+    filled at the channel edges, so nothing leaks into the neighbouring
+    pixel's channels 0-1 / 62-63 (which sit next to it in a packed row,
+    a flattened view or a wrapped rotation), forward or backward."""
+    shape = (batch, 1, 4, 64)
+    x = jnp.zeros(shape).at[1, 0, 2, channel].set(100.0)
+    y = _lrn(x)
+    hot = np.zeros(shape, bool)
+    hot[1, 0, 2, channel] = True
+    assert float(y[1, 0, 2, channel]) > 0
+    assert not np.asarray(y)[~hot].any()
+    # backward: a cotangent on every OTHER pixel reaches no gradient of
+    # the hot one; the hot pixel's own window does.
+    x = x + 1.0
+    w = jnp.asarray(~hot.any(axis=-1, keepdims=True), jnp.float32)
+    g = jax.grad(lambda v: (_lrn(v) * w).sum())(x)
+    g_ref = jax.grad(lambda v: (local_response_norm_xla(v) * w).sum())(x)
+    assert not np.asarray(g)[1, 0, 2].any()
+    np.testing.assert_allclose(_f32(g), _f32(g_ref), atol=1e-6, rtol=1e-5)
+
+
+def _kernel_instants(shapes):
+    """The ``lrn/kernel`` instants of tracing the kernel at ``shapes``
+    (bf16).  They fire where ``_lrn_call`` is traced, so its jit cache
+    is dropped first: no dependence on which tests ran before."""
+    ps._lrn_call.clear_cache()
+    tr = tracing.SpanTracer()
+    prev = tracing.install(tr)
+    try:
+        for shape in shapes:
+            jax.eval_shape(_lrn, jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+    finally:
+        tracing.install(prev)
+    return [e["args"] for e in tr.to_chrome_trace()["traceEvents"]
+            if e["name"] == "lrn/kernel"]
+
+
+def test_fused_lrn_view_follows_the_lane_padding():
+    """The batch goes on the lanes exactly where it pads them less than
+    the channels do — XLA's own choice at these shapes (PERF.md PR 26) —
+    and the instant says which body a program got."""
+    got = _kernel_instants([
+        (480, 2, 2, 64), (120, 2, 2, 192), (96, 2, 2, 64), (96, 2, 2, 192),
+        (64, 2, 2, 64), (8, 2, 2, 192), (1, 2, 2, 64)])
+    assert [a["view"] for a in got] == [
+        "cols", "cols", "cols", "rows", "rows", "rows", "rows"]
+    assert got[0]["rows"] == 4 and got[0]["lanes"] == 480
+    assert got[-1]["rows"] == 4 and got[-1]["lanes"] == 64
+    assert all(a["block_rows"] >= 1 and not a["backward"] for a in got)
+
+
+def test_fused_lrn_blocks_are_sized_from_the_shape():
+    """About a megabyte a block at the cell's two sites (no parameter):
+    16 and 5 pixels of (C, 480 -> 512 lanes) bf16."""
+    a, b, c = _kernel_instants([
+        (480, 56, 56, 64), (480, 56, 56, 192), (1, 56, 56, 192)])
+    assert (a["rows"], a["block_rows"]) == (3136, 16)
+    assert (b["rows"], b["block_rows"]) == (3136, 5)
+    assert (c["view"], c["rows"], c["block_rows"]) == ("rows", 3136, 2048)
+
+
+def test_fused_lrn_vjp_saves_x_alone():
+    """The residual of the VJP is x in its own dtype: the denominator is
+    recomputed, nothing float32 of the activation's size survives the
+    forward (the XLA body saves six such tensors)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
     x = _rand((2, 4, 4, 32)).astype(jnp.bfloat16)
-    out = ps.fused_lrn(x)
-    assert out.dtype == jnp.bfloat16
-    ref = local_response_norm(x)  # fp32 internals, bf16 out — same shape
+    res = saved_residuals(_lrn, x)
+    assert [(a.shape, a.dtype) for a, _ in res] == [(x.shape, x.dtype)]
+    ref = saved_residuals(local_response_norm_xla, x)
+    assert sum(a.dtype == jnp.float32 and a.shape == x.shape
+               for a, _ in ref) >= 2
+
+
+def test_local_response_norm_routing(monkeypatch):
+    """Off the TPU the entry point IS the reduce_window body, bit for
+    bit, and never touches the kernel; on a TPU backend it runs the
+    kernel, and the two agree."""
+    x = _rand((2, 4, 4, 16), seed=9)
+    called, kernel = [], ps.fused_lrn
+    monkeypatch.setattr(
+        ps, "fused_lrn",
+        lambda *a: called.append(a) or kernel(*a, interpret=True))
+    np.testing.assert_array_equal(
+        np.asarray(local_response_norm(x)),
+        np.asarray(local_response_norm_xla(x)))
+    assert not called
+    monkeypatch.setattr(layers.jax, "default_backend", lambda: "tpu")
     np.testing.assert_allclose(
-        np.asarray(out, dtype=np.float32), np.asarray(ref, np.float32),
-        atol=2e-2)
+        np.asarray(local_response_norm(x, 3, 2e-3, 0.5, 2.0)),
+        np.asarray(local_response_norm_xla(x, 3, 2e-3, 0.5, 2.0)),
+        atol=2e-6, rtol=1e-5)
+    assert len(called) == 1
 
 
-def test_lrn_cache_auto_threshold():
-    assert ps.resolve_lrn_cache_auto(ps.LRN_CACHE_AUTO_BYTES, None)
-    assert not ps.resolve_lrn_cache_auto(ps.LRN_CACHE_AUTO_BYTES + 1, None)
-    assert ps.resolve_lrn_cache_auto(1 << 40, True)  # explicit wins
-    assert not ps.resolve_lrn_cache_auto(1, False)
+@pytest.mark.parametrize("shape,spec", [
+    ((8, 4, 4, 64), P("dp")),
+    ((512, 2, 2, 64), P("dp")),
+    ((4, 4, 4, 64), P("dp", "sp")),
+], ids=["rows", "cols", "rows_2d"])
+def test_fused_lrn_partitions_by_pixels(shape, spec):
+    """Under GSPMD the call keeps whatever shards the pixel dimensions
+    (channels whole): no all-gather / all-reduce in the compiled forward
+    + backward, each device runs the kernel on its own pixels, and the
+    result is the single-device one."""
+    devs = np.asarray(jax.devices()[:4])
+    mesh = Mesh(devs.reshape(2, 2), ("dp", "sp")) if len(spec) > 1 \
+        else Mesh(devs, ("dp",))
+    x, g = _rand(shape, seed=4), _rand(shape, seed=5)
+
+    def fwd_bwd(x, g):
+        y, vjp = jax.vjp(_lrn, x)
+        return y, vjp(g)[0]
+
+    want = fwd_bwd(x, g)
+    sh = NamedSharding(mesh, spec)
+    fn = jax.jit(fwd_bwd, in_shardings=(sh, sh), out_shardings=(sh, sh))
+    text = fn.lower(x, g).compile().as_text()
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text
+    got = fn(jax.device_put(x, sh), jax.device_put(g, sh))
+    for a, b in zip(got, want):
+        assert a.sharding.is_equivalent_to(sh, a.ndim)
+        np.testing.assert_allclose(_f32(a), _f32(b), atol=1e-6, rtol=1e-6)
 
 
 def test_fused_bias_relu_parity():
@@ -177,15 +340,6 @@ def test_convblock_fused_epilogue_ignored_under_bn():
     ref = ConvBlock(8, (3, 3), use_bn=True)
     np.testing.assert_array_equal(
         np.asarray(bn.apply(v, x)), np.asarray(ref.apply(v, x)))
-
-
-def test_local_response_norm_impl_routing():
-    x = _rand((2, 4, 4, 16), seed=9)
-    np.testing.assert_allclose(
-        np.asarray(local_response_norm(x, impl="pallas")),
-        np.asarray(local_response_norm(x)), atol=1e-6)
-    with pytest.raises(ValueError, match="impl"):
-        local_response_norm(x, impl="cuda")
 
 
 @pytest.mark.slow

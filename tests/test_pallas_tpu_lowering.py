@@ -22,9 +22,10 @@ def test_case_table_covers_every_entry_point():
     names = {c.name for c in CASES}
     for cfg in ("abs", "flagship", "flagship_radix", "flagship_nocache"):
         assert {f"blockwise_{cfg}_fwd", f"blockwise_{cfg}_grad"} <= names
-    for c in (64, 192):
-        assert {f"lrn_fwd_c{c}", f"lrn_grad_cached_c{c}",
-                f"lrn_grad_recompute_c{c}"} <= names
+    for tag in ("n480_c64_bfloat16", "n480_c192_bfloat16",
+                "n1_c64_bfloat16", "n1_c192_bfloat16",
+                "n120_c64_float32", "n8_c192_float32"):
+        assert {f"lrn_fwd_{tag}", f"lrn_grad_{tag}"} <= names
     assert {"bias_relu", "bias_relu_pool", "probe_fp32", "probe_bf16",
             "probe_int8", "probe_fp32_shard_local"} <= names
 
