@@ -1,16 +1,56 @@
 """Adapter for the ``googlenet_v1`` family: the program's flagship trunk
 (``googlenet_mxu``: space-to-depth stem, fused inception 1x1s) beside
 the plain reference, and the two-way map between their parameter
-layouts.  Both maps are exact re-arrangements (no arithmetic)."""
+layouts.  Both maps are exact re-arrangements (no arithmetic).
+
+An adapter is everything the harness asks of a family, as explicit
+functions (a family that lacks one fails on the attribute, no default
+decides for it): what an input is (``input_shape``, ``warm_inputs``,
+``query_pool``, ``train_batches``), what one input's forward pass
+requires (``forward_flops``), the weights (``shapes``, ``init_scales``,
+``post_init``, laid out by ``to_program`` / ``from_program``), the
+program's model (``build_model``) and the plain reference (``embed``).
+``post_init`` is handed one of ``weights.make_params``' groups of whole
+layers (here always the whole tree: 28 MB), traced in float32 inside
+the group's jitted call, before the cast to ``precision.params``."""
 
 from __future__ import annotations
 
 import numpy as np
 
+from benchmarks.harness import weights
 from benchmarks.reference import googlenet as ref
 
 FUSED = ("b1x1", "b3x3_reduce", "b5x5_reduce")
 embed = ref.embed
+
+
+def input_shape(cfg):
+    """One input, as ``Solver`` and ``RetrievalServer`` take it."""
+    return (cfg["image_size"], cfg["image_size"], cfg["num_channels"])
+
+
+def warm_inputs(cfg, mix):
+    """What ``QueryEngine.warmup`` is called on, once each: every image
+    has the one shape, so one call warms every bucket's program."""
+    return [input_shape(cfg)]
+
+
+def query_pool(cfg, mix, seed):
+    """The serving pool: one unit-normal image for each key."""
+    return weights.normal_pool(seed, mix["pool_images"], input_shape(cfg))
+
+
+def train_batches(cfg, mix, seed):
+    """The staged pool of identity-balanced batches: (images, labels)."""
+    return weights.identity_batches(seed, mix["pool_batches"], mix["identities"],
+                                    mix["per_identity"], input_shape(cfg))
+
+
+def forward_flops(cfg, x=None):
+    """Operations one image's forward pass requires; every image of a
+    configuration has the one shape, so ``x`` tells nothing more."""
+    return ref.forward_flops(cfg["image_size"], cfg["num_channels"])
 
 
 def shapes(cfg):
@@ -44,11 +84,11 @@ def post_init(params):
     of errors moderate, as in a trained network.  The stem's eighth row
     and column of taps start at zero (see the reference)."""
     for name, leaf in params.items():
-        if name != "conv1":
-            k = leaf["kernel"]
+        k = leaf["kernel"]
+        if name == "conv1":
+            leaf["kernel"] = k.at[7, :, :, :].set(0.0).at[:, 7, :, :].set(0.0)
+        else:
             leaf["kernel"] = k - CENTRE * k.mean(axis=(0, 1, 2), keepdims=True)
-    k = params["conv1"]["kernel"]
-    params["conv1"]["kernel"] = k.at[7, :, :, :].set(0.0).at[:, 7, :, :].set(0.0)
     return params
 
 

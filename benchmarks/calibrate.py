@@ -121,7 +121,7 @@ def train(cell, devices, args):
         seed = args.first_seed + 7919 * k
         t0 = time.time()
         params0, feed = train_window.seeded_inputs(cell, seed)
-        images, labels = feed.images, feed.labels
+        images, labels = feed.inputs, feed.labels
         host0, prog = train_window.first_steps(solver, cell, params0, feed)
         solver.state = None
         t1 = time.time()
@@ -147,14 +147,12 @@ def serve(cell, devices, args):
     import jax.numpy as jnp
     import numpy as np
 
-    from npairloss_tpu.serve.engine import EngineConfig, QueryEngine
+    from npairloss_tpu.serve.engine import QueryEngine
 
     from benchmarks.harness import run_serve, serve_window, weights
     from benchmarks.reference import retrieval
 
     cfg, mix, adapter = cell.config, cell.traffic, cell.adapter
-    size = cfg["image_size"]
-    shape = (size, size, cfg["num_channels"])
     server, ctx = serve_window.build_server(cell, args.first_seed, False)
     e = mix["engine"]
     top_k = e["top_k"]
@@ -162,17 +160,16 @@ def serve(cell, devices, args):
     fewer = {}
     if mix["gallery"]["index"] == "ivf" and args.controls:
         for probes in args.probes:
-            fewer[probes] = QueryEngine(ctx["index"], EngineConfig(
-                top_k=top_k, buckets=(32,), probes=probes, scoring=e["scoring"],
-                probe_impl=e["probe_impl"]))
+            fewer[probes] = QueryEngine(ctx["index"], serve_window.engine_config(
+                dict(e, buckets=[32], probes=probes)))
             fewer[probes].warmup()
     loop = serve_window.open_window if mix["loop"] == "open" \
         else serve_window.closed_window
     for k in range(args.seeds):
         seed = args.first_seed + 7919 * k
         params = weights.make_params(adapter, cfg, seed)
-        ctx["host_params"] = jax.tree_util.tree_map(np.asarray, params)
-        ctx["pool"] = pool = weights.image_pool(seed, mix["pool_images"], shape)
+        ctx["host_params"] = weights.widened(params)
+        ctx["pool"] = pool = adapter.query_pool(cfg, mix, seed)
         server.engine.state = {"params": adapter.to_program(params, xp=jnp),
                                "batch_stats": {}}
         ledger, win = loop(server, ctx, mix, seed, args.window)
@@ -183,15 +180,13 @@ def serve(cell, devices, args):
             # the control need not serve: the reference in the lower
             # precision puts its own ten first; read them as answers
             p = jax.tree_util.tree_map(jnp.asarray, ctx["host_params"])
-            low = jax.jit(lambda pp, x: adapter.embed(
-                pp, x, quant=cfg["precision"]["control"]))
-            emb = np.concatenate([np.asarray(low(p, jnp.asarray(pool[i:i + 32])))
-                                  for i in range(0, len(pool), 32)])
+            emb = run_serve.embed_pool(adapter, p, pool, 32,
+                                       quant=cfg["precision"]["control"])
             s, r = retrieval.exact_topk(emb, ctx["gallery"], top_k)
             row["control"] = run_serve.serve_numbers(
                 run_serve.as_answers(r, s), ctx, cell, top_k)
             if fewer:
-                emb = np.concatenate([server.engine.encode(pool[i:i + 32])
+                emb = np.concatenate([server.engine.encode(np.stack(pool[i:i + 32]))
                                       for i in range(0, len(pool), 32)])
             for probes, engine in fewer.items():
                 out = engine.query(emb)

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import collections
 import gc
 import time
 import types
@@ -24,9 +25,28 @@ def as_answers(rows, scores):
                 for rr, ss in zip(rows, scores)])
 
 
-def serve_numbers(ledger, ctx, cell, top_k, quant=None):
+def embed_pool(adapter, params, pool, block, quant=None):
+    """The plain reference's embedding of every pool entry, rows in key
+    order.  Entries may differ in shape and dtype: they are grouped by
+    both, and each group goes through ``adapter.embed`` stacked in blocks
+    of ``block``."""
+    embed = jax.jit(lambda p, x: adapter.embed(p, x, quant=quant))
+    groups = {}
+    for i, x in enumerate(pool):
+        groups.setdefault((x.shape, str(x.dtype)), []).append(i)
+    rows = [None] * len(pool)
+    for keys in groups.values():
+        for lo in range(0, len(keys), block):
+            part = keys[lo:lo + block]
+            emb = np.asarray(embed(params, jnp.asarray(np.stack([pool[i] for i in part]))))
+            for i, row in zip(part, emb):
+                rows[i] = row
+    return np.stack(rows)
+
+
+def serve_numbers(ledger, ctx, cell, top_k):
     """Every answer of the window against the plain reference: the
-    reference embeds the image pool in float32 and scores it exactly
+    reference embeds the pool in float32 (``embed_pool``) and scores it exactly
     against the whole gallery; each served neighbour's score
     (``score_gap``) and rank (``rank_gap``: how far the k-th served score
     lies under the reference's k-th) are held to that, and so is the
@@ -35,10 +55,8 @@ def serve_numbers(ledger, ctx, cell, top_k, quant=None):
     from benchmarks.reference import retrieval
 
     params = jax.tree_util.tree_map(jnp.asarray, ctx["host_params"])
-    embed = jax.jit(lambda p, x: cell.adapter.embed(p, x, quant=quant))
-    pool, block = ctx["pool"], cell.traffic.get("reference_block", 32)
-    emb = np.concatenate([np.asarray(embed(params, jnp.asarray(pool[i:i + block])))
-                          for i in range(0, len(pool), block)])
+    emb = embed_pool(cell.adapter, params, ctx["pool"],
+                     cell.traffic.get("reference_block", 32))
     ref_s, ref_r = retrieval.exact_topk(emb, ctx["gallery"], top_k)
     rows_n = ctx["gallery"].shape[0]
     bad, keys, ids, scores = 0, [], [], []
@@ -69,9 +87,11 @@ def serve_numbers(ledger, ctx, cell, top_k, quant=None):
     return numbers
 
 
-def tally(cell, ledger, win, batches, cap):
+def tally(cell, ledger, win, batches, cap, pool):
     """What one window did, for the readers: answers, batches, the work
-    they required (``harness/counts.py``) and the host-clock numbers."""
+    they required (each answered request's own input by the adapter's
+    ``forward_flops``, the search by ``harness/counts.py``) and the
+    host-clock numbers."""
     mix, g = cell.traffic, cell.traffic["gallery"]
     top_k = mix["engine"]["top_k"]
     ok = {i for i, a in enumerate(ledger.answer)
@@ -81,7 +101,9 @@ def tally(cell, ledger, win, batches, cap):
            for i in range(len(ledger.answer))]
     rows = len(ok)
     dim = cell.config["embedding_dim"]
-    enc = counts.forward_flops(cell.config) * rows
+    asked = collections.Counter(ledger.key[i] for i in ok)
+    enc = sum(n * cell.adapter.forward_flops(cell.config, pool[key])
+              for key, n in asked.items())
     if g["index"] == "ivf":
         s_flops, s_bytes = counts.probe_cost(
             rows, mix["engine"]["probes"], g["rows"] / g["clusters"], dim, 0)
@@ -126,7 +148,7 @@ def run(cell, devices, args, process_start) -> int:
     compiles_after = server.engine.compiles_after_warmup
     server.replicaset.close(drain=True)
     dev = device.device_report(devices)
-    tallies = [dict(tally(cell, *r, ctx["cap"]), window=dict(r[1], steps=r[2]))
+    tallies = [dict(tally(cell, *r, ctx["cap"], ctx["pool"]), window=dict(r[1], steps=r[2]))
                for r in runs]
     host = tallies[0]
     metrics_all = {

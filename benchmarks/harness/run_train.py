@@ -34,12 +34,12 @@ def run(cell, devices, args, process_start) -> int:
         "setup_s": setup_s,
     }
     spans = telemetry.tracer.events_since(span0)[0] if telemetry else []
-    images, labels = feed.images, feed.labels
+    inputs, labels = feed.inputs, feed.labels
     # free the program's state before the reference runs
     solver.state = None
     solver = None
     gc.collect()
-    ref = train_window.reference_numbers(cell, host0, images, labels)
+    ref = train_window.reference_numbers(cell, host0, inputs, labels)
     numbers, notes = compare.training_numbers(prog, ref)
     checks, correct = compare.judge(numbers, tr["limits"])
     correct = correct and win["steps"] > 0

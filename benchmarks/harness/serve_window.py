@@ -1,4 +1,4 @@
-"""Serving windows: the program's ``RetrievalServer.submit`` on raw-image
+"""Serving windows: the program's ``RetrievalServer.submit`` on raw-input
 records, in process, one replica, under an open loop at a fixed rate or
 a closed loop of callers.  Every request is timed by the harness itself,
 an open-loop request from when it was DUE.
@@ -19,17 +19,36 @@ from npairloss_tpu.serve.batcher import QueueFullError
 from benchmarks.harness import tracing, traffic, weights
 
 
+def _fields(d):
+    return {k: tuple(v) if isinstance(v, list) else v for k, v in d.items()}
+
+
+def engine_config(engine: dict):
+    """``EngineConfig`` from EVERY key of a mix's ``engine`` dict (lists
+    as tuples): a key the program learns later reaches it from the data
+    file alone, and one it lacks is the dataclass's own error."""
+    from npairloss_tpu.serve.engine import EngineConfig
+
+    return EngineConfig(**_fields(engine))
+
+
+def batcher_config(mix: dict):
+    """``BatcherConfig`` from every key of the mix's ``batcher`` dict;
+    ``max_batch`` is the engine's last bucket."""
+    from npairloss_tpu.serve.batcher import BatcherConfig
+
+    return BatcherConfig(max_batch=mix["engine"]["buckets"][-1],
+                         **_fields(mix["batcher"]))
+
+
 def build_server(cell, seed, trace: bool):
     """(server, context) with the engine warmed on the cell's own shapes."""
-    from npairloss_tpu.serve.batcher import BatcherConfig
-    from npairloss_tpu.serve.engine import EngineConfig, QueryEngine
+    from npairloss_tpu.serve.engine import QueryEngine
     from npairloss_tpu.serve.index import GalleryIndex
     from npairloss_tpu.serve.ivf import IVFIndex
     from npairloss_tpu.serve.server import RetrievalServer, ServerConfig
 
     cfg, mix, adapter = cell.config, cell.traffic, cell.adapter
-    size = cfg["image_size"]
-    shape = (size, size, cfg["num_channels"])
     g = mix["gallery"]
     gallery, glabels = weights.mixture_gallery(
         g["seed"], g["rows"], cfg["embedding_dim"], g["centres"])
@@ -39,28 +58,22 @@ def build_server(cell, seed, trace: bool):
     else:
         index = GalleryIndex.build(gallery, glabels, normalize=False)
     params = weights.make_params(adapter, cfg, seed)
-    host_params = jax.tree_util.tree_map(np.asarray, params)
+    host_params = weights.widened(params)
     state = {"params": adapter.to_program(params, xp=jnp), "batch_stats": {}}
-    e = mix["engine"]
     qtracer = None
     if trace:
         from npairloss_tpu.obs.qtrace.core import QueryTracer
 
         qtracer = QueryTracer()
-    engine = QueryEngine(
-        index, EngineConfig(top_k=e["top_k"], buckets=tuple(e["buckets"]),
-                            probes=e.get("probes", 8), scoring=e["scoring"],
-                            probe_impl=e.get("probe_impl", "scan")),
-        model=adapter.build_model(cfg), state=state)
-    engine.warmup(shape)
-    b = mix["batcher"]
+    engine = QueryEngine(index, engine_config(mix["engine"]),
+                         model=adapter.build_model(cfg), state=state)
+    for x in adapter.warm_inputs(cfg, mix):
+        engine.warmup(x)
     server = RetrievalServer(
-        engine, BatcherConfig(max_batch=e["buckets"][-1],
-                              max_delay_ms=b["max_delay_ms"],
-                              max_queue=b["max_queue"]),
-        ServerConfig(metrics_window=0), input_shape=shape, qtrace=qtracer)
+        engine, batcher_config(mix), ServerConfig(metrics_window=0),
+        input_shape=adapter.input_shape(cfg), qtrace=qtracer)
     server.replicaset.start()
-    pool = weights.image_pool(seed, mix["pool_images"], shape)
+    pool = adapter.query_pool(cfg, mix, seed)
     ctx = {"gallery": gallery, "host_params": host_params, "pool": pool,
            "qtracer": qtracer, "index": index,
            "cap": getattr(getattr(index, "layout", None), "cap", None)}

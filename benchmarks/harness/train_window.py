@@ -23,8 +23,8 @@ class Feed:
     out listed batches; ``until`` cycles the pool while the clock is short
     of the deadline.  Only the staging thread calls ``__next__``."""
 
-    def __init__(self, images, labels):
-        self.images, self.labels = images, labels
+    def __init__(self, inputs, labels):
+        self.inputs, self.labels = inputs, labels
         self.order, self.deadline, self.i = [], None, 0
 
     def play(self, order):
@@ -44,9 +44,9 @@ class Feed:
         else:
             if time.perf_counter() >= self.deadline:
                 raise StopIteration
-            j = self.i % len(self.images)
+            j = self.i % len(self.inputs)
             self.i += 1
-        return self.images[j], self.labels[j]
+        return self.inputs[j], self.labels[j]
 
 
 def build_solver(cell, devices, telemetry=None):
@@ -74,10 +74,9 @@ def build_solver(cell, devices, telemetry=None):
         from npairloss_tpu.parallel.mesh import data_parallel_mesh
 
         mesh = data_parallel_mesh(devices)
-    size = cfg["image_size"]
     return Solver(
         cell.adapter.build_model(cfg), loss_cfg, scfg, mesh=mesh,
-        input_shape=(size, size, cfg["num_channels"]),
+        input_shape=cell.adapter.input_shape(cfg),
         engine=tr.get("engine", "dense"),
         precision=cfg["program"]["precision"], telemetry=telemetry)
 
@@ -90,10 +89,6 @@ def load_state(solver, adapter, params):
     solver.state = solver._place_state(state)
 
 
-def _host(tree):
-    return jax.tree_util.tree_map(np.asarray, tree)
-
-
 def first_steps(solver, cell, params0, feed):
     """Hand the seed's weights to the solver, drive it through the check
     steps by the window's own call and feed, and read the program's side
@@ -103,17 +98,17 @@ def first_steps(solver, cell, params0, feed):
     weights, numbers)."""
     cfg, adapter = cell.config, cell.adapter
     load_state(solver, adapter, params0)
-    host0 = _host(params0)
+    host0 = weights.widened(params0)
     prog = {"losses": []}
     quiet = lambda *_a, **_k: None
     for i in range(cell.traffic["check_steps"]):
-        feed.play([i % len(feed.images)])
+        feed.play([i % len(feed.inputs)])
         last = solver.train(feed, num_iters=i + 1, log_fn=quiet)
         prog["losses"].append(float(last["loss"]))
         if i == 0:
             velocity = adapter.from_program(
-                _host(solver.state["opt"].momentum_buf))
-    after = adapter.from_program(_host(solver.state["params"]))
+                weights.widened(solver.state["opt"].momentum_buf))
+    after = adapter.from_program(weights.widened(solver.state["params"]))
     lr, wd = cfg["solver"]["base_lr"], cfg["solver"]["weight_decay"]
     prog["grad"] = {n: {l: velocity[n][l] / lr - wd * host0[n][l]
                         for l in leaves} for n, leaves in host0.items()}
@@ -123,13 +118,9 @@ def first_steps(solver, cell, params0, feed):
 
 def seeded_inputs(cell, seed):
     """(weights on the device, Feed over the staged pool) of the seed."""
-    cfg, tr = cell.config, cell.traffic
-    size = cfg["image_size"]
-    params0 = weights.make_params(cell.adapter, cfg, seed)
-    images, labels = weights.identity_batches(
-        seed, tr["pool_batches"], tr["identities"], tr["per_identity"],
-        (size, size, cfg["num_channels"]))
-    return params0, Feed(images, labels)
+    cfg, adapter = cell.config, cell.adapter
+    params0 = weights.make_params(adapter, cfg, seed)
+    return params0, Feed(*adapter.train_batches(cfg, cell.traffic, seed))
 
 
 def setup(cell, devices, seed, telemetry=None):
@@ -159,7 +150,7 @@ def window(solver, feed, seconds):
     return {"steps": steps, "seconds": t1 - t0, "t0": t0, "t1": t1}
 
 
-def reference_numbers(cell, host0, images, labels, quant=None):
+def reference_numbers(cell, host0, inputs, labels, quant=None):
     """The plain reference follows the first steps from the same weights
     and rows, on one device, a block of rows at a time.  Run after the
     window, with the program's state freed."""
@@ -170,8 +161,8 @@ def reference_numbers(cell, host0, images, labels, quant=None):
                       ranks=cell.chips, block=tr["reference_block"], quant=quant)
     losses = []
     for i in range(tr["check_steps"]):
-        j = i % len(images)
-        losses.append(trainer.step(images[j], labels[j]))
+        j = i % len(inputs)
+        losses.append(trainer.step(inputs[j], labels[j]))
     after = jax.tree_util.tree_map(np.asarray, trainer.params)
     return {"losses": losses, "grad": trainer.first_grads,
             "delta": compare.tree_sub(after, host0)}

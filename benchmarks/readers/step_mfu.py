@@ -1,8 +1,9 @@
 """Whole-step share of the chip's peak, in percent: required operations
-of the work done in the window (``harness/counts.py``) over seconds x
-chips x peak FLOP/s.  ``train`` and ``serve_window`` take the host
-clock's window; ``serve_busy`` takes the traced window's work over the seconds
-in which the device ran an operation."""
+of the work done in the window (``harness/counts.py`` with the
+adapter's ``forward_flops``) over seconds x chips x peak FLOP/s.
+``train`` and ``serve_window`` take the host clock's window;
+``serve_busy`` takes the traced window's work over the seconds in which
+the device ran an operation."""
 
 from benchmarks.harness import counts
 
@@ -13,7 +14,8 @@ def read(ctx, kind="train"):
     cell, win = ctx["cell"], ctx["window"]
     if kind == "train":
         rows = ctx["rows_per_step"]
-        flops = win["steps"] * rows * counts.train_flops_per_image(cell.config, rows)
+        flops = win["steps"] * rows * counts.train_flops_per_image(
+            cell.adapter, cell.config, rows)
         seconds = win["seconds"]
     elif kind == "serve_window":
         flops, seconds = ctx["serve"]["required_flops"], win["seconds"]

@@ -59,6 +59,39 @@ def param_shapes(in_ch: int = 3):
     return {name: {"kernel": s, "bias": (s[-1],)} for name, s in shapes.items()}
 
 
+def _same(size, stride):
+    return -(-size // stride)
+
+
+def forward_flops(image_size=224, in_ch=3):
+    """Operations one image REQUIRES through GoogLeNet v1 to pool5
+    (convolutions only; the paper's stem is 7x7/2; a multiply-add is two)."""
+    total = 0
+
+    def conv(hw, k, cin, cout):
+        nonlocal total
+        total += 2 * hw * hw * k * k * cin * cout
+
+    hw = _same(image_size, 2)
+    conv(hw, 7, in_ch, 64)
+    hw = _same(hw, 2)
+    conv(hw, 1, 64, 64)
+    conv(hw, 3, 64, 192)
+    hw = _same(hw, 2)
+    c = 192
+    for key, (p1, p3r, p3, p5r, p5, pp) in INCEPTION.items():
+        if key in ("4a", "5a"):
+            hw = _same(hw, 2)
+        conv(hw, 1, c, p1)
+        conv(hw, 1, c, p3r)
+        conv(hw, 3, p3r, p3)
+        conv(hw, 1, c, p5r)
+        conv(hw, 5, p5r, p5)
+        conv(hw, 1, c, pp)
+        c = p1 + p3 + p5 + pp
+    return total
+
+
 def _round_to(x, dt):
     """float32 -> ``dt`` -> float32; an 8-bit float gets a per-tensor
     scale (largest magnitude onto the type's largest value)."""

@@ -5,9 +5,6 @@ float32 in its own place reads nought."""
 
 import bench_path  # noqa: F401  (repo root on sys.path)
 
-import jax
-import numpy as np
-
 from bench_drive import toy_cell
 from benchmarks.harness import compare, run_serve, train_window, weights
 from benchmarks.reference import retrieval
@@ -16,12 +13,9 @@ from benchmarks.reference import retrieval
 def test_training_control_fails_and_reference_passes():
     cell = toy_cell("googlenet_train")
     cfg, tr = cell.config, cell.traffic
-    size = cfg["image_size"]
     params = weights.make_params(cell.adapter, cfg, 2**31 + 5)
-    host0 = jax.tree_util.tree_map(np.asarray, params)
-    images, labels = weights.identity_batches(
-        2**31 + 5, tr["pool_batches"], tr["identities"], tr["per_identity"],
-        (size, size, cfg["num_channels"]))
+    host0 = weights.widened(params)
+    images, labels = cell.adapter.train_batches(cfg, tr, 2**31 + 5)
     ref = train_window.reference_numbers(cell, host0, images, labels)
     again = train_window.reference_numbers(cell, host0, images, labels)
     low = train_window.reference_numbers(cell, host0, images, labels,
@@ -35,15 +29,14 @@ def test_training_control_fails_and_reference_passes():
 def test_serving_control_fails():
     cell = toy_cell("googlenet_serve_flat_sat")
     cfg, mix = cell.config, cell.traffic
-    size, g, k = cfg["image_size"], mix["gallery"], mix["engine"]["top_k"]
+    g, k = mix["gallery"], mix["engine"]["top_k"]
     params = weights.make_params(cell.adapter, cfg, 2**31 + 5)
-    ctx = {"host_params": jax.tree_util.tree_map(np.asarray, params),
-           "pool": weights.image_pool(2**31 + 5, mix["pool_images"],
-                                      (size, size, cfg["num_channels"])),
+    ctx = {"host_params": weights.widened(params),
+           "pool": cell.adapter.query_pool(cfg, mix, 2**31 + 5),
            "gallery": weights.mixture_gallery(g["seed"], g["rows"],
                                               cfg["embedding_dim"], g["centres"])[0]}
-    low = jax.jit(lambda p, x: cell.adapter.embed(p, x, quant=cfg["precision"]["control"]))
-    emb = np.asarray(low(params, ctx["pool"]))
+    emb = run_serve.embed_pool(cell.adapter, params, ctx["pool"], len(ctx["pool"]),
+                               quant=cfg["precision"]["control"])
     s, r = retrieval.exact_topk(emb, ctx["gallery"], k)
 
     # the lower precision's own ten, read as answers

@@ -1,4 +1,5 @@
-"""``counts.py`` against hand-worked operations and bytes."""
+"""``counts.py`` and GoogLeNet's own count (beside its reference, asked
+through its adapter) against hand-worked operations and bytes."""
 
 import bench_path  # noqa: F401  (repo root on sys.path)
 
@@ -7,7 +8,9 @@ import os
 
 import pytest
 
+from benchmarks.adapters import googlenet_v1 as adapter
 from benchmarks.harness import counts, peaks
+from benchmarks.reference import googlenet
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -25,17 +28,18 @@ def test_googlenet_stem_and_total():
     # inception 3a at 28x28 on 192 channels (Table 1: 64, 96/128, 16/32, 32)
     i3a = 2 * 28 * 28 * (192 * 64 + 192 * 96 + 9 * 96 * 128 + 192 * 16
                          + 25 * 16 * 32 + 192 * 32)
-    total = counts.googlenet_forward_flops(224, 3)
+    total = googlenet.forward_flops(224, 3)
     assert total > stem + conv2 + i3a
     # the paper's ~1.5 billion multiply-adds
     assert total / 2 == pytest.approx(1.58e9, rel=0.02)
-    assert counts.forward_flops(_cfg("googlenet_v1")) == total
+    assert total == 3_163_295_744
+    assert adapter.forward_flops(_cfg("googlenet_v1")) == total
 
 
 def test_train_is_three_forwards_plus_loss():
     cfg = _cfg("googlenet_v1")
-    assert counts.train_flops_per_image(cfg, 480) == \
-        3 * counts.forward_flops(cfg) + 6 * 480 * 1024
+    assert counts.train_flops_per_image(adapter, cfg, 480) == \
+        3 * adapter.forward_flops(cfg) + 6 * 480 * 1024 == 9_492_836_352
 
 
 def test_probe_and_scan_bytes():

@@ -1,5 +1,6 @@
 """``BENCHMARK.json`` against the contract's letter, and the promise that
-a cell, a traffic mix and a per-layer metric are added as files."""
+a cell, a traffic mix, a per-layer metric and a configuration of another
+family are added as files."""
 
 import bench_path  # noqa: F401  (repo root on sys.path)
 
@@ -7,6 +8,7 @@ import json
 import os
 import re
 import shutil
+import sys
 
 import pytest
 
@@ -144,6 +146,54 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files(tmp_path, monkeypatch):
     assert after == before
 
 
+SCRATCH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "scratch_family")
+
+
+def test_a_configuration_is_added_as_files(tmp_path, monkeypatch, capsys):
+    """Copy the benchmark and add a scratch family that is no image trunk
+    (the program's ``mlp`` on a vector input): one adapter, one reference,
+    one configuration, a train mix, a serve mix and their manifest
+    entries.  Both cells run the whole rehearsal path to a well-formed
+    last line with ``correct`` true, and no file that was there changed."""
+    import benchmarks.adapters
+    import benchmarks.reference
+    from bench_drive import drive
+
+    root = tmp_path / "checkout"
+    b = root / "benchmarks"
+    shutil.copytree(os.path.join(ROOT, "benchmarks"), b,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    added = {b / os.path.relpath(os.path.join(d, f), SCRATCH)
+             for d, _, fs in os.walk(SCRATCH) for f in fs}
+    assert not added & set(before)
+    shutil.copytree(SCRATCH, b, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__", "manifest_entries.json"))
+    man = loader.manifest()
+    entries = json.load(open(os.path.join(SCRATCH, "manifest_entries.json")))
+    man["configs"] += entries["configs"]
+    man["workloads"] += entries["workloads"]
+    for m in man["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"] = m["workloads"] + entries["end_to_end"].get(m["name"], [])
+    (root / "BENCHMARK.json").write_text(json.dumps(man))
+    monkeypatch.setattr(loader, "ROOT", str(root))
+    monkeypatch.setattr(loader, "BENCH_DIR", str(b))
+    # the copy's packages: a PR's new modules would sit beside the old ones
+    monkeypatch.setattr(benchmarks.adapters, "__path__", [str(b / "adapters")])
+    monkeypatch.setattr(benchmarks.reference, "__path__", [str(b / "reference")])
+    try:
+        for w in entries["workloads"]:
+            line = drive(w["name"], capsys)
+            assert list(line)[-1] == "checks" and line["attempted"] > 0
+            assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
+            assert line["correct"] is True and line["failed"] == 0, line["checks"]
+    finally:
+        for name in ("benchmarks.adapters.scratch_mlp", "benchmarks.reference.scratch_mlp"):
+            sys.modules.pop(name, None)
+    assert {p: p.read_bytes() for p in before} == before
+
+
 def test_an_unlisted_cell_is_refused():
     with pytest.raises(SystemExit):
-        loader.Cell("vit_b16_train_dp4")
+        loader.Cell("no_such_cell")
