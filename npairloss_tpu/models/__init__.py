@@ -41,6 +41,12 @@ from npairloss_tpu.models.vit import ViTEmbedding
 FLAGSHIP_TRUNK = "googlenet_mxu"
 FLAGSHIP_POLICY = DEFAULT_POLICY
 
+def _olmo_hybrid(**kwargs):
+    from npairloss_tpu.models.olmo_hybrid import OlmoHybridEmbedding
+
+    return OlmoHybridEmbedding(**kwargs)
+
+
 _REGISTRY: Dict[str, Callable[..., Any]] = {
     "googlenet": GoogLeNetEmbedding,
     "googlenet_embedding": GoogLeNetEmbedding,
@@ -82,6 +88,11 @@ _REGISTRY: Dict[str, Callable[..., Any]] = {
     "resnet18": lambda **kw: ResNetEmbedding(stage_sizes=(2, 2, 2, 2), width=64, **kw),
     "vit_b16": ViTEmbedding,
     "mlp": MLPEmbedding,
+    # Token tower (decoder stack as a document encoder: gated-delta-rule
+    # and full-attention blocks, masked mean pool).  Resolved at call
+    # time: the tower and its ops load only when a token model is built,
+    # so a float-input process imports nothing of them.
+    "olmo_hybrid": _olmo_hybrid,
 }
 
 

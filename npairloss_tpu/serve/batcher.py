@@ -73,6 +73,10 @@ class MicroBatcher:
     pulls it off the queue into the forming batch — the queue-wait/
     assemble boundary per-query tracing needs (obs.qtrace), a no-op
     when unset.  ``name`` is the replica this batcher feeds.
+    ``fits(batch_items, item)`` (optional) lets the owner bound a
+    dispatch in something other than requests (a token engine's
+    ``token_budget``): a co-rider it refuses is held back, heads the
+    next turn, and order is kept; the head of a turn always goes.
 
     The dispatcher thread's time is spanned whole (obs.tracing):
     ``serve/idle`` (waiting for a head: nothing was queued) ->
@@ -89,11 +93,16 @@ class MicroBatcher:
         on_batch: Optional[Callable[[Dict[str, Any]], None]] = None,
         on_pick: Optional[Callable[[Any], None]] = None,
         name: Optional[str] = None,
+        fits: Optional[Callable[[List[Any], Any], bool]] = None,
     ):
         self.cfg = cfg
         self._dispatch_fn = dispatch_fn
         self._on_batch = on_batch
         self._on_pick = on_pick
+        self._fits = fits
+        # The co-rider ``fits`` refused last turn: off the queue, not
+        # yet in a batch; only the dispatcher thread touches it.
+        self._held = None
         self._tags = {"replica": name} if name else {}
         self._q: queue.Queue = queue.Queue(maxsize=cfg.max_queue)
         self._thread: Optional[threading.Thread] = None
@@ -151,6 +160,15 @@ class MicroBatcher:
         if self._thread.is_alive():
             log.error("batcher close: dispatcher did not drain in %.1fs",
                       timeout)
+        else:
+            # Closed for good (submit refuses from here on): drop the
+            # owner's callbacks.  They are its bound methods, a cycle
+            # owner -> batcher -> owner; without them a closed server
+            # and its engines' device memory go by reference counting
+            # alone, also where the host has frozen the collector
+            # (gc.freeze) and the cycle would never be walked.
+            self._dispatch_fn = self._on_batch = self._on_pick = None
+            self._fits = None
         self._thread = None
 
     # -- admission ---------------------------------------------------------
@@ -190,7 +208,9 @@ class MicroBatcher:
         """One head, its co-riders, their dispatch; True = stop."""
         delay = max(self.cfg.max_delay_ms, 0.0) / 1e3
         with tracing.span("serve/idle"):
-            head = self._q.get()
+            head, self._held = self._held, None
+            if head is None:
+                head = self._q.get()
         if head is _STOP:
             return True
         if failpoints.should_fire("serve.queue_stall"):
@@ -218,6 +238,10 @@ class MicroBatcher:
                     break
                 if item is _STOP:
                     stop_after = True
+                    break
+                if self._fits is not None and not self._fits(
+                        [b[0] for b in batch], item[0]):
+                    self._held = item
                     break
                 if self._on_pick is not None:
                     self._on_pick(item[0])

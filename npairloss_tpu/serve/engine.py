@@ -95,7 +95,17 @@ class EngineConfig:
     single-pass Pallas kernel, ``auto`` the per-platform pick (fused
     on TPU, scan elsewhere) — resolved once at engine build and
     stamped into /healthz and bench records.  Ignored by a flat
-    index."""
+    index.
+
+    ``length_buckets`` (ascending; empty = fixed-shape float inputs, the
+    default) makes this a TOKEN engine: an input is a 1-D int32 row of
+    token ids, and a dispatch pads its rows to a ``buckets`` entry and
+    every row to the smallest length bucket that holds the longest, so
+    steady state dispatches at most ``len(buckets) x
+    len(length_buckets)`` encode programs.  ``token_budget`` (0 = none)
+    is the most ``rows x padded length`` one dispatch may hold: warm-up
+    compiles the pairs within it, and the batcher holds back a co-rider
+    that would pass it (docs/SERVING.md §Inputs)."""
 
     top_k: int = 10
     buckets: Tuple[int, ...] = (1, 8, 32)
@@ -103,12 +113,41 @@ class EngineConfig:
     probes: int = 8
     scoring: str = "fp32"
     probe_impl: str = "scan"
+    length_buckets: Tuple[int, ...] = ()
+    token_budget: int = 0
 
     def __post_init__(self):
         if not self.buckets or list(self.buckets) != sorted(
                 set(int(b) for b in self.buckets)):
             raise ValueError(
                 f"buckets must be ascending and unique, got {self.buckets}"
+            )
+        if list(self.length_buckets) != sorted(
+                set(int(b) for b in self.length_buckets)) or any(
+                b < 1 for b in self.length_buckets):
+            raise ValueError(
+                "length_buckets must be ascending, unique and positive, "
+                f"got {self.length_buckets}"
+            )
+        if self.token_budget and not self.length_buckets:
+            # A keyword this kind of engine does not take: the error a
+            # dataclass gives an unknown field (TypeError), which is what
+            # a float-input tier's configuration said of it before token
+            # engines existed (tests/benchmarks/test_bench_seam.py).
+            raise TypeError(
+                f"token_budget={self.token_budget} is a token engine's "
+                "field: it needs length_buckets"
+            )
+        if self.token_budget < 0:
+            raise ValueError(
+                f"token_budget must be >= 0, got {self.token_budget}"
+            )
+        if self.token_budget and \
+                self.buckets[0] * self.length_buckets[-1] > self.token_budget:
+            raise ValueError(
+                f"token_budget={self.token_budget} cannot hold "
+                f"{self.buckets[0]} row(s) of the longest bucket "
+                f"{self.length_buckets[-1]}"
             )
         if self.top_k < 1:
             raise ValueError(f"top_k must be >= 1, got {self.top_k}")
@@ -334,6 +373,11 @@ class QueryEngine:
         self.warmed = False
         self.compiles_total = 0
         self.compiles_after_warmup = 0
+        # Token engines only (``length_buckets`` set): true tokens the
+        # encode path was handed, and the tokens it ran padded to
+        # (rows bucket x length bucket), warm-up's dummies included.
+        self.tokens_encoded = 0
+        self.tokens_padded = 0
         self._guard = os.environ.get(COMPILE_GUARD_ENV, "").strip().lower()
         self._ivf = isinstance(index, IVFIndex)
         # Resolved once here ("auto" -> the platform pick) so every
@@ -548,7 +592,15 @@ class QueryEngine:
                 with jax.named_scope("serve/normalize"):
                     return l2_normalize(emb)
 
-            self._encode_fn = jax.jit(encode)
+            def encode_tokens(state, ids, lengths):
+                with jax.named_scope("serve/encode"):
+                    emb = model.apply({"params": state["params"]}, ids,
+                                      lengths, train=False)
+                with jax.named_scope("serve/normalize"):
+                    return l2_normalize(emb)
+
+            self._encode_fn = jax.jit(
+                encode_tokens if self.cfg.length_buckets else encode)
         else:
             self._encode_fn = None
 
@@ -606,13 +658,62 @@ class QueryEngine:
             f"{self.cfg.buckets[-1]} (the batcher must chunk)"
         )
 
-    def encode(self, inputs: np.ndarray) -> np.ndarray:
+    def length_bucket_for(self, n: int) -> int:
+        """Smallest configured length bucket >= n tokens."""
+        for b in self.cfg.length_buckets:
+            if n <= b:
+                return b
+        raise ValueError(
+            f"row of {n} tokens exceeds the largest length bucket "
+            f"{self.cfg.length_buckets[-1]}"
+        )
+
+    def padded_tokens(self, lengths: Sequence[int]) -> int:
+        """Tokens one dispatch of rows of these lengths runs padded to:
+        its rows bucket x its length bucket (what ``token_budget``
+        bounds)."""
+        return (self.bucket_for(len(lengths))
+                * self.length_bucket_for(max(lengths)))
+
+    def _encode_tokens(self, rows: Sequence[np.ndarray]) -> np.ndarray:
+        """The token engine's encode: int32 rows of differing length,
+        right-padded to (rows bucket, length bucket) with their true
+        lengths beside them (a padding row is one token long: the pool
+        divides by the length)."""
+        n = len(rows)
+        bucket = self.bucket_for(n)
+        lens = [len(r) for r in rows]
+        width = self.length_bucket_for(max(lens))
+        tokens = sum(lens)
+        n_before = self._cache_size()
+        with tracing.span("serve/encode", rows=n, bucket=bucket,
+                          tokens=tokens, padded_tokens=bucket * width,
+                          length_bucket=width):
+            ids = np.zeros((bucket, width), np.int32)
+            lengths = np.ones((bucket,), np.int32)
+            for i, r in enumerate(rows):
+                ids[i, :lens[i]] = r
+            lengths[:n] = lens
+            emb = self._encode_fn(self.state, jnp.asarray(ids),
+                                  jnp.asarray(lengths))
+            with tracing.span("serve/encode/wait"):
+                emb = np.asarray(emb)
+        self.tokens_encoded += tokens
+        self.tokens_padded += bucket * width
+        self._count_compiles(("encode", (bucket, width)), n_before)
+        return emb[:n]
+
+    def encode(self, inputs) -> np.ndarray:
         """Raw inputs -> unit-norm query embeddings via the restored
-        trunk (eval mode), padded per bucket like :meth:`query`."""
+        trunk (eval mode), padded per bucket like :meth:`query`.  A token
+        engine (``cfg.length_buckets``) takes a list of 1-D int32 rows
+        and pads their length to a bucket as well."""
         if self._encode_fn is None:
             raise RuntimeError(
                 "engine built without model/state: embedding queries only"
             )
+        if self.cfg.length_buckets:
+            return self._encode_tokens(inputs)
         x = np.asarray(inputs, np.float32)
         n = x.shape[0]
         bucket = self.bucket_for(n)
@@ -757,7 +858,12 @@ class QueryEngine:
         deserialize instead of recompiling.  (An AOT
         ``lower().compile()`` first would pay every compile twice: jit's
         dispatch cache ignores AOT executables, so the priming dispatch
-        recompiles from scratch.)  Returns the wall seconds spent."""
+        recompiles from scratch.)  Returns the wall seconds spent.
+
+        ``input_shape`` is one float input's shape.  A token engine
+        warms from its own ``length_buckets`` instead -- every (rows
+        bucket, length bucket) pair within ``token_budget`` -- and
+        reads nothing of the argument."""
         import time as _time
 
         idx = self.index
@@ -766,7 +872,15 @@ class QueryEngine:
             with tracing.span("serve/warmup", bucket=bucket, kind="topk"):
                 self._query_bucketed(np.zeros((bucket, idx.dim),
                                               np.float32))
-            if self._encode_fn is not None:
+            if self._encode_fn is not None and self.cfg.length_buckets:
+                budget = self.cfg.token_budget
+                for width in self.cfg.length_buckets:
+                    if budget and bucket * width > budget:
+                        break
+                    with tracing.span("serve/warmup", bucket=bucket,
+                                      kind="encode", length_bucket=width):
+                        self.encode([np.zeros((width,), np.int32)] * bucket)
+            elif self._encode_fn is not None:
                 if input_shape is None:
                     raise ValueError(
                         "warmup needs input_shape to warm the encode path"
@@ -809,4 +923,9 @@ class QueryEngine:
             "compiles_total": self.compiles_total,
             "compiles_after_warmup": self.compiles_after_warmup,
             "executable_cache_size": self._cache_size(),
+            # Token engines only (absent-when-off: a float-input tier's
+            # summary keeps its shape).
+            **({"tokens_encoded": self.tokens_encoded,
+                "tokens_padded": self.tokens_padded}
+               if self.cfg.length_buckets else {}),
         }

@@ -76,6 +76,7 @@ class ReplicaSet:
         dispatch_factory: Callable[[Replica], Callable],
         on_batch=None,
         on_pick=None,
+        fits=None,
     ):
         if not engines:
             raise ValueError("ReplicaSet needs at least one engine")
@@ -85,6 +86,7 @@ class ReplicaSet:
             rep.batcher = MicroBatcher(
                 dispatch_factory(rep), batcher_cfg,
                 on_batch=on_batch, on_pick=on_pick, name=rep.name,
+                fits=fits,
             )
             self.replicas.append(rep)
         # Rejections that never reached a batcher (no live replica) —

@@ -38,6 +38,7 @@ import base64
 import collections
 import contextlib
 import dataclasses
+import functools
 import json
 import logging
 import queue
@@ -194,6 +195,41 @@ class ServerConfig:
     explicit_drops: bool = False
 
 
+def _token_cfg(engine):
+    """The engine's config when it is a TOKEN engine (``length_buckets``
+    set), else None; an engine stand-in without a ``cfg`` is a float
+    engine."""
+    cfg = getattr(engine, "cfg", None)
+    return cfg if getattr(cfg, "length_buckets", ()) else None
+
+
+def _float_input(value) -> np.ndarray:
+    return np.asarray(value, np.float32)
+
+
+def _token_input(value, longest: int, vocab: Optional[int]) -> np.ndarray:
+    """One token record's ``input``: a 1-D integer sequence of 1 to
+    ``longest`` ids inside the vocabulary, as int32; anything else is
+    that request's error."""
+    row = np.asarray(value)
+    if row.ndim != 1 or (row.size and row.dtype.kind not in "iu"):
+        raise ValueError(
+            "token input must be a 1-D sequence of integer ids, got "
+            f"shape {row.shape} dtype {row.dtype}"
+        )
+    if not 1 <= row.shape[0] <= longest:
+        raise ValueError(
+            f"token input of {row.shape[0]} ids; this engine takes 1 to "
+            f"{longest}"
+        )
+    if row.min() < 0 or (vocab is not None and row.max() >= vocab):
+        raise ValueError(
+            f"token id outside the vocabulary [0, {vocab}): "
+            f"{int(row.min())}..{int(row.max())}"
+        )
+    return row.astype(np.int32, copy=False)
+
+
 class RetrievalServer:
     """N replica engines + per-replica batchers + the request/answer
     protocol (one engine is the degenerate, pre-replica-tier shape)."""
@@ -292,6 +328,11 @@ class RetrievalServer:
             engines, batcher_cfg, self._replica_dispatch,
             on_batch=self._record_batch,
             on_pick=self._qtrace_pick if qtrace is not None else None,
+            # A token engine bounds a dispatch in TOKENS (rows bucket x
+            # length bucket <= token_budget), not in requests alone.
+            fits=(self._fits_token_budget
+                  if _token_cfg(self.engine) is not None
+                  and self.engine.cfg.token_budget else None),
         )
         self._lat = collections.deque(maxlen=max(cfg.latency_window, 1))
         # THIS window's latencies, cleared at each emission: window rows
@@ -577,6 +618,9 @@ class RetrievalServer:
             # watchdog of the good samples resolution requires
             # (silence holds a burning SLO, by design).
             row["compiles_after_warmup"] = compiles
+        # A token tier's running totals, beside the compile count
+        # (absent on a float-input tier: its rows keep their shape).
+        row.update(self._token_totals())
         if self.telemetry is not None and self.telemetry.metrics_enabled:
             try:
                 self.telemetry.log("serve", self.answered, row)
@@ -663,6 +707,26 @@ class RetrievalServer:
                 answers[i] = ans
         return answers
 
+    def _fits_token_budget(self, batch_items, item) -> bool:
+        """The batcher's ``fits``: whether ``item`` may join
+        ``batch_items`` inside the primary engine's ``token_budget``,
+        counted as the dispatch would run it (rows bucket x length
+        bucket).  A record with no usable token row counts one token:
+        it fails alone at parse, whatever it rides with."""
+        def length(rec):
+            try:
+                return max(len(rec["input"]), 1)
+            except (KeyError, TypeError):
+                return 1
+
+        engine = self.engine
+        try:
+            return engine.padded_tokens(
+                [length(r) for r in batch_items] + [length(item)]
+            ) <= engine.cfg.token_budget
+        except ValueError:  # longer than the last bucket: parse answers it
+            return True
+
     def _dispatch_core(self, items: List[Dict[str, Any]],
                        engine: Optional[QueryEngine] = None,
                        replica: Optional[str] = None,
@@ -704,6 +768,13 @@ class RetrievalServer:
             # (not in the engine) so warmup's dispatches stay fast.
             time.sleep(failpoints.SERVE_LATENCY_FAULT_S)
         dim = engine.index.dim
+        tcfg = _token_cfg(engine)
+        if tcfg is None:
+            parse_input, stack = _float_input, np.stack
+        else:
+            parse_input, stack = functools.partial(
+                _token_input, longest=tcfg.length_buckets[-1],
+                vocab=getattr(engine.model, "vocab_size", None)), list
         answers: List[Optional[Dict[str, Any]]] = [None] * len(items)
         emb_rows: List[tuple] = []  # (item position, (D,) query row)
         enc_rows: List[tuple] = []  # (item position, raw input array)
@@ -718,9 +789,7 @@ class RetrievalServer:
                         )
                     emb_rows.append((i, e))
                 elif "input" in rec:
-                    enc_rows.append(
-                        (i, np.asarray(rec["input"], np.float32))
-                    )
+                    enc_rows.append((i, parse_input(rec["input"])))
                 else:
                     raise ValueError(
                         "query record needs an 'embedding' or 'input' field"
@@ -730,9 +799,7 @@ class RetrievalServer:
                               "error": str(e)}
         if enc_rows:
             try:
-                enc = engine.encode(
-                    np.stack([x for _, x in enc_rows])
-                )
+                enc = engine.encode(stack([x for _, x in enc_rows]))
                 emb_rows.extend(
                     (i, row) for (i, _), row in zip(enc_rows, enc)
                 )
@@ -1177,6 +1244,18 @@ class RetrievalServer:
         # is the old value.
         return sum(e.compiles_after_warmup for e in self._all_engines())
 
+    def _token_totals(self) -> Dict[str, int]:
+        """Tier-wide ``tokens_encoded`` (true tokens handed to encode)
+        and ``tokens_padded`` (rows bucket x length bucket they ran
+        padded to); empty unless the primary is a token engine."""
+        if _token_cfg(self.engine) is None:
+            return {}
+        engines = self._all_engines()
+        return {"tokens_encoded": sum(getattr(e, "tokens_encoded", 0)
+                                      for e in engines),
+                "tokens_padded": sum(getattr(e, "tokens_padded", 0)
+                                     for e in engines)}
+
     def submit(self, record: Dict[str, Any]):
         """Admit one query record; returns (future, t_submit).  Raises
         :class:`QueueFullError` on backpressure — from a full replica
@@ -1391,7 +1470,8 @@ class RetrievalServer:
             **{**self.engine.compile_stats(),
                "compiles_total": sum(e.compiles_total
                                      for e in self._all_engines()),
-               "compiles_after_warmup": self._compiles_after_warmup()},
+               "compiles_after_warmup": self._compiles_after_warmup(),
+               **self._token_totals()},
         }
 
     def healthz(self) -> Dict[str, Any]:
