@@ -15,6 +15,13 @@ Streaming + merge layout (docs/SERVING.md):
     ``lax.scan`` carrying the running (B, k) best scores/rows — the
     B x N similarity matrix is never materialized (the
     ``ops/eval_retrieval.py`` trick, applied to the gallery axis);
+  * a turn of the scan scores ``_SCAN_GROUP`` blocks; their ``top_k``
+    and its merge into the carry run only when they can change the
+    answer: when one of their scores is strictly greater than that
+    query's carried k-th best (one ``lax.cond`` a turn).  With rows in
+    random order a lone query merges about ``k * ln(turns)`` of them;
+    the scan counts the merges and the ``serve/topk/scan`` span carries
+    the count;
   * across shards, each mesh shard returns its local top-k with GLOBAL
     row numbers (shard offset via ``axis_index``); the (G, B, k)
     candidates reshape to (B, G*k) in ascending-shard order and one
@@ -23,7 +30,9 @@ Streaming + merge layout (docs/SERVING.md):
 Both merges preserve ``lax.top_k``'s lowest-index-wins tie-break:
 candidates always concatenate in ascending global-row order, so the
 streamed/sharded answer is bit-identical to a dense single-device
-``top_k`` over the whole gallery.
+``top_k`` over the whole gallery.  A skipped turn keeps that: every
+row of a later turn has a higher row number than every carried one,
+so a score equal to the carried k-th would have lost the tie.
 
 Steady-state serving never compiles: :meth:`warmup` compiles and primes
 every padding bucket with one dummy dispatch each (populating the
@@ -76,14 +85,15 @@ class EngineConfig:
     """``buckets`` are the fixed query padding sizes (ascending); every
     micro-batch pads to the smallest bucket that fits, so steady state
     dispatches only ``len(buckets)`` distinct programs.  ``top_k`` is
-    the answer length; ``gallery_block`` the gallery rows streamed per
-    scan step inside a shard (bounds the similarity working set).
+    the answer length; ``gallery_block`` the gallery rows of one streamed
+    block inside a shard (a scan step walks ``_SCAN_GROUP`` of them:
+    bounds the similarity working set).
 
     ``probes`` is the IVF probe width (clusters scored per query —
     clamped to the cluster count; ignored by a flat index).
     ``scoring`` picks the similarity-matmul dtype: ``fp32`` is the
-    oracle's HIGHEST-precision path; ``bf16`` halves the scan's
-    bandwidth/MXU cost (the ring bf16 bench row's ~6.7x headroom);
+    oracle's HIGHEST-precision path; ``bf16`` casts both sides of the
+    scoring gemm to the MXU's native width;
     ``int8`` additionally quantizes the stored slab with a per-cluster
     scale (IVF only — flat storage has no cluster to scale by).  Both
     reduced modes are gated by the recall-parity harness
@@ -183,58 +193,91 @@ def _scored_matmul(q, g, scoring: str):
     )
 
 
+# Gallery blocks a turn of the flat scan scores, tests and, if it must,
+# merges as one.  Measured on a v5e at 2M x 1024 (PERF.md, PR 30): a
+# turn's test and branch cost 3-4 us and one ``top_k`` over two blocks
+# less than two over one, so two beat one in every case measured; four
+# merge too often once a bucket holds several distinct queries.
+_SCAN_GROUP = 2
+
+
 def _stream_topk(q, emb, labels_unused, valid, k: int, block: int,
                  scoring: str = "fp32"):
     """Running top-k of ``q @ emb.T`` over gallery blocks.
 
-    Returns (scores, rows) of shape (B, k) with rows GLOBAL over ``emb``
-    (0-based).  Invalid (padding) rows never win; the final clamped
-    block masks rows a previous block already scored, so each gallery
-    row is a candidate exactly once.
+    Returns (scores, rows, scan): scores and rows of shape (B, k) with
+    rows GLOBAL over ``emb`` (0-based), and ``scan`` = int32 [turns
+    walked, turns merged], a turn being ``_SCAN_GROUP`` blocks.  Invalid
+    (padding) rows never win; the final clamped turn masks rows a
+    previous one already scored, so each gallery row is a candidate
+    exactly once.
+
+    A turn's rows are merged only when they can change the answer: when
+    one of their scores is STRICTLY greater than that query's carried
+    k-th best.  Turns go by in ascending row order, so every candidate
+    of a turn has a higher row number than every carried one, and a
+    score equal to the carried k-th loses ``top_k``'s lowest-index-wins
+    tie anyway: the carry a skipped turn leaves is the carry its merge
+    would have left, bit for bit.
     """
     n = emb.shape[0]
     b = int(min(block, n))
-    n_blocks = -(-n // b)
-    kb = min(k, b)
+    group = max(1, min(_SCAN_GROUP, n // b))
+    w = group * b
+    turns = -(-n // w)
+    kw = min(k, w)
     bq = q.shape[0]
 
-    def one_block(carry, j):
-        best_s, best_r = carry
-        start = jnp.minimum(j * b, n - b)
-        g = jax.lax.dynamic_slice_in_dim(emb, start, b, axis=0)
-        v = jax.lax.dynamic_slice_in_dim(valid, start, b, axis=0)
+    def one_turn(carry, j):
+        best_s, best_r, merged = carry
+        start = jnp.minimum(j * w, n - w)
+        v = jax.lax.dynamic_slice_in_dim(valid, start, w, axis=0)
         # named_scope: the scoring gemm vs the top-k merge show up as
         # separate regions in `prof --step serve` (obs.perf) — the
         # split that decides whether bf16/int8 scoring pays.
         with jax.named_scope("serve/score"):
-            sims = _scored_matmul(q, g, scoring)
-        rows = start + jnp.arange(b, dtype=jnp.int32)
-        # Mask padding rows AND the final block's clamped overlap (rows
-        # below the unclamped start were scored by an earlier block — a
+            # One gemm a block, of the shape ``gallery_block`` gives it,
+            # so a score does not depend on the group.  The barrier keeps
+            # the test's reduction out of the gemm's own fusion, where it
+            # cost ~10 us a turn (PERF.md, PR 30).
+            sims = jnp.concatenate(jax.lax.optimization_barrier([
+                _scored_matmul(
+                    q, jax.lax.dynamic_slice_in_dim(emb, start + i * b, b, axis=0),
+                    scoring)
+                for i in range(group)]), axis=1)
+        # Mask padding rows AND the final turn's clamped overlap (rows
+        # below the unclamped start were scored by an earlier turn — a
         # duplicate candidate would corrupt the top-k answer).
-        ok = v & (rows >= j * b)
+        ok = v & (jnp.arange(w, dtype=jnp.int32) >= j * w - start)
+
+        def merge(best_s, best_r):
+            new_s, new_i = jax.lax.top_k(sims, kw)
+            # Best-first concat keeps candidates in ascending global row
+            # order within equal scores, so top_k's lowest-index-first
+            # tie-break reproduces the dense answer exactly.
+            cand_s = jnp.concatenate([best_s, new_s], axis=1)
+            cand_r = jnp.concatenate([best_r, start + new_i], axis=1)
+            best_s, sel = jax.lax.top_k(cand_s, k)
+            return best_s, jnp.take_along_axis(cand_r, sel, axis=1)
+
         with jax.named_scope("serve/merge"):
             sims = jnp.where(ok[None, :], sims, jnp.float32(_NEG_FILL))
-            blk_s, blk_i = jax.lax.top_k(sims, kb)
-            blk_r = rows[blk_i]
-            # Merge: best-first concat keeps candidates in ascending
-            # global row order within equal scores, so top_k's
-            # lowest-index-first tie-break reproduces the dense answer
-            # exactly.
-            cand_s = jnp.concatenate([best_s, blk_s], axis=1)
-            cand_r = jnp.concatenate([best_r, blk_r], axis=1)
-            new_s, sel = jax.lax.top_k(cand_s, k)
-            new_r = jnp.take_along_axis(cand_r, sel, axis=1)
-        return (new_s, new_r), None
+            # top_k returns scores in descending order: the last column
+            # is the carried k-th best (_NEG_FILL until k rows are in).
+            wins = jnp.any(sims > best_s[:, -1:])
+            best_s, best_r = jax.lax.cond(
+                wins, merge, lambda s, r: (s, r), best_s, best_r)
+        return (best_s, best_r, merged + wins.astype(jnp.int32)), None
 
     init = (
         jnp.full((bq, k), jnp.float32(_NEG_FILL)),
         jnp.zeros((bq, k), jnp.int32),
+        jnp.int32(0),
     )
-    (best_s, best_r), _ = jax.lax.scan(
-        one_block, init, jnp.arange(n_blocks, dtype=jnp.int32)
+    (best_s, best_r, merged), _ = jax.lax.scan(
+        one_turn, init, jnp.arange(turns, dtype=jnp.int32)
     )
-    return best_s, best_r
+    return best_s, best_r, jnp.stack([jnp.int32(turns), merged])
 
 
 def _ivf_probe_topk(q, packed, rows, centroids, cvalid, scale,
@@ -485,16 +528,16 @@ class QueryEngine:
                 # must compute offsets for the NEW layout.
                 shard_n = emb.shape[0]
                 kl = min(k, shard_n)
-                s, r = _stream_topk(q, emb, labels, valid, kl, block,
-                                    scoring)
+                s, r, scan = _stream_topk(q, emb, labels, valid, kl, block,
+                                          scoring)
                 offset = jax.lax.axis_index(axis) * shard_n
-                return s[None], (r + offset)[None]
+                return s[None], (r + offset)[None], scan[None]
 
             sharded = jax.shard_map(
                 per_shard,
                 mesh=mesh,
                 in_specs=(P(), P(axis), P(axis), P(axis)),
-                out_specs=(P(axis), P(axis)),
+                out_specs=(P(axis), P(axis), P(axis)),
                 # Every output is P(axis); the per-shard scan starts
                 # from a replicated (-inf, 0) carry that turns varying
                 # on the first block, which the varying-axes checker
@@ -506,14 +549,14 @@ class QueryEngine:
             def topk(q, emb, labels, valid):
                 # (G, B, kl) per-shard candidates -> (B, G*kl) in
                 # ascending-shard (== ascending global row) order, then
-                # one merging top_k.
-                s, r = sharded(q, emb, labels, valid)
+                # one merging top_k.  The shards' block counts add up.
+                s, r, scan = sharded(q, emb, labels, valid)
                 g, _, kl = s.shape
                 s = jnp.transpose(s, (1, 0, 2)).reshape(q.shape[0], g * kl)
                 r = jnp.transpose(r, (1, 0, 2)).reshape(q.shape[0], g * kl)
                 best_s, sel = jax.lax.top_k(s, k)
                 best_r = jnp.take_along_axis(r, sel, axis=1)
-                return best_s, best_r
+                return best_s, best_r, scan.sum(axis=0)
 
             self._topk_fn = jax.jit(topk)
         else:
@@ -823,10 +866,20 @@ class QueryEngine:
         n_before = self._cache_size()
         t_score = time.perf_counter()
         with tracing.span("serve/topk", rows=n, bucket=bucket):
-            scores, rows = self._topk_fn(jnp.asarray(q), *args)
+            scores, rows, *scan = self._topk_fn(jnp.asarray(q), *args)
             with tracing.span("serve/topk/wait"):
                 scores = np.asarray(scores)[:n]
                 rows = np.asarray(rows)[:n]
+                scan = [np.asarray(x) for x in scan]
+            if scan:
+                # The flat scan's own count of turns walked and merged
+                # (an IVF probe returns none): known only now, so a span
+                # of its own carries it into the tracer and the
+                # profiler's host plane.
+                turns, merged = (int(x) for x in scan[0])
+                with tracing.span("serve/topk/scan", scan_blocks=turns,
+                                  scan_blocks_merged=merged):
+                    pass
         t_score1 = time.perf_counter()
         self._count_compiles(sig, n_before)
         t_gather = time.perf_counter()

@@ -553,7 +553,7 @@ def test_dispatch_spans_share_a_batch_and_encode_ends_on_the_host(no_tracer):
     assert set(by) == {
         "serve/idle", "serve/batch", "serve/dispatch", "serve/encode",
         "serve/encode/wait", "serve/topk", "serve/topk/wait",
-        "serve/gather", "serve/assemble", "serve/reply"}
+        "serve/topk/scan", "serve/gather", "serve/assemble", "serve/reply"}
     assert all(e["args"]["replica"] == "r0" for e in turn)
     assert by["serve/dispatch"]["args"]["size"] == 1
     end = lambda e: e["ts"] + e["dur"]
@@ -561,6 +561,9 @@ def test_dispatch_spans_share_a_batch_and_encode_ends_on_the_host(no_tracer):
         by[parent]["ts"] <= by[child]["ts"] and end(by[child]) <= end(by[parent]))
     assert inside("serve/encode/wait", "serve/encode")
     assert inside("serve/topk/wait", "serve/topk")
+    assert inside("serve/topk/scan", "serve/topk")
+    scan = by["serve/topk/scan"]["args"]
+    assert 1 <= scan["scan_blocks_merged"] <= scan["scan_blocks"]
     for child in ("serve/encode", "serve/topk", "serve/gather",
                   "serve/assemble"):
         assert inside(child, "serve/dispatch")
