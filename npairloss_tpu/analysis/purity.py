@@ -1,7 +1,7 @@
 """Pass ``purity`` — transitive jax-free proof for the contract modules.
 
 A handful of modules are *file-path-loaded* by jax-free processes
-(``scripts/bench_check.py`` gates, ``bench.py``'s parent): their
+(``scripts/bench_check.py`` gates, ``chip_smoke.py``'s parent): their
 contract is that executing them imports NO heavy dependency — not
 directly, not transitively.  Until now that contract was enforced only
 by actually running the gates; this pass proves it at lint time by

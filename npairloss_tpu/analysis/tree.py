@@ -25,7 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 SKIP_DIRS = {"__pycache__", ".git", "fixtures", "node_modules", ".claude"}
 
 # Where library code lives relative to the root: the package and the
-# CI/bench scripts.  Tests are scanned only by the marker pass (its
+# CI scripts.  Tests are scanned only by the marker pass (its
 # own root list).
 CODE_DIRS = ("npairloss_tpu", "scripts")
 

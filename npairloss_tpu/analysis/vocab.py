@@ -69,7 +69,8 @@ _ENTRYPOINTS: List[Tuple[re.Pattern, str]] = [
     (re.compile(r"python(?:3)?\s+-m\s+npairloss_tpu\s+(\S+)"), CLI_PY),
     (re.compile(r"(?:python(?:3)?\s+)?(?:scripts/)?bench_check\.py"),
      "scripts/bench_check.py"),
-    (re.compile(r"(?:python(?:3)?\s+)?(?:\./)?bench\.py"), "bench.py"),
+    (re.compile(r"(?:python(?:3)?\s+)?(?:\./)?benchmarks/run\.py"),
+     "benchmarks/run.py"),
 ]
 
 _BACKTICK_ROW_RE = re.compile(r"^\|\s*`([^`]+)`")
@@ -166,7 +167,7 @@ def _doc_command_lines(text: str) -> List[Tuple[int, str]]:
             continue
         if in_fence and ("npairloss_tpu" in stripped
                          or "bench_check.py" in stripped
-                         or "bench.py" in stripped):
+                         or "benchmarks/run.py" in stripped):
             start = i + 1
             cmd = stripped
             while cmd.endswith("\\") and i + 1 < len(lines):
@@ -237,16 +238,6 @@ def run(tree: SourceTree) -> List[Finding]:
                             f"{sub!r} which {vocab_rel} does not "
                             f"define (known: {sorted(subs)})"))
                         break
-                    if sub == "bench":
-                        # `... bench` forwards its args to bench.py
-                        # verbatim; check against THAT vocabulary.
-                        if not tree.exists("bench.py"):
-                            break
-                        if "bench.py" not in vocab_cache:
-                            vocab_cache["bench.py"] = _argparse_vocab(
-                                tree, "bench.py")
-                        flags, _ = vocab_cache["bench.py"]
-                        vocab_rel = "bench.py"
                 tail = cmd[m.end():]
                 for flag in _flags_of(tail):
                     if flag not in flags:

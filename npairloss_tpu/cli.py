@@ -193,8 +193,8 @@ def _build_solver(args):
         want = 1
     mp = int(getattr(args, "mp", 1) or 1)
     if want > 1 or engine == "ring" or mp > 1:
-        # Ring streams over a mesh axis; a 1-device mesh is valid (the
-        # bench times it), so honor --engine ring even single-device.
+        # Ring streams over a mesh axis; a 1-device mesh is valid, so
+        # honor --engine ring even single-device.
         # --mp > 1 folds the same devices into a 2-D dp x mp mesh for
         # partition rules that shard parameters (docs/DISTRIBUTED.md).
         from npairloss_tpu.parallel import build_mesh
@@ -2129,7 +2129,7 @@ def cmd_watch(args) -> int:
 
 def _add_staticcheck_options(sc) -> None:
     """The staticcheck option vocabulary, restated here so argparse
-    construction stays import-free (the bench-parent contract, like
+    construction stays import-free (the jax-free-parent contract, like
     _PRECISION_CHOICES).  Option strings, choices, and defaults are
     pinned equal to analysis.runner's own parser by
     tests/test_staticcheck.py — both front doors feed one
@@ -2770,23 +2770,6 @@ def _prof_serve(args, jax, np, dev, tel, steps, obsperf):
     )
 
 
-def cmd_bench(args) -> int:
-    import importlib.util
-
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(repo_root, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    # Forward only the subcommand's own args — bench.main would
-    # otherwise re-parse the full argv (incl. the word "bench") and die.
-    bench_args = list(args.bench_args or [])
-    if args.platform == "cpu" and "--platform" not in bench_args:
-        bench_args = ["--platform", "cpu", *bench_args]
-    return bench.main(bench_args)
-
-
 def main(argv: Optional[list] = None) -> int:
     p = argparse.ArgumentParser(
         prog="npairloss_tpu", description=__doc__,
@@ -2798,7 +2781,7 @@ def main(argv: Optional[list] = None) -> int:
         help="pin the jax platform before backend init: 'tpu' fails at "
         "start-up when no chip is found instead of running on whatever "
         "JAX falls back to; 'cpu' is an explicit CPU run (the "
-        "measuring commands time/prof/bench refuse a CPU without it); "
+        "measuring commands time/prof refuse a CPU without it); "
         "default: JAX's own choice",
     )
     sub = p.add_subparsers(dest="cmd", required=True)
@@ -3731,22 +3714,7 @@ def main(argv: Optional[list] = None) -> int:
     pp.add_argument("--json", action="store_true")
     pp.set_defaults(fn=cmd_parse)
 
-    b = sub.add_parser("bench", help="run the benchmark")
-    b.set_defaults(fn=cmd_bench, bench_args=[])
-
-    # Everything after the literal "bench" goes to bench.py verbatim
-    # (argparse REMAINDER in a subparser cannot capture leading
-    # optionals like --smoke).
-    argv = list(sys.argv[1:]) if argv is None else list(argv)
-    bench_args = []
-    if "bench" in argv:
-        idx = argv.index("bench")
-        bench_args = argv[idx + 1:]
-        argv = argv[:idx + 1]
-
     args = p.parse_args(argv)
-    if getattr(args, "fn", None) is cmd_bench:
-        args.bench_args = bench_args
     if args.platform != "default":
         import jax
 

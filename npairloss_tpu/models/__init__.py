@@ -10,9 +10,9 @@ policy (models.precision: "mxu" / "bf16" / "fp32_parity" or a
 PrecisionPolicy object) through the trunk: policy-aware trunks
 (GoogLeNet family, ViT) resolve per-module dtypes/precision by regex
 over their module paths; the rest honor the policy's compute dtype.
-The FLAGSHIP trunk+policy pair — what bench.py headlines and the CLI
-defaults to for ``--precision mxu`` runs — is ``googlenet_mxu`` under
-the ``"mxu"`` policy (FLAGSHIP_TRUNK / FLAGSHIP_POLICY below).
+The FLAGSHIP trunk+policy pair — what the benchmark's ``googlenet_v1``
+cells run and ``--precision mxu`` defaults to — is ``googlenet_mxu``
+under the ``"mxu"`` policy (FLAGSHIP_TRUNK / FLAGSHIP_POLICY below).
 """
 
 from __future__ import annotations
@@ -34,10 +34,10 @@ from npairloss_tpu.models.resnet import ResNetEmbedding
 from npairloss_tpu.models.vit import ViTEmbedding
 
 # The flagship workload's trunk + policy: the parity-preserving MXU
-# rewrites (s2d stem + fused inception 1x1s; step time on the current
-# chip: not measured) under the single-pass-bf16 mixed-precision
-# policy.  One home, so bench.py, the CLI, and the
-# tests agree on what "flagship" means.
+# rewrites (s2d stem + fused inception 1x1s) under the single-pass-bf16
+# mixed-precision policy.  Its step on the chip is the benchmark's cell
+# 1 (``googlenet_train``; PERF_LEDGER.jsonl has every PR's reading).
+# One home, so the CLI and the tests agree on what "flagship" means.
 FLAGSHIP_TRUNK = "googlenet_mxu"
 FLAGSHIP_POLICY = DEFAULT_POLICY
 
@@ -123,8 +123,8 @@ def get_model(name: str,
 
 def flagship_model(policy: Optional[Union[str, PrecisionPolicy]] =
                    FLAGSHIP_POLICY, **kwargs):
-    """The headline trunk under the default (or given) policy — the ONE
-    constructor bench.py, the CLI flagship paths, and the tests share."""
+    """The flagship trunk under the default (or given) policy — the ONE
+    constructor the CLI flagship paths and the tests share."""
     return get_model(FLAGSHIP_TRUNK, policy=policy, **kwargs)
 
 

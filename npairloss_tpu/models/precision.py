@@ -17,13 +17,13 @@ Shipped policies (``get_policy`` / ``available_policies``):
 * ``"mxu"`` — THE FLAGSHIP DEFAULT.  bf16 compute over fp32 master
   params, explicit single-pass bf16 MXU precision on every conv/dense,
   and the loss engines' gemms in the same single-pass mode
-  (``loss_matmul_precision="default"`` — the measured ring-bf16 row is
-  6.7x the HIGHEST mode at pool 4096, BENCH_r05).  Normalization
+  (``loss_matmul_precision="default"``; against the HIGHEST mode on
+  the current chip: not measured).  Normalization
   arithmetic (LRN / LayerNorm / BatchNorm statistics, L2 normalize)
   stays fp32 — that is a property of the module implementations, which
   compute their statistics in fp32 regardless of the activation dtype.
   The policy/fp32 loss delta is bounded by test
-  (tests/test_precision_policy.py) and reported by bench.py.
+  (tests/test_precision_policy.py).
 * ``"bf16"`` — the pre-policy headline: bf16 compute, fp32 params,
   backend-default conv precision, oracle-parity (HIGHEST) loss gemms.
   Byte-compatible with the old ``dtype=jnp.bfloat16`` constructors.
@@ -151,7 +151,7 @@ class PrecisionPolicy:
         return ModulePrecision(**base)
 
     def describe(self) -> Dict[str, Any]:
-        """JSON-able summary (run manifests, bench records)."""
+        """JSON-able summary (run manifests)."""
         return {
             "name": self.name,
             "param_dtype": jnp.dtype(self.param_dtype).name,
@@ -169,8 +169,8 @@ _POLICIES: Dict[str, PrecisionPolicy] = {
     # The flagship default: wide single-pass bf16 gemms everywhere the
     # MXU runs, fp32 master params/updates, fp32 normalization (module-
     # internal).  The TPU-v4 paper (PAPERS.md) is explicit that this is
-    # what the MXU rewards; googlenet_mxu at 21.91 ms vs 27.85 ms
-    # (BENCH_r05) is this repo's measured evidence.
+    # what the MXU rewards; the flagship's step on the current chip is
+    # the benchmark's cell 1 (PERF_LEDGER.jsonl).
     "mxu": PrecisionPolicy(
         name="mxu",
         param_dtype=jnp.float32,
@@ -201,7 +201,7 @@ _POLICIES: Dict[str, PrecisionPolicy] = {
     ),
 }
 
-# The policy the flagship workload (bench headline, CLI default when
+# The policy the flagship workload (benchmark cell 1, CLI default when
 # --precision is not given but a policy-aware entry point wants one)
 # runs under.
 DEFAULT_POLICY = "mxu"
@@ -209,8 +209,8 @@ DEFAULT_POLICY = "mxu"
 
 def get_policy(name: Union[str, PrecisionPolicy]) -> PrecisionPolicy:
     """Resolve a policy name (or pass a policy through).  Unknown names
-    raise with the known vocabulary — the CLI argparse choices and
-    bench row validation both build on this being loud."""
+    raise with the known vocabulary — the CLI argparse choices build
+    on this being loud."""
     if isinstance(name, PrecisionPolicy):
         return name
     key = str(name).lower()

@@ -29,7 +29,7 @@ package closes the loop while the process is still running
 IMPORTANT: this whole package must stay importable WITHOUT jax (stdlib
 only) — ``watch`` runs backend-free, and ``scripts/bench_check.py
 --alerts`` file-path-loads the alert validator from a jax-free process
-(the bench-parent contract).
+(the jax-free-parent contract).
 """
 
 from npairloss_tpu.obs.live.alerts import (

@@ -1,9 +1,9 @@
 """The ONE cost-analysis / MFU helper (docs/OBSERVABILITY.md §Perf).
 
-The single home of XLA ``cost_analysis`` -> FLOPs -> MFU (bench.py's
-rows, ``cli.py cmd_time``, the ``prof`` report); ``utils.profiling``
-re-exports the names, and every producer of an ``mfu`` number in this
-repo goes through :func:`mfu_from_timing`.
+The single home of XLA ``cost_analysis`` -> FLOPs -> MFU (``cli.py
+cmd_time``, the ``prof`` report, ``Solver`` under ``perf_metrics``);
+``utils.profiling`` re-exports the names, and every producer of an
+``mfu`` number in the package goes through :func:`mfu_from_timing`.
 
 Stdlib-only: the "stage" arguments are duck-typed
 ``jax.stages.Lowered``/``Compiled`` objects (anything with a

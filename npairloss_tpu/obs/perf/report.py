@@ -4,8 +4,8 @@ One on-disk artifact per ``prof`` run (JSON + human table): per-region
 FLOPs / bytes / arithmetic intensity / bound class / share-of-step /
 est-ms-at-roofline from the static HLO attribution, plus the dynamic
 step-time decomposition reconciled against wall time.  The JSON schema
-is versioned and pinned by tests — downstream tooling (bench gates,
-the next perf PR's before/after diffs) may rely on every key listed in
+is versioned and pinned by tests — downstream tooling (the next perf
+PR's before/after diffs) may rely on every key listed in
 :func:`validate_report`.
 
 Intra-package imports are lazy so jax-free file-path loaders can use

@@ -3,7 +3,7 @@
 The reference layer's only observability was commented-out ``LOG(INFO)``
 wall-clock probes (reference: npair_multi_class_loss.cu:423, cu:464-468);
 this framework's early telemetry scattered across a ``log_fn`` string
-callback, hand-rolled JSON writers in ``bench.py``, and ``StepTimer``.
+callback, hand-rolled JSON writers, and ``StepTimer``.
 This module is the structured replacement: a ``MetricLogger`` protocol
 with file (JSONL/CSV), in-memory (ring buffer), and fan-out (multiplex)
 implementations.  Every record is a flat dict; the stamping of the
@@ -56,7 +56,7 @@ class JsonlSink:
     """Append-only JSON-lines file sink — one record per line.
 
     Line-buffered so a killed process loses at most the current line
-    (the bench spill lesson: partial telemetry beats no telemetry).
+    (partial telemetry beats no telemetry).
     Parent directories are created on demand.
     """
 

@@ -28,7 +28,7 @@ serving path:
     scale — the exact arithmetic of the scan baseline, now MXU-shaped).
 
 Dispatch count for the probe path drops from 4 pipeline stages to 2
-(declared in :data:`PROBE_IMPLS`, stamped into bench records).
+(declared in :data:`PROBE_IMPLS`).
 
 Parity contract (tests/test_pallas_ivf.py, ci.sh interpret smoke):
 scores match the scan baseline to 1e-6 and recall@{1,10} vs the
@@ -56,12 +56,12 @@ from jax.experimental.pallas import tpu as pltpu
 from npairloss_tpu.ops.pallas_mode import default_interpret
 
 # The probe-impl registry — the single source of truth the CLI flag
-# vocabulary (cli._PROBE_IMPL_CHOICES), bench rows, and tests enumerate
+# vocabulary (cli._PROBE_IMPL_CHOICES) and the tests enumerate
 # from (pinned by the staticcheck ``vocab`` pass, the _PRECISION_CHOICES
 # pattern).  ``dispatch_count`` is the declared number of device
 # pipeline stages on the probe path (centroid-select / gather / score /
 # merge for the scan; centroid-select / fused kernel for the Pallas
-# path) — stamped into bench records so the fused win is auditable.
+# path) — pinned by tests/test_pallas_ivf.py.
 PROBE_IMPLS = {
     "scan": {"dispatch_count": 4, "pallas": False},
     "fused": {"dispatch_count": 2, "pallas": True},
@@ -90,8 +90,8 @@ def _round_up(x: int, mult: int) -> int:
 def resolve_probe_impl(impl: str, platform: Optional[str] = None) -> str:
     """``auto`` -> the per-platform pick: the fused kernel where Mosaic
     compiles it (TPU), the scan baseline elsewhere (interpret-mode
-    emulation is a parity harness, not a serving path) — mirroring how
-    the bench rows pick the int8/bf16 scoring dtype per platform."""
+    emulation is a parity harness, not a serving path); resolved once
+    at engine build (``serve/engine.py``)."""
     if impl not in PROBE_IMPLS:
         raise ValueError(
             f"probe_impl must be one of {sorted(PROBE_IMPLS)}, "

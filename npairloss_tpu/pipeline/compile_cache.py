@@ -10,7 +10,7 @@ home:
 * unset: the cache goes to one fixed path inside the checkout,
   ``<repo>/.jax_cache/`` (git-ignored).
 
-``train``, ``serve``, ``index``, ``bench.py`` and ``chip_smoke.py`` call
+``train``, ``serve``, ``index`` and ``chip_smoke.py`` call
 :func:`enable_compile_cache` before their first compile.  The off
 switch is JAX's own (``JAX_ENABLE_COMPILATION_CACHE=false``), which is
 how the test suite keeps the cache off (tests/conftest.py).

@@ -104,7 +104,7 @@ class EngineConfig:
     ``scan`` is the lax.scan gather+score baseline, ``fused`` the
     single-pass Pallas kernel, ``auto`` the per-platform pick (fused
     on TPU, scan elsewhere) — resolved once at engine build and
-    stamped into /healthz and bench records.  Ignored by a flat
+    stamped into /healthz.  Ignored by a flat
     index.
 
     ``length_buckets`` (ascending; empty = fixed-shape float inputs, the
@@ -424,8 +424,8 @@ class QueryEngine:
         self._guard = os.environ.get(COMPILE_GUARD_ENV, "").strip().lower()
         self._ivf = isinstance(index, IVFIndex)
         # Resolved once here ("auto" -> the platform pick) so every
-        # consumer — the jitted program choice, /healthz, bench rows,
-        # the qtrace fused flag — reports the impl that actually runs.
+        # consumer — the jitted program choice, /healthz, the qtrace
+        # fused flag — reports the impl that actually runs.
         # None for flat engines: the probe path does not exist there,
         # and /healthz keeps its pre-IVF shape (absent-when-off).
         self.probe_impl = (

@@ -29,8 +29,8 @@ scores only the probed clusters:
     -inf), and the per-shard top-k candidates merge exactly like the
     flat engine's shard merge.
   * **Scoring dtype**: the cluster-scan matmul can run fp32 (HIGHEST —
-    the flat oracle's precision), bf16 (the ~6.7x MXU headroom the ring
-    bf16 bench row measured), or int8 with a per-cluster scale
+    the flat oracle's precision), bf16 (single-pass MXU gemms; speed
+    on the current chip: not measured), or int8 with a per-cluster scale
     (max-abs symmetric quantization) — gated by the recall-parity
     harness (tests/test_ivf.py) against the brute-force oracle.
   * **add()**: new rows assign to their nearest EXISTING centroid (no
@@ -411,8 +411,8 @@ def topk_recall(
     mean over queries of |approx top-K ∩ exact top-K| / K.  ``rows``
     are (B, >=K) global gallery row ids (the engines' ``"rows"``
     output); this is the gate the bf16/int8 scoring modes and every
-    probe count must clear (tests/test_ivf.py, the ``ivf_qps_1m``
-    bench row's hard floor)."""
+    probe count must clear (tests/test_ivf.py; the benchmark's cell 3
+    holds the served answers to a ``recall_miss`` limit of its own)."""
     a = np.asarray(approx_rows)
     e = np.asarray(exact_rows)
     if a.shape[0] != e.shape[0]:

@@ -86,7 +86,7 @@ _SPEC_KEYS = frozenset((
 
 def tenant_of_slo(slo_name: str) -> Optional[str]:
     """The tenant id a ``tenant_*@<id>`` SLO/alert is scoped to, or
-    None for a tier-wide name — the verdict/bench side of the naming
+    None for a tier-wide name — the verdict/gate side of the naming
     contract."""
     if TENANT_SLO_SEP not in slo_name:
         return None

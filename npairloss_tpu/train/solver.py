@@ -257,7 +257,7 @@ class Solver:
         # dtypes are the model's own business (get_model(policy=...));
         # here the policy supplies the loss engines' gemm precision when
         # ``matmul_precision`` isn't set explicitly, and is recorded so
-        # telemetry/bench stamp which recipe a run trained under.
+        # telemetry stamps which recipe a run trained under.
         if precision is not None:
             from npairloss_tpu.models.precision import get_policy
 
@@ -654,7 +654,7 @@ class Solver:
         Donation covers state AND the ring AND the batch args — the
         prefetcher guarantees batch buffers are fresh per step, so the
         jitted step can reuse them in place (the sync path cannot make
-        that promise: callers like bench.py redispatch one buffer)."""
+        that promise: a caller may redispatch one buffer)."""
         from npairloss_tpu.pipeline import MetricWindow
 
         train_step = self._train_step_body()

@@ -1,27 +1,13 @@
 #!/usr/bin/env python
 """Artifact gates, jax-free (docs/OBSERVABILITY.md §Perf observatory).
 
-One mode per ``npairloss-*-v1`` artifact (fleet report, alert log,
-remediation audit, quality log, gameday verdict, qtrace, WAL, tenants
-manifest, staticcheck) plus ``--history PATH``: walk a JSONL file of
-``bench.py`` records (one per line, oldest first) and FAIL (exit != 0)
-when the newest measured record regresses against the best earlier
-one.  Which records, cells and bounds the repo is judged by is the
-benchmark's to define (ROADMAP S0); this gate is the comparison logic.
-
-Noise-aware thresholds, two-window-min semantics: every measured row
-publishes ``min(ms_per_step_windows)`` and keeps both windows, and the
-spread between a row's own windows IS its noise floor.  A row only
-counts as regressed when it falls below the reference by MORE than
-``max(--tol, spread_new, spread_ref)`` — a jittery measurement widens
-its own gate instead of crying wolf.
-
-What is gated, per comparable record pair:
-  * the headline ``value`` (emb/s, higher is better);
-  * every extras row with ``emb_per_sec`` (engine + batch-scaling
-    rows), matched by name/path;
-  * every extras row with ``p99_ms`` (serving rows; LOWER is better).
-Rows present only on one side are coverage changes, not regressions.
+One mode per ``npairloss-*-v1`` artifact the program writes (fleet
+report, alert log, remediation audit, quality log, gameday verdict,
+qtrace, WAL, tenants manifest) plus ``--static`` (the invariant
+linter): each validates outside input and FAILS (exit != 0) on the
+first contract it finds broken.  Speed is not gated here: the
+benchmark (``BENCHMARK.json``, ``benchmarks/run.py``) and the driver's
+per-cell comparison of every PR do that.
 
 Stdlib-only and jax-free by design: a gate must run on any box that
 can read the artifacts.
@@ -33,52 +19,13 @@ import argparse
 import json
 import os
 import sys
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_TOL = 0.05
-# Hard (absolute, not noise-relative) gates on the approximate-index
-# bench row (ISSUE 11 / docs/SERVING.md §Approximate index): a faster-
-# but-wrong index is a regression however smooth the trajectory, and an
-# IVF path slower than 5x the flat scan has lost its reason to exist.
-IVF_RECALL_FLOOR = 0.95
-IVF_SPEEDUP_FLOOR = 5.0
 
 
 def _log(msg: str) -> None:
     print(f"[bench_check] {msg}", file=sys.stderr, flush=True)
-
-
-# -- record harvesting --------------------------------------------------------
-
-def _is_measurement(rec: Dict[str, Any]) -> bool:
-    """A full-mode record with a measured headline."""
-    return (
-        isinstance(rec, dict)
-        and isinstance(rec.get("value"), (int, float))
-        and rec.get("value", 0) > 0
-        and rec.get("mode", "full") == "full"
-    )
-
-
-def load_history_records(path: str) -> List[Tuple[str, Dict[str, Any]]]:
-    records = []
-    try:
-        with open(path) as f:
-            for i, line in enumerate(f):
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    _log(f"{path}:{i + 1}: bad JSON line skipped")
-                    continue
-                if _is_measurement(rec):
-                    records.append((f"history[{i}]", rec))
-    except FileNotFoundError:
-        pass
-    return records
 
 
 # -- fleet-report gate --------------------------------------------------------
@@ -743,231 +690,16 @@ def check_static(root: str, diff_base: Optional[str] = None) -> List[str]:
     return violations
 
 
-# -- the gate -----------------------------------------------------------------
-
-def _ivf_hard_gates(new_rows: Dict[str, Dict]) -> List[str]:
-    """Absolute gates on the newest record's ``ivf_qps_1m`` row: the
-    recall@1 floor against the flat oracle, and the minimum qps speedup
-    over the ``flat_qps_1m`` twin measured in the same pass.  Rows
-    absent = coverage unchanged, nothing to gate."""
-    out: List[str] = []
-    ivf = new_rows.get("ivf_qps_1m")
-    if not isinstance(ivf, dict):
-        return out
-    r1 = ivf.get("recall_at_1")
-    if isinstance(r1, (int, float)):
-        if r1 < IVF_RECALL_FLOOR:
-            out.append(
-                f"ivf_qps_1m: recall@1 {r1:.4f} < hard floor "
-                f"{IVF_RECALL_FLOOR} (approximate answers drifted from "
-                "the brute-force oracle)")
-        else:
-            _log(f"ivf recall@1 {r1:.4f} >= floor {IVF_RECALL_FLOOR}")
-    flat = new_rows.get("flat_qps_1m")
-    ivf_qps, flat_qps = ivf.get("qps"), (flat or {}).get("qps")
-    if isinstance(ivf_qps, (int, float)) and \
-            isinstance(flat_qps, (int, float)) and flat_qps > 0:
-        speedup = ivf_qps / flat_qps
-        if speedup < IVF_SPEEDUP_FLOOR:
-            out.append(
-                f"ivf_qps_1m: {speedup:.1f}x flat qps < hard floor "
-                f"{IVF_SPEEDUP_FLOOR}x ({ivf_qps:.1f} vs {flat_qps:.1f} "
-                "qps at the 1M gallery)")
-        else:
-            _log(f"ivf speedup {speedup:.1f}x flat "
-                 f">= floor {IVF_SPEEDUP_FLOOR}x")
-    return out
-
-
-def _fused_probe_gates(new: Dict[str, Any]) -> List[str]:
-    """Shape + hard gates on the fused-Pallas probe rows (ISSUE 19),
-    jax-free off the record dict alone: a measured ``ivf_fused_qps_1m``
-    must carry its RESOLVED impl, a declared pipeline dispatch count
-    <= 2 (the 4 -> 2 claim the row exists to stamp), and the same
-    recall@1 floor as the scan row; ``ivf_probe_kernel_micro`` must
-    declare both impls' dispatch counts and a measured scan clock.
-    Skipped/error/absent rows = coverage unchanged, nothing to gate."""
-    out: List[str] = []
-    extras = new.get("extras")
-    extras = extras if isinstance(extras, dict) else {}
-
-    def measured(name):
-        row = extras.get(name)
-        if isinstance(row, dict) and "error" not in row \
-                and not row.get("skipped"):
-            return row
-        return None
-
-    fused = measured("ivf_fused_qps_1m")
-    if fused is not None:
-        if fused.get("probe_impl") != "fused":
-            out.append(
-                f"ivf_fused_qps_1m: probe_impl {fused.get('probe_impl')!r}"
-                " != 'fused' (the row must stamp the RESOLVED impl it "
-                "measured)")
-        dc = fused.get("dispatch_count")
-        if not isinstance(dc, int) or isinstance(dc, bool) or dc > 2:
-            out.append(
-                f"ivf_fused_qps_1m: dispatch_count {dc!r} is not an "
-                "int <= 2 (the fused probe path's whole claim)")
-        r1 = fused.get("recall_at_1")
-        if isinstance(r1, (int, float)) and r1 < IVF_RECALL_FLOOR:
-            out.append(
-                f"ivf_fused_qps_1m: recall@1 {r1:.4f} < hard floor "
-                f"{IVF_RECALL_FLOOR} (the kernel drifted from the "
-                "brute-force oracle)")
-        elif isinstance(r1, (int, float)):
-            _log(f"fused recall@1 {r1:.4f} >= floor {IVF_RECALL_FLOOR}")
-    micro = measured("ivf_probe_kernel_micro")
-    if micro is not None:
-        fd = micro.get("fused_dispatches")
-        if not isinstance(fd, int) or isinstance(fd, bool) or fd > 2:
-            out.append(
-                f"ivf_probe_kernel_micro: fused_dispatches {fd!r} is "
-                "not an int <= 2")
-        sd = micro.get("scan_dispatches")
-        if not isinstance(sd, int) or isinstance(sd, bool) \
-                or (isinstance(fd, int) and fd >= sd):
-            out.append(
-                f"ivf_probe_kernel_micro: scan_dispatches {sd!r} must "
-                "be an int above fused_dispatches — the row records the "
-                "dispatch-count DROP")
-        if not isinstance(micro.get("scan_ms"), (int, float)):
-            out.append(
-                "ivf_probe_kernel_micro: scan_ms missing/non-numeric "
-                "(the baseline clock the fused claim compares against)")
-    return out
-
-
-def _spread(rec: Dict[str, Any]) -> float:
-    """Relative window spread = the record's own measured noise floor
-    (two-window-min semantics: the min is published, the spread is the
-    jitter evidence)."""
-    w = rec.get("ms_per_step_windows")
-    if isinstance(w, list) and len(w) >= 2:
-        ws = [float(x) for x in w if isinstance(x, (int, float)) and x > 0]
-        if len(ws) >= 2 and min(ws) > 0:
-            return (max(ws) - min(ws)) / min(ws)
-    return 0.0
-
-
-def _walk_rows(rec: Dict[str, Any], prefix: str = "") -> Dict[str, Dict]:
-    """Flatten extras into {path: row} for every dict carrying a
-    gateable metric; error/skipped rows are not measurements."""
-    out: Dict[str, Dict] = {}
-    extras = rec.get("extras") if not prefix else rec
-    if not isinstance(extras, dict):
-        return out
-    for name, row in extras.items():
-        if not isinstance(row, dict):
-            continue
-        path = f"{prefix}{name}"
-        if "error" in row or row.get("skipped"):
-            continue
-        if any(isinstance(row.get(k), (int, float))
-               for k in ("emb_per_sec", "p99_ms")):
-            out[path] = row
-        else:
-            out.update(_walk_rows(row, prefix=path + "/"))
-    return out
-
-
-def check(
-    records: List[Tuple[str, Dict[str, Any]]],
-    tol: float = DEFAULT_TOL,
-) -> List[str]:
-    """Newest record vs the best earlier evidence; returns the list of
-    violations (empty = gate passes)."""
-    if len(records) < 2:
-        _log(f"{len(records)} measured record(s) — nothing to gate")
-        return []
-    new_src, new = records[-1]
-    violations: List[str] = []
-
-    # Headline: higher is better; reference = best earlier value, with
-    # its own windows' spread as that reference's noise contribution.
-    best_src, best = max(records[:-1], key=lambda r: r[1]["value"])
-    best_value, best_spread = best["value"], _spread(best)
-    # A POLICY headline (the precision-policy flagship, ISSUE 7) must
-    # additionally clear the best earlier measured googlenet_mxu bar —
-    # the mxu trunk's own throughput is the floor the policy default
-    # exists to beat, so a policy flagship slower than the plain mxu
-    # row is a regression even when it beats the old prototxt-trunk
-    # headlines.  Pre-policy records are never gated against the bar
-    # (their headline IS the plain trunk).
-    if new.get("policy"):
-        for src, rec in records[:-1]:
-            row = _walk_rows(rec).get("batch_scaling/120_mxu")
-            if row and isinstance(row.get("emb_per_sec"), (int, float)) \
-                    and row["emb_per_sec"] > best_value:
-                best_src = f"{src} (120_mxu bar)"
-                best_value, best_spread = row["emb_per_sec"], _spread(row)
-    eff = max(tol, _spread(new), best_spread)
-    floor = best_value * (1.0 - eff)
-    verdict = "OK" if new["value"] >= floor else "REGRESSED"
-    _log(f"headline: {new['value']:.1f} ({new_src}) vs best "
-         f"{best_value:.1f} ({best_src}), tol {eff:.1%} -> {verdict}")
-    if verdict != "OK":
-        violations.append(
-            f"headline emb/s {new['value']:.1f} < {floor:.1f} "
-            f"(best {best_value:.1f} from {best_src}, tol {eff:.1%})")
-
-    # Per-row gates against the most recent earlier record carrying the
-    # same row (engine rows are re-measured selectively; the freshest
-    # prior evidence is the comparison that means something).
-    new_rows = _walk_rows(new)
-    for path, row in sorted(new_rows.items()):
-        ref_row, ref_src = None, None
-        for src, rec in reversed(records[:-1]):
-            cand = _walk_rows(rec).get(path)
-            if cand is not None:
-                ref_row, ref_src = cand, src
-                break
-        if ref_row is None:
-            continue
-        eff = max(tol, _spread(row), _spread(ref_row))
-        if isinstance(row.get("emb_per_sec"), (int, float)) and \
-                isinstance(ref_row.get("emb_per_sec"), (int, float)):
-            floor = ref_row["emb_per_sec"] * (1.0 - eff)
-            if row["emb_per_sec"] < floor:
-                violations.append(
-                    f"{path}: emb/s {row['emb_per_sec']:.1f} < "
-                    f"{floor:.1f} (ref {ref_row['emb_per_sec']:.1f} from "
-                    f"{ref_src}, tol {eff:.1%})")
-        if isinstance(row.get("p99_ms"), (int, float)) and \
-                isinstance(ref_row.get("p99_ms"), (int, float)) and \
-                ref_row["p99_ms"] > 0:
-            ceil = ref_row["p99_ms"] * (1.0 + eff)
-            if row["p99_ms"] > ceil:
-                violations.append(
-                    f"{path}: p99 {row['p99_ms']:.2f} ms > {ceil:.2f} ms "
-                    f"(ref {ref_row['p99_ms']:.2f} from {ref_src}, "
-                    f"tol {eff:.1%})")
-    violations.extend(_ivf_hard_gates(new_rows))
-    violations.extend(_fused_probe_gates(new))
-    return violations
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
-        description="noise-aware bench regression gate")
-    ap.add_argument(
-        "--history", metavar="PATH",
-        help="gate a bench trajectory: a JSONL file of bench.py "
-        "records, oldest first — the newest measured record vs the "
-        "best earlier one",
-    )
-    ap.add_argument(
-        "--tol", type=float, default=DEFAULT_TOL,
-        help="base relative tolerance before the per-record window "
-        "spread widens it (default 0.05)",
-    )
+        description="artifact gates: validate one npairloss-*-v1 "
+        "artifact, or run the invariant linter (stdlib-only, jax-free)")
     ap.add_argument(
         "--fleet-report", dest="fleet_report", metavar="PATH",
-        help="gate a fleet report artifact instead of the bench "
-        "trajectory: schema-valid (npairloss-fleet-report-v1), "
-        "per-rank step counts agree, zero unattributed collective "
-        "bytes — the ci.sh fleet-smoke wiring",
+        help="gate a fleet report artifact: schema-valid "
+        "(npairloss-fleet-report-v1), per-rank step counts agree, "
+        "zero unattributed collective bytes — the ci.sh fleet-smoke "
+        "wiring",
     )
     ap.add_argument(
         "--expect-link", dest="expect_link", choices=["ici", "dcn"],
@@ -976,16 +708,16 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument(
         "--alerts", metavar="PATH",
-        help="gate a live-observatory alert log instead of the bench "
-        "trajectory: schema-valid (npairloss-alerts-v1) and no "
-        "unresolved critical alert — the ci.sh live-obs-smoke wiring",
+        help="gate a live-observatory alert log: schema-valid "
+        "(npairloss-alerts-v1) and no unresolved critical alert — "
+        "the ci.sh live-obs-smoke wiring",
     )
     ap.add_argument(
         "--remediation", metavar="PATH",
-        help="gate a remediation audit log instead of the bench "
-        "trajectory: schema-valid (npairloss-remediation-v1), every "
-        "action justified by a fired alert, no abandoned critical "
-        "remediation — the ci.sh chaos-suite wiring",
+        help="gate a remediation audit log: schema-valid "
+        "(npairloss-remediation-v1), every action justified by a "
+        "fired alert, no abandoned critical remediation — the ci.sh "
+        "chaos-suite wiring",
     )
     ap.add_argument(
         "--alerts-log", dest="alerts_log", metavar="PATH",
@@ -995,15 +727,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument(
         "--quality", metavar="PATH",
-        help="gate a shadow-recall quality log instead of the bench "
-        "trajectory: schema-valid (npairloss-quality-v1), every "
-        "recall-floor breach matched by a fired alert, no silently-"
-        "stalled shadow scorer — the ci.sh quality-smoke wiring",
+        help="gate a shadow-recall quality log: schema-valid "
+        "(npairloss-quality-v1), every recall-floor breach matched by "
+        "a fired alert, no silently-stalled shadow scorer — the ci.sh "
+        "quality-smoke wiring",
     )
     ap.add_argument(
         "--gameday", metavar="PATH",
-        help="gate a gameday verdict instead of the bench trajectory: "
-        "schema-valid (npairloss-gameday-v1) and PASSING — every "
+        help="gate a gameday verdict: schema-valid "
+        "(npairloss-gameday-v1) and PASSING — every "
         "injected fault remediated, SLOs held outside incident "
         "windows, zero dropped queries across the hot-swaps — with "
         "the fault blocks cross-checked against the run's serve "
@@ -1011,19 +743,19 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument(
         "--qtrace", metavar="PATH",
-        help="gate a query-trace exemplar artifact instead of the "
-        "bench trajectory: schema-valid (npairloss-qtrace-v1), stage "
-        "vocabulary and span nesting intact, trace ids unique, and "
+        help="gate a query-trace exemplar artifact: schema-valid "
+        "(npairloss-qtrace-v1), stage vocabulary and span nesting "
+        "intact, trace ids unique, and "
         "the exemplar worst case consistent with the logged p99 "
         "budget within the ring tolerance — the ci.sh qtrace-smoke "
         "wiring",
     )
     ap.add_argument(
         "--wal", metavar="PATH",
-        help="gate a durable-ingest WAL directory instead of the "
-        "bench trajectory: schema-valid (npairloss-wal-v1), record "
-        "CRCs and sealed-segment seals intact, sequence numbers "
-        "contiguous — the ci.sh cold-restart-smoke wiring",
+        help="gate a durable-ingest WAL directory: schema-valid "
+        "(npairloss-wal-v1), record CRCs and sealed-segment seals "
+        "intact, sequence numbers contiguous — the ci.sh "
+        "cold-restart-smoke wiring",
     )
     ap.add_argument(
         "--wal-watermark", dest="wal_watermark", type=int,
@@ -1035,9 +767,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument(
         "--static", nargs="?", const=REPO, default=None, metavar="ROOT",
         help="run the invariant linter (docs/STATICCHECK.md) over ROOT "
-        "(default: this repo) instead of the bench trajectory and fail "
-        "on any finding outside the committed allowlist — the ci.sh "
-        "staticcheck-stage wiring",
+        "(default: this repo) and fail on any finding outside the "
+        "committed allowlist — the ci.sh staticcheck-stage wiring",
     )
     ap.add_argument(
         "--static-diff", dest="static_diff", metavar="BASE",
@@ -1146,19 +877,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"bench_check OK (fleet report {args.fleet_report})")
         return 0
 
-    if not args.history:
-        ap.error("pick a gate: --history PATH or one of the artifact "
-                 "modes (see --help)")
-    records = load_history_records(args.history)
-    _log(f"{len(records)} measured record(s): "
-         + ", ".join(src for src, _ in records))
-    violations = check(records, tol=args.tol)
-    if violations:
-        for v in violations:
-            print(f"REGRESSION: {v}")
-        return 1
-    print(f"bench_check OK ({len(records)} records, no regressions)")
-    return 0
+    ap.error("pick a gate: --fleet-report, --alerts, --remediation, "
+             "--quality, --gameday, --qtrace, --wal, --tenants or "
+             "--static (see --help)")
 
 
 if __name__ == "__main__":

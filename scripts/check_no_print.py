@@ -6,7 +6,7 @@ sinks (docs/OBSERVABILITY.md) — a print() in library code bypasses both
 the embedder's logging configuration and the structured telemetry
 pipeline.  The user-facing surfaces are exempt: ``cli.py`` and
 ``__main__.py`` (their printed JSON lines ARE the product), plus
-everything outside the package (scripts/, tests/, bench.py).
+everything outside the package (scripts/, tests/, benchmarks/).
 
 Exit 0 when clean; exit 1 listing every offending file:line.
 

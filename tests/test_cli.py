@@ -173,24 +173,28 @@ def test_train_weights_finetune_start(tmp_path):
     assert rc == 0
 
 
-def test_bench_subcommand_forwards_args(monkeypatch):
-    """`npairloss_tpu bench --smoke` must forward --smoke to bench.py
-    instead of dying on argv re-parsing (argparse REMAINDER cannot
-    capture leading optionals in a subparser)."""
-    import npairloss_tpu.cli as cli
+def test_bench_is_not_a_subcommand(capsys):
+    """The benchmark is `python3 benchmarks/run.py` (BENCHMARK.json's
+    command); the CLI has no `bench` to forward to."""
+    with pytest.raises(SystemExit) as e:
+        main(["bench"])
+    assert e.value.code == 2
+    assert "invalid choice: 'bench'" in capsys.readouterr().err
 
-    seen = {}
 
-    def fake_bench(args):
-        seen["bench_args"] = args.bench_args
-        return 0
+def test_a_path_named_bench_keeps_the_arguments_after_it(
+        tmp_path, monkeypatch, capsys):
+    """An argv in which `bench` is a VALUE is parsed whole: the
+    forwarding that once cut every argv at that word, on any
+    subcommand, is gone with the subcommand."""
+    import json
+    import shutil
 
-    # main() builds its parser per call and resolves cmd_bench from
-    # module globals, so the patch takes effect.
-    monkeypatch.setattr(cli, "cmd_bench", fake_bench)
-    rc = cli.main(["bench", "--smoke", "--steps", "3"])
-    assert rc == 0
-    assert seen["bench_args"] == ["--smoke", "--steps", "3"]
+    monkeypatch.chdir(tmp_path)
+    shutil.copy(os.path.join(REPO, "examples", "tiny_net.prototxt"),
+                "bench")
+    assert main(["parse", "bench", "--json"]) == 0
+    assert isinstance(json.loads(capsys.readouterr().out), dict)
 
 
 def test_cli_time_command(capsys):

@@ -6,7 +6,7 @@ The load-bearing contract: with ``probes >= n_clusters`` every cluster
 is scored, so the IVF answer SET must equal the flat exact scan's at
 fp32 scoring — on one device and on the 8-device mesh.  Partial probes
 and reduced scoring dtypes trade recall for latency; those floors are
-pinned here and gated in the ``ivf_qps_1m`` bench row.
+pinned here.
 """
 
 import numpy as np
@@ -114,7 +114,7 @@ def test_full_probe_matches_flat_exactly(rng, mesh_width):
 def test_reduced_scoring_recall_floor(rng, scoring, floor):
     """bf16/int8 cluster-scan scoring at FULL probe: the only error
     source is the matmul dtype, and recall vs the fp32 oracle must
-    stay above the floor (the parity gate the bench row hardens)."""
+    stay above the floor."""
     emb, lab = _clustered_data(rng)
     q = _queries(rng, emb)
     flat = GalleryIndex.build(emb, lab, normalize=False)

@@ -57,8 +57,7 @@ ATOL = 1e-6  # the acceptance gate: fused == scan to 1e-6 scores
 def test_probe_impl_registry_pins_cli_choices():
     """CLI flag vocabulary == the registry (the _PRECISION_CHOICES
     pattern; the staticcheck vocab pass holds the same pin), and the
-    registry declares the 4 -> 2 dispatch-count drop the bench rows
-    stamp."""
+    registry declares the 4 -> 2 dispatch-count drop."""
     from npairloss_tpu.cli import _PROBE_IMPL_CHOICES
 
     assert set(_PROBE_IMPL_CHOICES) == set(PROBE_IMPLS)

@@ -224,11 +224,8 @@ def test_default_policy_hlo_contains_bf16_convolutions():
 @pytest.mark.slow
 def test_flagship_policy_loss_delta_bounded():
     """Same flagship trunk, same params, same batch: |loss(mxu) -
-    loss(fp32_parity)| stays small (the acceptance bound bench.py
-    reports at full scale as policy_fp32_loss_delta).  Slow-marked:
-    two GoogLeNet jits (~12s); every bench headline record re-reports
-    the delta at full scale and the tier-1 HLO pin covers the policy
-    threading itself."""
+    loss(fp32_parity)| stays small.  Slow-marked: two GoogLeNet jits
+    (~12s); the tier-1 HLO pin covers the policy threading itself."""
     from npairloss_tpu import REFERENCE_CONFIG
     from npairloss_tpu.models import flagship_model, jit_init
     from npairloss_tpu.ops.npair_loss import npair_loss

@@ -289,6 +289,28 @@ def test_vocab_choice_pin_drift(tmp_path):
                for k in _keys(run_suite(root), "vocab"))
 
 
+@pytest.mark.parametrize("flag,finding", [
+    ("--no-such-flag", True), ("--trace 1", False)])
+def test_vocab_lints_the_documented_benchmark_command(
+        tmp_path, flag, finding):
+    """A fenced `python3 benchmarks/run.py ...` is held to THAT file's
+    argparse: the benchmark that exists is the one the docs may cite."""
+    root = _write_tree(tmp_path, {
+        "benchmarks/run.py": """            import argparse
+            ap = argparse.ArgumentParser()
+            ap.add_argument("--workload", required=True)
+            ap.add_argument("--trace", type=int, default=0)
+        """,
+        "README.md": f"""            ```
+            python3 benchmarks/run.py --workload x {flag}
+            ```
+        """,
+    })
+    keys = _keys(run_suite(root), "vocab")
+    assert any("flag---no-such-flag" in k for k in keys) if finding \
+        else not keys
+
+
 def test_vocab_undocumented_watchdog(tmp_path):
     root = _write_tree(tmp_path, {
         "npairloss_tpu/obs/live/watchdogs.py": """\
