@@ -266,6 +266,12 @@ class _Span:
             self._t0 = self.tracer.now_us()
         return self
 
+    def note(self, **args: Any) -> None:
+        """Arguments known only inside the block (how a batch formed).
+        They reach the tracer's event, written at exit; the profiler's
+        annotation took its arguments at entry and keeps those."""
+        self.args = {**self.args, **args}
+
     def __exit__(self, *exc) -> bool:
         tr = self.tracer
         if tr is not None:

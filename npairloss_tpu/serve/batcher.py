@@ -3,10 +3,11 @@
 Serving traffic arrives one query at a time; the accelerator wants
 fixed-shape micro-batches.  The batcher sits between them: callers
 ``submit()`` individual queries and get a ``Future``; a single
-dispatcher thread coalesces queued queries until either the largest
-padding bucket is full or the OLDEST queued query's latency deadline
-expires, then hands the batch to ``dispatch_fn`` and distributes the
-per-query results.
+dispatcher thread takes the oldest queued query and everything queued
+behind it, up to the largest padding bucket, waits for more only while
+the queue is empty and that query's latency deadline has not passed,
+then hands the batch to ``dispatch_fn`` and distributes the per-query
+results.
 
 Admission is a BOUNDED queue, modeled on the training pipeline's
 ``DispatchController`` (pipeline/controller.py): when the engine falls
@@ -15,14 +16,23 @@ reject-with-backpressure, never unbounded growth.  The caller (the
 server front end) turns that into a rejected-request answer the client
 can retry against another replica.
 
-The deadline is measured from the first query's SUBMIT time, so queue
-wait counts against it: a query never waits more than ``max_delay_ms``
-for co-riders before its batch dispatches (dispatch+compute time is on
-top — bound it by warming the engine, docs/SERVING.md).
+The deadline bounds the WAIT for co-riders, never the taking of what
+is queued.  A turn first takes every query that is already there,
+without waiting, until the batch is full or the owner's ``fits`` hook
+stops it; taking them costs the head no time.  Only when the queue is
+empty and the batch is not full does it look at the clock: the deadline
+is measured from the head's SUBMIT time, so queue wait counts against
+it and a head that has outlived it dispatches at once with whoever is
+there; otherwise it waits for co-riders until the deadline and no
+longer.  So a query never waits more than ``max_delay_ms`` for
+co-riders (dispatch+compute time is on top — bound it by warming the
+engine, docs/SERVING.md), and a backlog goes out in full batches
+whatever ``max_delay_ms`` is.
 """
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import contextlib
 import dataclasses
@@ -73,14 +83,19 @@ class MicroBatcher:
     pulls it off the queue into the forming batch — the queue-wait/
     assemble boundary per-query tracing needs (obs.qtrace), a no-op
     when unset.  ``name`` is the replica this batcher feeds.
-    ``fits(batch_items, item)`` (optional) lets the owner bound a
-    dispatch in something other than requests (a token engine's
-    ``token_budget``): a co-rider it refuses is held back, heads the
-    next turn, and order is kept; the head of a turn always goes.
+    ``fits(items)`` (optional) lets the owner bound a dispatch in
+    something other than requests (a token engine's padded tokens): it
+    is shown the batch so far with the candidates behind it, in order,
+    and returns how many of them, counted from the first, ride in one
+    dispatch.  It judges them TOGETHER (two rows may not fit a bucket
+    that four fill).  The rest is held back, in order, to head the next
+    turns; the head of a turn always goes.
 
     The dispatcher thread's time is spanned whole (obs.tracing):
     ``serve/idle`` (waiting for a head: nothing was queued) ->
-    ``serve/batch`` (waiting for co-riders) -> ``serve/dispatch`` ->
+    ``serve/batch`` (forming the batch: ``size``, of which ``drained``
+    co-riders were taken without waiting, ``held`` refused by ``fits``,
+    ``waited_ms`` under the deadline) -> ``serve/dispatch`` ->
     ``serve/reply`` (the futures' done-callbacks run inline here).
     Every span of one turn, the engine's included, carries the turn's
     sequence number ``batch`` (and ``replica``).
@@ -93,16 +108,19 @@ class MicroBatcher:
         on_batch: Optional[Callable[[Dict[str, Any]], None]] = None,
         on_pick: Optional[Callable[[Any], None]] = None,
         name: Optional[str] = None,
-        fits: Optional[Callable[[List[Any], Any], bool]] = None,
+        fits: Optional[Callable[[List[Any]], int]] = None,
     ):
         self.cfg = cfg
         self._dispatch_fn = dispatch_fn
         self._on_batch = on_batch
         self._on_pick = on_pick
         self._fits = fits
-        # The co-rider ``fits`` refused last turn: off the queue, not
-        # yet in a batch; only the dispatcher thread touches it.
-        self._held = None
+        # What ``fits`` refused: off the queue, not yet in a batch, in
+        # submission order and fewer than ``max_batch``; only the
+        # dispatcher thread touches it (and ``_stopping``: the
+        # sentinel has been taken off the queue, nothing is behind it).
+        self._held: collections.deque = collections.deque()
+        self._stopping = False
         self._tags = {"replica": name} if name else {}
         self._q: queue.Queue = queue.Queue(maxsize=cfg.max_queue)
         self._thread: Optional[threading.Thread] = None
@@ -175,7 +193,8 @@ class MicroBatcher:
 
     @property
     def queue_depth(self) -> int:
-        return self._q.qsize()
+        """Admitted and not yet in a batch: queued or held back."""
+        return self._q.qsize() + len(self._held)
 
     def submit(self, item: Any) -> concurrent.futures.Future:
         """Admit one query; returns its Future.  Raises
@@ -206,11 +225,8 @@ class MicroBatcher:
 
     def _turn(self) -> bool:
         """One head, its co-riders, their dispatch; True = stop."""
-        delay = max(self.cfg.max_delay_ms, 0.0) / 1e3
         with tracing.span("serve/idle"):
-            head, self._held = self._held, None
-            if head is None:
-                head = self._q.get()
+            head = self._held.popleft() if self._held else self._q.get()
         if head is _STOP:
             return True
         if failpoints.should_fire("serve.queue_stall"):
@@ -225,31 +241,77 @@ class MicroBatcher:
             # dispatcher is queue wait, not assemble time.
             self._on_pick(head[0])
         batch = [head]
-        deadline = head[2] + delay
-        stop_after = False
-        with tracing.span("serve/batch"):
-            while len(batch) < self.cfg.max_batch:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
+        deadline = head[2] + max(self.cfg.max_delay_ms, 0.0) / 1e3
+        drained, waited = 0, 0.0
+        # The one co-rider the last wait brought; it is judged with
+        # what queued up behind it.
+        late: list = []
+        with tracing.span("serve/batch") as sp:
+            while True:
+                # What is already there, first: taking it costs the
+                # head no wait, whatever the clock says.
+                fresh = late + self._take_queued(
+                    self.cfg.max_batch - len(batch) - len(late))
+                drained += max(self._admit(batch, fresh) - len(late), 0)
+                if self._stopping or self._held \
+                        or len(batch) >= self.cfg.max_batch:
+                    break
+                # The queue is empty and the batch is not full: only
+                # now the deadline, which bounds the wait for co-riders.
+                t = time.perf_counter()
+                if t >= deadline:
                     break
                 try:
-                    item = self._q.get(timeout=remaining)
+                    late = [self._q.get(timeout=deadline - t)]
                 except queue.Empty:
+                    late = []
+                waited += time.perf_counter() - t
+                if not late:
                     break
-                if item is _STOP:
-                    stop_after = True
+                if late[0] is _STOP:
+                    self._stopping = True
                     break
-                if self._fits is not None and not self._fits(
-                        [b[0] for b in batch], item[0]):
-                    self._held = item
-                    break
-                if self._on_pick is not None:
-                    self._on_pick(item[0])
-                batch.append(item)
-        self._run_batch(batch)
-        return stop_after
+            sp.note(size=len(batch), drained=drained,
+                    held=len(self._held), waited_ms=waited * 1e3)
+        self._run_batch(batch, drained)
+        # The sentinel was the queue's last entry: what is held back
+        # is all that is left, and it heads the next turns.
+        return self._stopping and not self._held
 
-    def _run_batch(self, batch) -> None:
+    def _take_queued(self, room: int) -> list:
+        """Up to ``room`` requests that are already here, without
+        waiting: what was held back first, then the queue."""
+        taken: list = []
+        while len(taken) < room:
+            if self._held:
+                taken.append(self._held.popleft())
+                continue
+            try:
+                item = self._q.get_nowait()
+            except queue.Empty:
+                break
+            if item is _STOP:
+                self._stopping = True
+                break
+            taken.append(item)
+        return taken
+
+    def _admit(self, batch: list, fresh: list) -> int:
+        """``fresh`` joins ``batch`` as far as ``fits`` lets it (all of
+        it without the hook); the rest is held back in order.  Returns
+        how many joined."""
+        n = len(fresh)
+        if fresh and self._fits is not None:
+            n = self._fits([b[0] for b in batch + fresh]) - len(batch)
+            n = min(max(n, 0), len(fresh))
+        if self._on_pick is not None:
+            for item in fresh[:n]:
+                self._on_pick(item[0])
+        batch.extend(fresh[:n])
+        self._held.extendleft(reversed(fresh[n:]))
+        return n
+
+    def _run_batch(self, batch, drained: int) -> None:
         items = [b[0] for b in batch]
         t0 = time.perf_counter()
         try:
@@ -280,5 +342,6 @@ class MicroBatcher:
                 "size": len(items),
                 "dispatch_ms": (now - t0) * 1e3,
                 "oldest_wait_ms": (t0 - batch[0][2]) * 1e3,
-                "queue_depth": self._q.qsize(),
+                "queue_depth": self.queue_depth,
+                "drained": drained,
             })

@@ -328,11 +328,10 @@ class RetrievalServer:
             engines, batcher_cfg, self._replica_dispatch,
             on_batch=self._record_batch,
             on_pick=self._qtrace_pick if qtrace is not None else None,
-            # A token engine bounds a dispatch in TOKENS (rows bucket x
-            # length bucket <= token_budget), not in requests alone.
-            fits=(self._fits_token_budget
-                  if _token_cfg(self.engine) is not None
-                  and self.engine.cfg.token_budget else None),
+            # A token engine bounds a dispatch in padded TOKENS (rows
+            # bucket x length bucket), not in requests alone.
+            fits=(self._token_coriders
+                  if _token_cfg(self.engine) is not None else None),
         )
         self._lat = collections.deque(maxlen=max(cfg.latency_window, 1))
         # THIS window's latencies, cleared at each emission: window rows
@@ -707,25 +706,39 @@ class RetrievalServer:
                 answers[i] = ans
         return answers
 
-    def _fits_token_budget(self, batch_items, item) -> bool:
-        """The batcher's ``fits``: whether ``item`` may join
-        ``batch_items`` inside the primary engine's ``token_budget``,
-        counted as the dispatch would run it (rows bucket x length
-        bucket).  A record with no usable token row counts one token:
-        it fails alone at parse, whatever it rides with."""
+    def _token_coriders(self, items) -> int:
+        """The batcher's ``fits`` on a token tier: how many of ``items``,
+        counted from the first, ride in ONE dispatch.  A co-rider must
+        cost nothing: the dispatch's padded tokens, counted as it would
+        run (rows bucket x length bucket), stay within the primary
+        engine's ``token_budget`` AND within the sum of what its rows
+        would run padded alone.  Then riding together can only save the
+        per-dispatch search and host share, never add device work.
+        Prefixes are judged whole: with rows buckets (1, 4) two rows of
+        one length bucket fail and four pass, so the answer is the
+        LONGEST prefix that holds, not the first that fails.  A record
+        with no usable token row counts one token: it fails alone at
+        parse, whatever it rides with."""
+        engine = self.engine
+        cfg = engine.cfg
+        longest = cfg.length_buckets[-1]
+
         def length(rec):
             try:
-                return max(len(rec["input"]), 1)
+                n = len(rec["input"])
             except (KeyError, TypeError):
                 return 1
+            return n if 1 <= n <= longest else 1
 
-        engine = self.engine
-        try:
-            return engine.padded_tokens(
-                [length(r) for r in batch_items] + [length(item)]
-            ) <= engine.cfg.token_budget
-        except ValueError:  # longer than the last bucket: parse answers it
-            return True
+        lens = [length(r) for r in items[:cfg.buckets[-1]]]
+        best, alone = 1, 0
+        for k, n in enumerate(lens, 1):
+            alone += engine.padded_tokens([n])
+            together = engine.padded_tokens(lens[:k])
+            if together <= alone and (
+                    not cfg.token_budget or together <= cfg.token_budget):
+                best = k
+        return best
 
     def _dispatch_core(self, items: List[Dict[str, Any]],
                        engine: Optional[QueryEngine] = None,

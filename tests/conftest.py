@@ -30,6 +30,17 @@ import pytest
 
 
 @pytest.fixture
+def tracer():
+    """A fresh ``SpanTracer`` installed as the process's for one test."""
+    from npairloss_tpu.obs import tracing
+
+    tr = tracing.SpanTracer()
+    prev = tracing.install(tr)
+    yield tr
+    tracing.install(prev)
+
+
+@pytest.fixture
 def rng():
     return np.random.default_rng(0)
 

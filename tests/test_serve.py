@@ -257,6 +257,179 @@ def test_batcher_backpressure_rejects_not_queues():
         b.close()
 
 
+def _gated_batcher(cfg, **kw):
+    """A batcher whose dispatches wait for ``release``: a backlog forms
+    behind the first one.  -> (batcher, entered, release, batches)."""
+    entered, release, batches = threading.Event(), threading.Event(), []
+
+    def dispatch(items):
+        batches.append(list(items))
+        entered.set()
+        release.wait(timeout=10.0)
+        return items
+
+    b = MicroBatcher(dispatch, cfg, **kw).start()
+    return b, entered, release, batches
+
+
+def _batch_spans(tr):
+    return [e["args"] for e in tr.events_since(0)[0]
+            if e["name"] == "serve/batch"]
+
+
+@pytest.mark.parametrize("delay_ms", [0.0, 5.0, 250.0])
+def test_batcher_backlog_goes_out_in_full_batches(delay_ms):
+    """What is queued is taken before the clock is read: behind a slow
+    dispatch every head has outlived a 0 or 5 ms deadline, and the
+    backlog still goes out ``max_batch`` at a time, in submission order
+    (it went out in singles when the deadline was looked at first)."""
+    b, entered, release, batches = _gated_batcher(
+        BatcherConfig(max_batch=4, max_delay_ms=delay_ms, max_queue=32))
+    try:
+        first = b.submit(-1)
+        assert entered.wait(timeout=5.0)
+        futs = [b.submit(i) for i in range(10)]
+        assert b.queue_depth == 10
+        time.sleep(0.02)
+        release.set()
+        assert first.result(timeout=10.0) == -1
+        assert [f.result(timeout=10.0) for f in futs] == list(range(10))
+    finally:
+        release.set()
+        b.close()
+    assert batches == [[-1], [0, 1, 2, 3], [4, 5, 6, 7], [8, 9]]
+    assert b.dispatched == 11 and b.batches == 4
+
+
+def test_batcher_closed_loop_runs_full_batches():
+    """Eight callers, each sending its next request from its answer's
+    done-callback (inline, on the dispatcher thread): every turn finds
+    at least ``max_batch`` queued, so but for the first batch and the
+    tail every dispatch is full, with ``max_delay_ms`` long passed."""
+    sizes, done, total = [], threading.Event(), 80
+    lock, sent, answered = threading.Lock(), [0], [0]
+
+    def dispatch(items):
+        sizes.append(len(items))
+        time.sleep(0.002)  # a dispatch outlasts the 1 ms deadline
+        return items
+
+    b = MicroBatcher(dispatch, BatcherConfig(max_batch=4, max_delay_ms=1.0,
+                                             max_queue=16)).start()
+
+    def launch():
+        with lock:
+            if sent[0] >= total:
+                return
+            sent[0] += 1
+            i = sent[0]
+        b.submit(i).add_done_callback(finished)
+
+    def finished(_fut):
+        launch()
+        with lock:
+            answered[0] += 1
+            if answered[0] == total:
+                done.set()
+
+    try:
+        for _ in range(8):
+            launch()
+        assert done.wait(timeout=20.0)
+    finally:
+        b.close()
+    assert sum(sizes) == total == b.dispatched
+    assert all(n == 4 for n in sizes[1:-3]), sizes
+
+
+def test_batcher_deadline_bounds_the_wait_for_coriders_only(tracer):
+    """A head on an empty queue waits for co-riders until ``max_delay_ms``
+    after its SUBMIT and no longer; one that has outlived its deadline
+    in the queue is dispatched at once."""
+    stats = []
+    b, entered, release, batches = _gated_batcher(
+        BatcherConfig(max_batch=8, max_delay_ms=40.0, max_queue=16),
+        on_batch=stats.append)
+    try:
+        a = b.submit(0)
+        assert entered.wait(timeout=5.0)
+        c = b.submit(1)
+        time.sleep(0.08)  # c outlives its 40 ms in the queue
+        release.set()
+        assert (a.result(timeout=10.0), c.result(timeout=10.0)) == (0, 1)
+    finally:
+        release.set()
+        b.close()
+    first, second = _batch_spans(tracer)[:2]
+    assert batches == [[0], [1]]
+    # dispatched max_delay_ms after its submit (and whatever the
+    # machine was late by), having waited since it was picked
+    assert 0.0 < first["waited_ms"] < 1000.0
+    assert 35.0 <= stats[0]["oldest_wait_ms"] < 1000.0
+    assert second["waited_ms"] == 0.0 and second["size"] == 1
+
+
+def test_batcher_counts_how_a_batch_formed(tracer):
+    """``serve/batch`` carries ``size``, ``drained`` (co-riders taken
+    without waiting), ``held`` (refused by ``fits``) and ``waited_ms``;
+    ``on_batch`` gains ``drained``."""
+    stats = []
+    b, entered, release, batches = _gated_batcher(
+        BatcherConfig(max_batch=4, max_delay_ms=0.0, max_queue=16),
+        on_batch=stats.append, fits=lambda items: min(len(items), 3))
+    try:
+        futs = [b.submit(0)]
+        assert entered.wait(timeout=5.0)
+        futs += [b.submit(i) for i in range(1, 6)]
+        release.set()
+        assert [f.result(timeout=10.0) for f in futs] == list(range(6))
+    finally:
+        release.set()
+        b.close()
+    assert batches == [[0], [1, 2, 3], [4, 5]]
+    spans = _batch_spans(tracer)
+    assert [(s["size"], s["drained"], s["held"]) for s in spans] == [
+        (1, 0, 0), (3, 2, 1), (2, 1, 0)]
+    assert all(s["waited_ms"] == 0.0 and s["batch"] == i + 1
+               for i, s in enumerate(spans))
+    assert [(s["size"], s["drained"]) for s in stats] == [
+        (1, 0), (3, 2), (2, 1)]
+    # the depth a batch leaves behind counts what was held back
+    assert [s["queue_depth"] for s in stats] == [5, 2, 0]
+
+
+@pytest.mark.parametrize("fits,want", [
+    (None, [[0], [1, 2, 3, 4], [5, 6]]),
+    (lambda items: 1, [[0], [1], [2], [3], [4], [5], [6]]),
+    (lambda items: min(len(items), 2), [[0], [1, 2], [3, 4], [5, 6]]),
+], ids=["no-hook", "singles", "pairs"])
+def test_batcher_stop_met_mid_drain_answers_everything(fits, want):
+    """A draining ``close`` whose sentinel is met while a turn takes
+    what is queued: that batch, and everything the hook held back,
+    is still dispatched and answered before the thread exits."""
+    b, entered, release, batches = _gated_batcher(
+        BatcherConfig(max_batch=4, max_delay_ms=1000.0, max_queue=16),
+        fits=fits)
+    futs = [b.submit(0)]
+    assert entered.wait(timeout=5.0)
+    futs += [b.submit(i) for i in range(1, 7)]
+    closer = threading.Thread(target=b.close)
+    closer.start()
+    deadline = time.perf_counter() + 5.0
+    while b._q.qsize() < 7 and time.perf_counter() < deadline:
+        time.sleep(0.005)  # the sentinel is queued behind the six
+    t0 = time.perf_counter()
+    release.set()
+    closer.join(timeout=10.0)
+    assert not closer.is_alive()
+    # the last batch is not full: it did not sit out its second
+    assert time.perf_counter() - t0 < 0.5
+    assert [f.result(timeout=1.0) for f in futs] == list(range(7))
+    assert batches == want
+    with pytest.raises(QueueFullError):
+        b.submit(99)
+
+
 # -- server: drain + zero-recompile steady state ----------------------------
 
 

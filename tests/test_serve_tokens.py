@@ -3,15 +3,12 @@
 ``token_budget``, on the tiny Olmo-Hybrid preset; and that a float-input
 engine is what it was."""
 
-import threading
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from npairloss_tpu.models import get_model
-from npairloss_tpu.obs import tracing
 from npairloss_tpu.serve.batcher import BatcherConfig, MicroBatcher
 from npairloss_tpu.serve.engine import (EngineConfig, QueryEngine,
                                         ServeCompileError)
@@ -48,22 +45,20 @@ def engine(strict_env):
     return eng
 
 
-@pytest.fixture
-def server(engine):
+def _server(engine, start=True):
     srv = RetrievalServer(engine,
                           BatcherConfig(max_batch=4, max_delay_ms=40.0, max_queue=64),
                           ServerConfig(metrics_window=0))
-    srv.replicaset.start()
-    yield srv
-    srv.replicaset.close()
+    if start:
+        srv.replicaset.start()
+    return srv
 
 
 @pytest.fixture
-def tracer():
-    tr = tracing.SpanTracer()
-    prev = tracing.install(tr)
-    yield tr
-    tracing.install(prev)
+def server(engine):
+    srv = _server(engine)
+    yield srv
+    srv.replicaset.close()
 
 
 def _docs(lengths, seed=1):
@@ -146,70 +141,122 @@ def test_a_malformed_record_fails_alone(server, bad, why):
     assert len(a["neighbors"]) == len(b["neighbors"]) == CFG["top_k"]
 
 
-def test_the_budget_is_counted_as_the_dispatch_would_run(server):
-    fits = server._fits_token_budget
-    rec = lambda n: {"input": list(range(n))}
-    assert server.batcher._fits == fits
-    assert fits([rec(10)], rec(12))            # 4 rows x 16 = 64
-    assert fits([rec(10), rec(12), rec(3)], rec(30))   # 4 x 32 = 128
-    assert not fits([rec(10)], rec(33))        # 4 x 64 = 256
-    assert not fits([rec(40)], rec(40))
-    # what parse will refuse counts one token and rides along to fail alone
-    assert fits([rec(10)], {"input": None}) and fits([rec(10)], {"id": 1})
-    assert fits([rec(10)], rec(65))
+def _rec(n):
+    return {"input": list(range(n))}
 
 
-def test_backlog_of_mixed_lengths_never_leaves_the_budget(engine, server, tracer):
-    """Thirty documents at once, long beside short: every dispatch stays
-    within the budget (a pair outside it was never warmed and the strict
-    guard would fail the batch) and every answer comes, in order.  How
-    many ride together is the machine's speed (a head whose deadline has
-    passed goes alone), so it is not asserted."""
+@pytest.mark.parametrize("lengths,rides,why", [
+    ((10, 12), 1, "4 rows x 16 = 64 padded tokens for 32 alone"),
+    ((10, 12, 3), 1, "64 for 48"),
+    ((10, 12, 3, 16), 4, "64 for 64: the bucket is full"),
+    ((10, 12, 3, 16, 9), 4, "the engine's last rows bucket is 4"),
+    ((10, 12, 3, 30), 1, "4 x 32 = 128 for 80"),
+    ((30, 17, 32, 20), 4, "128 for 128, and within the budget"),
+    ((40, 40, 40, 40), 1, "256 for 256, but the budget is 128"),
+    ((10, 33), 1, "4 x 64 = 256: past the budget and past 80"),
+    ((64,), 1, "the head of a turn always goes"),
+])
+def test_the_budget_is_counted_as_the_dispatch_would_run(server, lengths, rides, why):
+    """The hook counts a dispatch as it would RUN (rows bucket x length
+    bucket) and admits the longest prefix that stays within the budget
+    and within what its rows would run padded alone."""
+    assert server.batcher._fits == server._token_coriders
+    assert server._token_coriders([_rec(n) for n in lengths]) == rides, why
+
+
+def test_what_parse_will_refuse_counts_one_token(server):
+    # it rides along to fail alone, whatever it rides with
+    bad = [{"input": None}, {"id": 1}, _rec(65)]
+    assert server._token_coriders([_rec(10)] + bad) == 4
+    assert server._token_coriders([_rec(40)] + bad) == 1
+
+
+def _alone(engine, lengths):
+    return sum(engine.padded_tokens([n]) for n in lengths)
+
+
+def test_backlog_of_mixed_lengths_never_leaves_the_budget(engine, tracer):
+    """Thirty documents queued before the dispatcher starts, long beside
+    short: every dispatch stays within the budget (a pair outside it was
+    never warmed and the strict guard would fail the batch), none runs
+    more padded tokens than its rows would alone, every answer comes, in
+    order; and the four of one length bucket that meet ride together."""
     lengths = [64, 5, 40, 7, 9, 33, 64, 12, 3, 16] * 3
+    lengths[11:15] = [20, 17, 32, 25]   # four of the 32 bucket, adjacent
     compiles = engine.compiles_after_warmup
-    futs = [server.submit({"id": i, "input": d.tolist()})[0]
+    srv = _server(engine, start=False)
+    futs = [srv.submit({"id": i, "input": d.tolist()})[0]
             for i, d in enumerate(_docs(lengths, seed=2))]
-    answers = [f.result(timeout=120) for f in futs]
+    srv.replicaset.start()
+    try:
+        answers = [f.result(timeout=120) for f in futs]
+    finally:
+        srv.replicaset.close()
+    assert [a["id"] for a in answers] == list(range(len(lengths)))
     assert all("error" not in a for a in answers)
     spans = _encode_spans(tracer)
-    assert max(s["padded_tokens"] for s in spans) <= CFG["token_budget"]
+    assert sum(s["rows"] for s in spans) == len(lengths)
+    at = 0
+    for s in spans:   # order of service is submission's
+        rows = lengths[at:at + s["rows"]]
+        at += s["rows"]
+        assert s["tokens"] == sum(rows)
+        assert s["padded_tokens"] <= min(CFG["token_budget"], _alone(engine, rows))
+    assert (4, 32) in [(s["rows"], s["length_bucket"]) for s in spans]
     assert engine.compiles_after_warmup == compiles
 
 
-def test_fits_holds_a_corider_back_and_keeps_order():
-    """The batcher alone: ``fits`` refuses a co-rider, which heads the
-    next turn; the head of a turn always goes (9 is over the budget by
-    itself); order is submission's."""
+@pytest.mark.parametrize("bucket,lengths", [(16, (5, 16, 9, 1)), (32, (17, 32, 20, 30))])
+def test_four_documents_of_one_length_bucket_ride_in_one_dispatch(
+        engine, tracer, bucket, lengths):
+    srv = _server(engine, start=False)
+    futs = [srv.submit({"id": i, "input": d.tolist()})[0]
+            for i, d in enumerate(_docs(lengths, seed=3))]
+    srv.replicaset.start()
+    try:
+        assert [f.result(timeout=60)["id"] for f in futs] == [0, 1, 2, 3]
+    finally:
+        srv.replicaset.close()
+    (span,) = _encode_spans(tracer)
+    assert (span["rows"], span["bucket"], span["length_bucket"]) == (4, 4, bucket)
+    assert span["padded_tokens"] == 4 * bucket == _alone(engine, lengths)
+
+
+def _only(value, rides):
+    """A hook in the batcher's form: of a run of ``value`` at the front,
+    ``rides`` of them go together or one goes alone (a pair may not fit
+    a bucket that three fill)."""
+    def fits(items):
+        run = next((k for k, x in enumerate(items) if x != items[0]), len(items))
+        return rides if items[0] == value and run >= rides else 1
+    return fits
+
+
+def test_fits_holds_coriders_back_and_keeps_order():
+    """The batcher alone: ``fits`` judges the candidates together and
+    admits a prefix; what it refuses is held back IN ORDER, heads the
+    next turns and is judged again with what is queued behind it; the
+    head of a turn always goes; order is submission's."""
     batches = []
 
     def dispatch(items):
         batches.append(list(items))
         return items
 
-    b = MicroBatcher(dispatch, BatcherConfig(max_batch=8, max_delay_ms=500.0, max_queue=32),
-                     fits=lambda batch, item: sum(batch) + item <= 8).start()
+    b = MicroBatcher(dispatch, BatcherConfig(max_batch=4, max_delay_ms=500.0, max_queue=32),
+                     fits=_only(4, rides=3))
+    order = (7, 4, 4, 3, 4, 4, 4, 9, 4, 4)
+    futs = [b.submit(n) for n in order]
+    assert b.queue_depth == len(order)
+    b.start()
     try:
-        futs = [b.submit(n) for n in (0, 5, 3, 4, 2, 9, 1)]
-        assert [f.result(timeout=10.0) for f in futs] == [0, 5, 3, 4, 2, 9, 1]
+        assert [f.result(timeout=10.0) for f in futs] == list(order)
     finally:
         b.close()
-    assert batches == [[0, 5, 3], [4, 2], [9], [1]]
-    assert b.dispatched == 7 and b.batches == 4
-
-
-def test_a_held_corider_is_answered_on_close():
-    release = threading.Event()
-
-    def dispatch(items):
-        release.wait(timeout=10.0)
-        return items
-
-    b = MicroBatcher(dispatch, BatcherConfig(max_batch=4, max_delay_ms=300.0, max_queue=8),
-                     fits=lambda batch, item: False).start()
-    futs = [b.submit(i) for i in range(3)]
-    release.set()
-    b.close()
-    assert [f.result(timeout=10.0) for f in futs] == [0, 1, 2]
+    # 7 alone, holding 4, 4, 3; two 4s do not make three, so each goes
+    # alone; 3 alone; the three 4s together, holding 9; ...
+    assert batches == [[7], [4], [4], [3], [4, 4, 4], [9], [4], [4]]
+    assert b.dispatched == len(order) and b.batches == 8 and b.queue_depth == 0
 
 
 @pytest.mark.parametrize("kwargs,why", [

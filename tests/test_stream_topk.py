@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from npairloss_tpu.obs import tracing
 from npairloss_tpu.serve import EngineConfig, GalleryIndex, QueryEngine
 from npairloss_tpu.serve.engine import (
     _NEG_FILL, _SCAN_GROUP, _scored_matmul, _stream_topk)
@@ -126,14 +125,6 @@ def test_the_counter_counts_the_turns_whose_merge_ran(order, merged):
     assert turns == 6 and list(scan) == [6, merged]
     np.testing.assert_array_equal(s, ds)
     np.testing.assert_array_equal(r, dr)
-
-
-@pytest.fixture
-def tracer():
-    tr = tracing.SpanTracer()
-    prev = tracing.install(tr)
-    yield tr
-    tracing.install(prev)
 
 
 def _scan_spans(tr):
