@@ -29,6 +29,7 @@ if "length_buckets" not in EngineConfig.__dataclass_fields__:
                       "cannot run on it")
 
 embed = ref.embed
+stages = ref.stages  # optional: the reference a block at a time (run_serve.embed_pool)
 gated_delta_cost = ref.gated_delta_cost
 
 

@@ -143,7 +143,6 @@ def train(cell, devices, args):
 
 
 def serve(cell, devices, args):
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -179,8 +178,7 @@ def serve(cell, devices, args):
         if k < args.controls:
             # the control need not serve: the reference in the lower
             # precision puts its own ten first; read them as answers
-            p = jax.tree_util.tree_map(jnp.asarray, ctx["host_params"])
-            emb = run_serve.embed_pool(adapter, p, pool, 32,
+            emb = run_serve.embed_pool(adapter, ctx["host_params"], pool, 32,
                                        quant=cfg["precision"]["control"])
             s, r = retrieval.exact_topk(emb, ctx["gallery"], top_k)
             row["control"] = run_serve.serve_numbers(

@@ -1,7 +1,7 @@
 """The upper reading of a serving cell's limits where the float32
 reference fills the chip by itself (a tower of billions of parameters):
-``calibrate.py`` keeps the program's server beside the reference, which
-does not fit there.  Here no server is built.  A seed at a time: the
+``calibrate.py`` keeps the program's server beside the reference's
+stage and activations; here no server is built.  A seed at a time: the
 plain reference embeds the cell's pool in float32 and again with every
 matrix product's operands rounded to a narrower type
 (``precision.control``, or ``--quants a,b``); the narrower embedding's
@@ -47,8 +47,6 @@ def main():
         from npairloss_tpu.pipeline.compile_cache import enable_compile_cache
 
         enable_compile_cache()
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from benchmarks.harness import run_serve, weights
@@ -66,11 +64,9 @@ def main():
         seed = args.first_seed + 7919 * k
         ctx = {"host_params": weights.widened(weights.make_params(adapter, cfg, seed)),
                "pool": adapter.query_pool(cfg, mix, seed), "gallery": gallery}
-        params = jax.tree_util.tree_map(jnp.asarray, ctx["host_params"])
-        ref = run_serve.embed_pool(adapter, params, ctx["pool"], block)
-        low = {q: run_serve.embed_pool(adapter, params, ctx["pool"], block, quant=q)
-               for q in quants}
-        del params  # serve_numbers puts its own float32 tree on the device
+        ref = run_serve.embed_pool(adapter, ctx["host_params"], ctx["pool"], block)
+        low = {q: run_serve.embed_pool(adapter, ctx["host_params"], ctx["pool"], block,
+                                       quant=q) for q in quants}
         cos = (ref @ ref.T)[~np.eye(len(ref), dtype=bool)]
         row = {"workload": cell.name, "seed": seed, "limits": mix["limits"],
                "pool_cosine": {"min": float(cos.min()), "mean": float(cos.mean()),

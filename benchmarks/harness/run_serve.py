@@ -25,28 +25,52 @@ def as_answers(rows, scores):
                 for rr, ss in zip(rows, scores)])
 
 
-def embed_pool(adapter, params, pool, block, quant=None):
+def embed_pool(adapter, host_params, pool, block, quant=None):
     """The plain reference's embedding of every pool entry, rows in key
     order.  Entries may differ in shape and dtype: they are grouped by
-    both, and each group goes through ``adapter.embed`` stacked in blocks
-    of ``block``."""
-    embed = jax.jit(lambda p, x: adapter.embed(p, x, quant=quant))
+    both, and each group is stacked in blocks of ``block``.
+
+    The reference's weights stream a STAGE at a time.  An adapter may
+    answer one optional question, ``stages(host_params)``: the reference
+    as an ordered list of (sub-tree of ``host_params``, function), each
+    function taking its sub-tree and the activations to the next
+    activations (the first stage's are the stacked inputs, the last's
+    the embeddings), with ``quant`` as ``embed`` takes it.  One stage's
+    weights go to the device, every block of the pool goes through it,
+    the activations wait on the host in float32, and the stage is freed
+    before the next is loaded: the device holds the largest stage and a
+    block's activations, never the whole tree.  The host holds the whole
+    pool's activations between stages, which bounds the pool (cell 5: 48
+    documents, 111k tokens x 3840 x 4 B = 1.7 GB).  An adapter without
+    ``stages`` is one stage: the whole tree through ``adapter.embed``.
+    This loop is the one place where reference weights reach the device."""
+    stages = getattr(adapter, "stages", None)
+    stages = stages(host_params) if stages else [(host_params, adapter.embed)]
     groups = {}
     for i, x in enumerate(pool):
         groups.setdefault((x.shape, str(x.dtype)), []).append(i)
+    parts = [keys[lo:lo + block] for keys in groups.values()
+             for lo in range(0, len(keys), block)]
+    acts = [np.stack([pool[i] for i in part]) for part in parts]
+    programs = {}
+    for tree, fn in stages:
+        if fn not in programs:
+            programs[fn] = jax.jit(lambda p, x, fn=fn: fn(p, x, quant=quant))
+        weights = jax.tree_util.tree_map(jnp.asarray, tree)
+        for k, x in enumerate(acts):  # in place: one copy of the pool's activations
+            acts[k] = np.asarray(programs[fn](weights, jnp.asarray(x)))
+        del weights  # freed before the next stage loads
     rows = [None] * len(pool)
-    for keys in groups.values():
-        for lo in range(0, len(keys), block):
-            part = keys[lo:lo + block]
-            emb = np.asarray(embed(params, jnp.asarray(np.stack([pool[i] for i in part]))))
-            for i, row in zip(part, emb):
-                rows[i] = row
+    for part, emb in zip(parts, acts):
+        for i, row in zip(part, emb):
+            rows[i] = row
     return np.stack(rows)
 
 
 def serve_numbers(ledger, ctx, cell, top_k):
     """Every answer of the window against the plain reference: the
-    reference embeds the pool in float32 (``embed_pool``) and scores it exactly
+    reference embeds the pool in float32 (``embed_pool``, its weights a
+    stage at a time) and scores it exactly
     against the whole gallery; each served neighbour's score
     (``score_gap``) and rank (``rank_gap``: how far the k-th served score
     lies under the reference's k-th) are held to that, and so is the
@@ -54,8 +78,7 @@ def serve_numbers(ledger, ctx, cell, top_k):
     (``recall_miss``, the mean over all answers)."""
     from benchmarks.reference import retrieval
 
-    params = jax.tree_util.tree_map(jnp.asarray, ctx["host_params"])
-    emb = embed_pool(cell.adapter, params, ctx["pool"],
+    emb = embed_pool(cell.adapter, ctx["host_params"], ctx["pool"],
                      cell.traffic.get("reference_block", 32))
     ref_s, ref_r = retrieval.exact_topk(emb, ctx["gallery"], top_k)
     rows_n = ctx["gallery"].shape[0]
@@ -179,4 +202,5 @@ def run(cell, devices, args, process_start) -> int:
     return run_train.finish(cell, args, ctx_m, metrics_all, traced, dev, checks,
                             correct, attempted=attempted,
                             failed=attempted - sum(len(t["ok"]) for t in tallies),
-                            extra={"numbers": numbers, "host_clock": metrics_all})
+                            extra={"numbers": numbers, "host_clock": metrics_all,
+                                   "window": host["window"]})

@@ -201,24 +201,53 @@ def ffn(p, x, q):
     return _mm(jax.nn.silu(_mm(x, p["gate"], q)) * _mm(x, p["up"], q), p["down"], q)
 
 
-def hidden_states(params, ids, quant=None):
-    """(B, T) token ids -> (B, T, hidden) after the final norm; the kinds
-    of layer are read off the tree's own names."""
+def block(p, x, quant=None):
+    """One block on (B, T, hidden): ``p`` holds its mixer under ``gdn`` or
+    ``attn``, its ``ffn`` and its ``norms``."""
     q = quantizer(quant)
-    x = params["embed"]["table"][ids].astype(jnp.float32)
-    i = 0
-    while f"block_{i}/ffn" in params:
-        b = f"block_{i}"
-        mix = gdn_mixer(params[f"{b}/gdn"], x, q) if f"{b}/gdn" in params \
-            else attn_mixer(params[f"{b}/attn"], x, q)
-        h = x + rms_norm(mix, params[f"{b}/norms"]["mixer"])
-        x = h + rms_norm(ffn(params[f"{b}/ffn"], h, q), params[f"{b}/norms"]["ffn"])
-        i += 1
-    return rms_norm(x, params["final_norm"]["weight"])
+    mix = gdn_mixer(p["gdn"], x, q) if "gdn" in p else attn_mixer(p["attn"], x, q)
+    h = x + rms_norm(mix, p["norms"]["mixer"])
+    return h + rms_norm(ffn(p["ffn"], h, q), p["norms"]["ffn"])
+
+
+def block_tree(params, i):
+    """Block ``i``'s leaves out of the flat plain layout, the kind of
+    layer read off the tree's own names; empty past the last block."""
+    return {part: params[f"block_{i}/{part}"] for part in ("gdn", "attn", "ffn", "norms")
+            if f"block_{i}/{part}" in params}
 
 
 def embed(params, ids, quant=None):
     """(B, T) int token ids, every row T true tokens -> (B, hidden)
-    unit-norm embeddings: the mean over the tokens, L2-normalized."""
-    x = jnp.mean(hidden_states(params, ids, quant), axis=1)
+    unit-norm embeddings: ``stages`` one after another on the whole tree."""
+    x = ids
+    for tree, fn in stages(params):
+        x = fn(tree, x, quant=quant)
+    return x
+
+
+def lookup(p, ids, quant=None):
+    """The first stage of ``stages``: (B, T) ids -> (B, T, hidden)."""
+    return p["table"][ids].astype(jnp.float32)
+
+
+def norm_pool(p, x, quant=None):
+    """The last stage of ``stages``: the final norm, then (B, T, hidden) ->
+    (B, hidden), the mean over the tokens, L2-normalized."""
+    x = jnp.mean(rms_norm(x, p["weight"]), axis=1)
     return x / jnp.sqrt(jnp.maximum(jnp.sum(x * x, -1, keepdims=True), 1e-12))
+
+
+def stages(params):
+    """The tower as an ordered list of (sub-tree, function): the table,
+    then one block a stage, then the final norm and the pooling, so that
+    ``run_serve.embed_pool`` holds one stage's float32 weights on the
+    device at a time (the table, 1.54 GB, or a block, 0.83 GB, at the
+    published widths) where the whole tree is 8.2 GB at eight layers.
+    Every block goes through the ONE function ``block``, so a kind of
+    layer compiles once a shape, not once a layer."""
+    trees = []
+    while tree := block_tree(params, len(trees)):
+        trees.append(tree)
+    return ([(params["embed"], lookup)] + [(tree, block) for tree in trees]
+            + [(params["final_norm"], norm_pool)])

@@ -6,7 +6,28 @@ import bench_path  # noqa: F401  (repo root on sys.path)
 import argparse
 import gc
 import json
+import os
 import time
+
+SCRATCH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "scratch_family")
+
+
+def grown_manifest():
+    """(manifest, entries): the committed manifest with the scratch
+    family's entries appended, as a later PR would append its own: a
+    configuration, two cells, a per-layer metric, and its cells' names on
+    the end-to-end lists."""
+    from benchmarks.harness import loader
+
+    man = loader.manifest()
+    with open(os.path.join(SCRATCH, "manifest_entries.json")) as f:
+        entries = json.load(f)
+    for key in ("configs", "workloads", "per_layer"):
+        man[key] += entries[key]
+    for m in man["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"] = m["workloads"] + entries["end_to_end"].get(m["name"], [])
+    return man, entries
 
 
 def toy_cell(name):

@@ -1,7 +1,8 @@
 """Adapter for the scratch ``scratch_mlp`` family of
 ``test_a_configuration_is_added_as_files``: the program's ``mlp`` trunk
 on a vector input, beside its plain reference.  The two parameter
-layouts are the same tree.  ``post_init`` leaves a group as it is."""
+layouts are the same tree.  ``post_init`` leaves a group as it is.  It
+answers the optional ``stages`` too: two stages, one layer each."""
 
 from __future__ import annotations
 
@@ -11,6 +12,7 @@ from benchmarks.harness import weights
 from benchmarks.reference import scratch_mlp as ref
 
 embed = ref.embed
+stages = ref.stages  # the optional question: the reference a layer at a time
 
 
 def input_shape(cfg):

@@ -146,9 +146,6 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files(tmp_path, monkeypatch):
     assert after == before
 
 
-SCRATCH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "scratch_family")
-
-
 def test_a_configuration_is_added_as_files(tmp_path, monkeypatch, capsys):
     """Copy the benchmark and add a scratch family that is no image trunk
     (the program's ``mlp`` on a vector input): one adapter, one reference,
@@ -156,8 +153,9 @@ def test_a_configuration_is_added_as_files(tmp_path, monkeypatch, capsys):
     entries.  Both cells run the whole rehearsal path to a well-formed
     last line with ``correct`` true, and no file that was there changed."""
     import benchmarks.adapters
+    import benchmarks.readers
     import benchmarks.reference
-    from bench_drive import drive
+    from bench_drive import SCRATCH, drive, grown_manifest
 
     root = tmp_path / "checkout"
     b = root / "benchmarks"
@@ -169,27 +167,28 @@ def test_a_configuration_is_added_as_files(tmp_path, monkeypatch, capsys):
     assert not added & set(before)
     shutil.copytree(SCRATCH, b, dirs_exist_ok=True,
                     ignore=shutil.ignore_patterns("__pycache__", "manifest_entries.json"))
-    man = loader.manifest()
-    entries = json.load(open(os.path.join(SCRATCH, "manifest_entries.json")))
-    man["configs"] += entries["configs"]
-    man["workloads"] += entries["workloads"]
-    for m in man["end_to_end"]:
-        if "workloads" in m:
-            m["workloads"] = m["workloads"] + entries["end_to_end"].get(m["name"], [])
+    man, entries = grown_manifest()
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     monkeypatch.setattr(loader, "ROOT", str(root))
     monkeypatch.setattr(loader, "BENCH_DIR", str(b))
     # the copy's packages: a PR's new modules would sit beside the old ones
     monkeypatch.setattr(benchmarks.adapters, "__path__", [str(b / "adapters")])
     monkeypatch.setattr(benchmarks.reference, "__path__", [str(b / "reference")])
+    monkeypatch.setattr(benchmarks.readers, "__path__", [str(b / "readers")])
     try:
         for w in entries["workloads"]:
             line = drive(w["name"], capsys)
             assert list(line)[-1] == "checks" and line["attempted"] > 0
             assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
             assert line["correct"] is True and line["failed"] == 0, line["checks"]
+        for m in entries["per_layer"]:  # its metric file and its reader, found by name
+            assert m["name"] in {x["name"] for x in loader.Cell(m["workloads"][0]).per_layer()}
+            spec = loader.metric_spec(m["name"])
+            assert loader.reader(spec["reader"])({"serve": {"in_window": 7}}, **spec["args"]) == 7
+            assert loader.reader(spec["reader"])({}, **spec["args"]) is None
     finally:
-        for name in ("benchmarks.adapters.scratch_mlp", "benchmarks.reference.scratch_mlp"):
+        for name in ("benchmarks.adapters.scratch_mlp", "benchmarks.reference.scratch_mlp",
+                     "benchmarks.readers.scratch_in_window"):
             sys.modules.pop(name, None)
     assert {p: p.read_bytes() for p in before} == before
 

@@ -12,7 +12,7 @@ import os
 import numpy as np
 import pytest
 
-from bench_drive import drive, toy_cell
+from bench_drive import drive, grown_manifest, toy_cell
 from benchmarks.adapters import olmo_hybrid as adapter
 from benchmarks.harness import compare, loader, run_serve, weights
 from benchmarks.reference import olmo_hybrid as ref
@@ -212,36 +212,108 @@ def test_gdn_scan_roofline_is_the_least_time_over_the_scans_time(tracer, cfg):
         assert region(ctx, **args) == pytest.approx(1e3 * seconds / 2), name
 
 
-# the accepted manifest (commit 8e26c23), less this family's entries
-ACCEPTED = "3b995595997cd620e09d28bcbe13af2c6d25709e5a374b90538d00ecb79b991b"
+# What PR 29 added and what was accepted before it, pinned BY NAME and in
+# every field; nothing that FOLLOWS them is pinned: a later PR appends
+# configurations, cells, metrics and its cells' names on an accepted
+# metric's ``workloads`` list, and edits no entry that is there.
+CONFIGS = ["googlenet_v1", "olmo_hybrid_7b_l8"]
+CELLS = ["googlenet_train", "googlenet_serve_ivf_rate", "googlenet_serve_flat_sat", CELL]
+END_TO_END = ["train_emb_per_s_chip", "serve_answers_per_s", "setup_s"]
+BEFORE = 37  # per-layer metrics accepted before PR 29
 METRICS = ["docs_step_mfu", "docs_busy_mfu", "docs_idle_share", "docs_compiles_in_window",
            "docs_batch_rows_mean", "docs_encode_ms_batch", "docs_search_ms_batch",
            "gdn_scan_ms_batch", "gdn_proj_conv_ms_batch", "full_attn_core_ms_batch",
            "ffn_ms_batch", "docs_encode_host_ms_batch", "docs_dispatch_host_ms_batch",
            "docs_pad_share", "gdn_scan_roofline"]
+# the digest of ``accepted_part``: the manifest of commit 84369ac (PR 32)
+# with the bound of serve_answers_per_s at 0.095 (PR 34, this commit)
+ACCEPTED = "b1f589a1368ff8bc523e2637353826d8f804d8685d57dd7c2a68016fee51779a"
 
 
-def test_the_manifest_gains_the_family_and_no_accepted_entry_changed():
-    man = loader.manifest()
-    assert [c["name"] for c in man["configs"]] == ["googlenet_v1", "olmo_hybrid_7b_l8"]
-    assert [w["name"] for w in man["workloads"]][3:] == [CELL]
-    assert [m["name"] for m in man["per_layer"]][37:] == METRICS
-    for m in man["per_layer"][37:]:
+def accepted_part(man):
+    """The accepted entries of ``man`` alone, each ``workloads`` list cut
+    to the accepted cells (a later cell's name on it is not a change)."""
+    def cut(m):
+        return dict(m, workloads=[w for w in m["workloads"] if w in CELLS]) \
+            if "workloads" in m else m
+
+    return {"command": man["command"], "paths": man["paths"],
+            "run_seconds": man["run_seconds"],
+            "configs": man["configs"][:len(CONFIGS)],
+            "workloads": man["workloads"][:len(CELLS)],
+            "end_to_end": [cut(m) for m in man["end_to_end"][:len(END_TO_END)]],
+            "per_layer": [cut(m) for m in man["per_layer"][:BEFORE + len(METRICS)]]}
+
+
+def check_pin(man):
+    part = accepted_part(man)
+    assert [c["name"] for c in part["configs"]] == CONFIGS
+    assert [w["name"] for w in part["workloads"]] == CELLS
+    assert [m["name"] for m in part["end_to_end"]] == END_TO_END
+    assert [m["name"] for m in part["per_layer"]][BEFORE:] == METRICS
+    for m in part["per_layer"][BEFORE:]:
         assert m["workloads"] == [CELL] and m["moves"] == "serve_answers_per_s"
+    digest = hashlib.sha256(json.dumps(part, sort_keys=True).encode()).hexdigest()
+    assert digest == ACCEPTED, digest
+
+
+def test_the_manifest_keeps_the_family_and_no_accepted_entry_changed():
+    man = loader.manifest()
+    check_pin(man)
     roof = {m["name"]: m for m in man["per_layer"]}["gdn_scan_roofline"]
     assert roof["unit"] == "%" and roof["source"] == "device_trace"
-    rest = json.loads(json.dumps(man))
-    for e in rest["end_to_end"]:
-        if "workloads" in e:
-            e["workloads"] = [w for w in e["workloads"] if w != CELL]
-    rest["configs"], rest["workloads"] = rest["configs"][:1], rest["workloads"][:3]
-    rest["per_layer"] = rest["per_layer"][:37]
-    digest = hashlib.sha256(json.dumps(rest, sort_keys=True).encode()).hexdigest()
-    assert digest == ACCEPTED
     cell = loader.Cell(CELL, man)
     assert {m["name"] for m in cell.end_to_end()} == {"serve_answers_per_s", "setup_s"}
-    assert {m["name"] for m in cell.per_layer()} == set(METRICS)
+    assert {m["name"] for m in cell.per_layer()} >= set(METRICS)
     mix = cell.traffic
     assert mix["loop"] == "open" and mix["zipf_s"] == 0 and mix["reference_block"] == 2
     assert mix["rate_qps"] == pytest.approx(mix["knee_factor"] * mix["knee_qps"], rel=0.02)
     assert mix["knee_factor"] == 1.5 and mix["sweep"] and mix["sweep_date"]
+
+
+def test_the_pin_holds_nothing_that_follows_the_accepted_entries():
+    """The scratch family's entries appended (a third configuration, two
+    more cells, a per-layer metric, longer end-to-end lists), a fourth
+    end-to-end metric and a later cell on an accepted per-layer metric's
+    list: the pin passes."""
+    man, entries = grown_manifest()
+    assert len(man["configs"]) == 3 and len(man["workloads"]) == len(CELLS) + 2
+    assert man["per_layer"][-1]["name"] == entries["per_layer"][0]["name"]
+    lists = {m["name"]: m["workloads"] for m in man["end_to_end"] if "workloads" in m}
+    assert lists["serve_answers_per_s"][-1] == "scratch_serve"
+    man["end_to_end"].append({"name": "scratch_p95_ms", "unit": "ms", "better": "lower",
+                              "bound": 0.05, "source": "host_clock",
+                              "workloads": ["scratch_serve"]})
+    man["per_layer"][0]["workloads"] = man["per_layer"][0]["workloads"] + ["scratch_serve"]
+    check_pin(man)
+
+
+def _set(path, value):
+    def change(man):
+        at = man
+        for key in path[:-1]:
+            at = at[key]
+        at[path[-1]] = value(at[path[-1]]) if callable(value) else value
+    return change
+
+
+@pytest.mark.parametrize("change", [
+    pytest.param(_set(("per_layer", 3, "unit"), "us"), id="a-unit"),
+    pytest.param(_set(("per_layer", BEFORE + 2, "moves"), "setup_s"), id="a-moves"),
+    pytest.param(_set(("per_layer", BEFORE - 1, "workloads"), lambda w: w[:-1]),
+                 id="a-per-layer-list-loses-a-cell"),
+    pytest.param(_set(("end_to_end", 1, "workloads"), lambda w: [x for x in w if x != CELL]),
+                 id="an-end-to-end-list-loses-a-cell"),
+    pytest.param(_set(("configs", 1, "reduced"), ["layer_types", "vocab_size"]),
+                 id="a-configs-reduced"),
+    pytest.param(_set(("end_to_end", 1, "bound"), 0.05), id="a-bound"),
+    pytest.param(_set(("workloads", 2, "traffic"), "closed_16"), id="a-cells-traffic"),
+    pytest.param(lambda man: man["per_layer"].insert(BEFORE, man["per_layer"].pop()),
+                 id="an-entry-put-among-the-accepted"),
+])
+def test_the_pin_fails_when_a_field_of_an_accepted_entry_changes(change):
+    man, _ = grown_manifest()
+    check_pin(man)
+    change(man)
+    with pytest.raises(AssertionError):
+        check_pin(man)

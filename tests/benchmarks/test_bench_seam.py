@@ -9,8 +9,10 @@ import bench_path  # noqa: F401  (repo root on sys.path)
 import hashlib
 import os
 import re
+import sys
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -158,3 +160,94 @@ def test_a_key_the_dataclass_lacks_fails_loudly(which):
     with pytest.raises(TypeError, match="token_budget"):
         serve_window.engine_config(mix["engine"])
         serve_window.batcher_config(mix)
+
+
+@pytest.fixture
+def scratch_mlp(monkeypatch):
+    """The scratch family's adapter and reference, imported from its files."""
+    import benchmarks.adapters
+    import benchmarks.reference
+    from bench_drive import SCRATCH
+
+    for pkg in (benchmarks.adapters, benchmarks.reference):
+        monkeypatch.setattr(pkg, "__path__", pkg.__path__ + [
+            os.path.join(SCRATCH, pkg.__name__.rpartition(".")[2])])
+    import importlib
+    adapter = importlib.import_module("benchmarks.adapters.scratch_mlp")
+    yield adapter
+    for name in ("benchmarks.adapters.scratch_mlp", "benchmarks.reference.scratch_mlp"):
+        sys.modules.pop(name, None)
+
+
+def _scratch_tree(adapter, hidden=(32,)):
+    cfg = {"input_dim": 24, "hidden": list(hidden), "embedding_dim": 16}
+    rng = np.random.default_rng(11)
+    tree = {name: {leaf: rng.normal(0, 0.3, shape).astype(np.float32)
+                   for leaf, shape in leaves.items()}
+            for name, leaves in adapter.shapes(cfg).items()}
+    pool = [rng.normal(size=shape).astype(np.float32)
+            for shape in [(24,)] * 5 + [(4, 6)] * 3 + [(2, 12)]]  # ragged: three groups
+    return tree, pool
+
+
+@pytest.mark.parametrize("quant", [None, "float8_e4m3fn", "bfloat16"])
+def test_a_streamed_reference_equals_the_whole_tree_embed(scratch_mlp, quant):
+    """Two stages (a layer each) over a ragged pool in blocks of two,
+    against the one-stage path an adapter without ``stages`` takes."""
+    tree, pool = _scratch_tree(scratch_mlp)
+    assert len(scratch_mlp.stages(tree)) == 2
+    whole = types.SimpleNamespace(embed=scratch_mlp.embed)
+    want = run_serve.embed_pool(whole, tree, pool, 2, quant=quant)
+    got = run_serve.embed_pool(scratch_mlp, tree, pool, 2, quant=quant)
+    assert got.shape == want.shape == (9, 16)
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
+    if quant:  # and the control is a different embedding, stage by stage too
+        plain = run_serve.embed_pool(scratch_mlp, tree, pool, 2)
+        assert np.max(np.abs(got - plain)) > 1e-4
+
+
+def test_a_stage_is_freed_before_the_next_loads(scratch_mlp):
+    """A tree larger than the device budget (here: more than any two of
+    its four stages together) is checked stage by stage: when the loop
+    asks for the next stage, no earlier stage's weights are alive."""
+    tree, pool = _scratch_tree(scratch_mlp, hidden=(256, 256, 256))
+    sizes = [sum(v.nbytes for v in leaves.values()) for leaves in tree.values()]
+    budget = max(sizes) + 16384  # one stage and the pool's activations
+    assert sum(sizes) > 2 * budget
+    seen = []
+
+    def stages(host_params):  # the adapter's own, looking at the device between stages
+        for sub, fn in scratch_mlp.stages(host_params):
+            seen.append(sum(a.nbytes for a in jax.live_arrays()))
+            yield sub, fn
+
+    watched = types.SimpleNamespace(embed=scratch_mlp.embed, stages=stages)
+    base = sum(a.nbytes for a in jax.live_arrays())
+    emb = run_serve.embed_pool(watched, tree, pool, 2)
+    assert len(seen) == 4 and emb.shape == (9, 16)
+    # no earlier stage's weights are alive (on the CPU the waiting
+    # activations, 9 rows x 256 floats, are live arrays too)
+    assert max(seen) - base <= 9 * 256 * 4 + 1024 < min(sizes)
+    assert sum(a.nbytes for a in jax.live_arrays()) - base < min(sizes)
+    whole = types.SimpleNamespace(embed=scratch_mlp.embed)
+    np.testing.assert_allclose(emb, run_serve.embed_pool(whole, tree, pool, 2), atol=1e-6)
+
+
+def test_the_token_tower_streams_a_block_a_stage():
+    """``olmo_hybrid``'s stages: the table, one block each (all through ONE
+    function, so a kind of layer compiles once a shape), the final norm
+    with the pooling; every leaf in exactly one stage; at rehearsal size
+    the streamed embedding is the whole tree's."""
+    cell = toy_cell("olmo_hybrid_serve_docs_backlog")
+    cfg, mix = cell.config, cell.traffic
+    tree = weights.widened(weights.make_params(cell.adapter, cfg, 5))
+    stages = cell.adapter.stages(tree)
+    assert len(stages) == len(cfg["layer_types"]) + 2
+    assert len({fn for _, fn in stages[1:-1]}) == 1 and "table" in stages[0][0]
+    count = lambda t: len(jax.tree_util.tree_leaves(t))
+    assert sum(count(sub) for sub, _ in stages) == count(tree)
+    pool = cell.adapter.query_pool(cfg, mix, 5)
+    whole = types.SimpleNamespace(embed=cell.adapter.embed)
+    got = run_serve.embed_pool(cell.adapter, tree, pool, mix["reference_block"])
+    want = run_serve.embed_pool(whole, tree, pool, mix["reference_block"])
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)

@@ -3,9 +3,15 @@
 import bench_path  # noqa: F401  (repo root on sys.path)
 
 import concurrent.futures
+import gc
+import json
+import os
 import threading
 import time
 
+import pytest
+
+from bench_drive import SCRATCH
 from benchmarks.harness import serve_window, traffic
 
 MIX = {"rate_qps": 200.0, "pool_images": 16, "zipf_s": 1.1, "canon_seed": 24}
@@ -102,3 +108,31 @@ def test_an_open_window_runs_to_its_last_answer():
     _, _, lat, late = _window(0.6, at=190)
     assert late["seconds"] > 1.4 and late["t1"] - late["t0"] == late["seconds"]
     assert len(lat) / late["seconds"] < 200 / 1.3
+
+
+@pytest.mark.parametrize("chunk", [16384, 64, 7])
+def test_a_closed_window_runs_to_the_clock_whatever_the_server_sustains(monkeypatch, chunk):
+    """The scratch family's closed mix (4 callers) against a server of
+    ~1,000 answers/s: the callers run until the clock and the window is
+    ``seconds`` and the drain of what was in flight, whether its records
+    were all made before it (one chunk of 16,384) or most of them inside
+    it (chunks of 64 or 7); every chunk is the same keys in an order of
+    its own."""
+    with open(os.path.join(SCRATCH, "traffic", "scratch_closed_4.json")) as f:
+        mix = json.load(f)
+    monkeypatch.setattr(serve_window, "_CHUNK", chunk)
+    server = StallingServer(0.0, at=-1)
+    ctx = {"pool": list(range(mix["pool_images"])), "qtracer": None}
+    seed = 2**31 + 11
+    try:
+        ledger, win = serve_window.closed_window(server, ctx, mix, seed, 0.4)
+    finally:
+        gc.unfreeze()
+        server.stop = True
+    assert 0.4 <= win["seconds"] < 0.5 and win["offered"] > 100 > mix["callers"]
+    assert len(ledger.answer) == len(ledger.key) == win["offered"] and None not in ledger.done
+    assert [a["id"] for a in ledger.answer] == list(range(win["offered"]))
+    chunks = [traffic.closed_loop(mix, seed + lo, chunk)
+              for lo in range(0, max(win["offered"], 2 * chunk), chunk)]
+    assert ledger.key == sum(chunks, [])[:win["offered"]]
+    assert sorted(chunks[0]) == sorted(chunks[1]) and (chunk < 64 or chunks[0] != chunks[1])
