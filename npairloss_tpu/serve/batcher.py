@@ -9,6 +9,22 @@ the queue is empty and that query's latency deadline has not passed,
 then hands the batch to ``dispatch_fn`` and distributes the per-query
 results.
 
+A batch runs in two phases: the dispatcher thread forms it and
+LAUNCHES it (``dispatch_fn``: parse, encode, the top-k's launch); its
+FINISH (the top-k's wait, the answers) and the reply follow.  Under a
+backlog -- a whole batch queued behind the one just launched, or
+another batch still out -- the dispatcher hands the launched batch to
+the replica's completion thread and goes straight back to the queue:
+batch n+1 forms and encodes while batch n's search runs on the
+device, and the cycle is the larger of the two phases, not their sum.
+The dispatcher waits at the hand-off while the completion thread still
+holds the previous batch: at most one batch lies between them, which
+bounds what is in flight and keeps answers in batch order.  Otherwise
+nothing would overlap the finish, and the dispatcher runs it itself,
+inside its dispatch, and replies: a lone request or a partial batch
+at an offered rate pays no hand-off.  No setting: each tier gets the
+overlap from the backlog it has.
+
 Admission is a BOUNDED queue, modeled on the training pipeline's
 ``DispatchController`` (pipeline/controller.py): when the engine falls
 behind, ``submit`` raises :class:`QueueFullError` immediately —
@@ -75,9 +91,11 @@ class BatcherConfig:
 class MicroBatcher:
     """``start()`` -> ``submit(item) -> Future`` -> ``close(drain=...)``.
 
-    ``dispatch_fn(items)`` receives the coalesced list and must return
-    one result per item, in order; an exception fails every future in
-    the batch (the server answers each with an error record).
+    ``dispatch_fn(items)`` receives the coalesced list and returns one
+    result per item, in order, or a call with no arguments that returns
+    them (the batch's second phase, run on the completion thread); an
+    exception in either fails every future in that batch and no other
+    (the server answers each with an error record).
     ``on_batch`` (optional) receives a stats dict per dispatched batch;
     ``on_pick`` (optional) receives each item the instant the dispatcher
     pulls it off the queue into the forming batch — the queue-wait/
@@ -91,14 +109,20 @@ class MicroBatcher:
     that four fill).  The rest is held back, in order, to head the next
     turns; the head of a turn always goes.
 
-    The dispatcher thread's time is spanned whole (obs.tracing):
+    Both threads' time is spanned whole (obs.tracing).  Dispatcher:
     ``serve/idle`` (waiting for a head: nothing was queued) ->
     ``serve/batch`` (forming the batch: ``size``, of which ``drained``
     co-riders were taken without waiting, ``held`` refused by ``fits``,
-    ``waited_ms`` under the deadline) -> ``serve/dispatch`` ->
-    ``serve/reply`` (the futures' done-callbacks run inline here).
-    Every span of one turn, the engine's included, carries the turn's
-    sequence number ``batch`` (and ``replica``).
+    ``waited_ms`` under the deadline; ``inflight`` 1 if the previous
+    batch was not yet answered as this one's launch began) ->
+    ``serve/dispatch`` (the launch) -> ``serve/handoff`` (the wait for
+    the completion thread).  Completion thread: ``serve/finish`` ->
+    ``serve/reply`` (the futures' done-callbacks run inline here).  A
+    batch the dispatcher finishes itself has its ``serve/finish``
+    inside its ``serve/dispatch`` and its ``serve/reply`` after it, on
+    the dispatcher thread.  Every span of one batch, the engine's
+    included, carries the batch's sequence number ``batch`` (and
+    ``replica``).
     """
 
     def __init__(
@@ -124,6 +148,12 @@ class MicroBatcher:
         self._tags = {"replica": name} if name else {}
         self._q: queue.Queue = queue.Queue(maxsize=cfg.max_queue)
         self._thread: Optional[threading.Thread] = None
+        self._finisher: Optional[threading.Thread] = None
+        # The hand-off slot: the launched batch the completion thread
+        # has not yet answered (None when it holds none, ``_STOP`` once
+        # the dispatcher is done).  The dispatcher fills it only empty.
+        self._slot: Any = None
+        self._slot_cv = threading.Condition()
         self._closed = threading.Event()
         # Serializes the closed-check + enqueue in submit() against
         # close() setting the flag: without it a racing submit can land
@@ -138,6 +168,11 @@ class MicroBatcher:
 
     def start(self) -> "MicroBatcher":
         if self._thread is None:
+            self._slot = None
+            self._finisher = threading.Thread(
+                target=self._finish_loop, name="serve-finisher",
+                daemon=True)
+            self._finisher.start()
             self._thread = threading.Thread(
                 target=self._loop, name="serve-batcher", daemon=True
             )
@@ -148,9 +183,10 @@ class MicroBatcher:
         """Stop accepting and shut the dispatcher down.
 
         ``drain=True`` (the SIGTERM contract): every already-admitted
-        query is dispatched and answered before the thread exits — zero
-        dropped in-flight queries.  ``drain=False`` fails pending
-        futures with :class:`QueueFullError` instead.
+        query is dispatched and answered before the threads exit — zero
+        dropped in-flight queries.  ``drain=False`` fails the queued
+        futures with :class:`QueueFullError` instead; a batch already
+        launched is answered either way.
         """
         with self._admit_lock:
             # Under the lock no submit is between its closed-check and
@@ -172,10 +208,15 @@ class MicroBatcher:
                         QueueFullError("batcher closed without drain")
                     )
         # The sentinel lands BEHIND any admitted work, so a draining
-        # close processes the whole queue first.
+        # close processes the whole queue first; the dispatcher hands
+        # it on behind its last batch, and the completion thread stops
+        # once that batch is answered.
         self._q.put(_STOP)
+        deadline = time.perf_counter() + timeout
         self._thread.join(timeout=timeout)
-        if self._thread.is_alive():
+        self._finisher.join(
+            timeout=max(deadline - time.perf_counter(), 0.0))
+        if self._thread.is_alive() or self._finisher.is_alive():
             log.error("batcher close: dispatcher did not drain in %.1fs",
                       timeout)
         else:
@@ -187,7 +228,7 @@ class MicroBatcher:
             # (gc.freeze) and the cycle would never be walked.
             self._dispatch_fn = self._on_batch = self._on_pick = None
             self._fits = None
-        self._thread = None
+        self._thread = self._finisher = None
 
     # -- admission ---------------------------------------------------------
 
@@ -217,14 +258,18 @@ class MicroBatcher:
 
     def _loop(self) -> None:
         seq = 0
-        while True:
-            seq += 1
-            with tracing.tagged(batch=seq, **self._tags):
-                if self._turn():
-                    return
+        try:
+            while True:
+                seq += 1
+                with tracing.tagged(batch=seq, **self._tags):
+                    if self._turn(seq):
+                        return
+        finally:
+            # Behind the last batch: the completion thread stops there.
+            self._hand_off(_STOP)
 
-    def _turn(self) -> bool:
-        """One head, its co-riders, their dispatch; True = stop."""
+    def _turn(self, seq: int) -> bool:
+        """One head, its co-riders, their launch; True = stop."""
         with tracing.span("serve/idle"):
             head = self._held.popleft() if self._held else self._q.get()
         if head is _STOP:
@@ -271,9 +316,12 @@ class MicroBatcher:
                 if late[0] is _STOP:
                     self._stopping = True
                     break
+            with self._slot_cv:
+                inflight = int(self._slot is not None)
             sp.note(size=len(batch), drained=drained,
-                    held=len(self._held), waited_ms=waited * 1e3)
-        self._run_batch(batch, drained)
+                    held=len(self._held), waited_ms=waited * 1e3,
+                    inflight=inflight)
+        self._launch(batch, drained, seq)
         # The sentinel was the queue's last entry: what is held back
         # is all that is left, and it heads the next turns.
         return self._stopping and not self._held
@@ -311,37 +359,120 @@ class MicroBatcher:
         self._held.extendleft(reversed(fresh[n:]))
         return n
 
-    def _run_batch(self, batch, drained: int) -> None:
+    def _launch(self, batch, drained: int, seq: int) -> None:
+        """The batch's first phase.  With another batch out, or a whole
+        batch queued behind this one (a backlog), the batch goes to the
+        completion thread and this thread goes back to the queue;
+        otherwise nothing would overlap it, and this thread finishes it
+        too, inside its dispatch, and replies."""
         items = [b[0] for b in batch]
         t0 = time.perf_counter()
         try:
             with tracing.span("serve/dispatch", size=len(items)):
-                results = self._dispatch_fn(items)
-            if len(results) != len(items):
-                raise RuntimeError(
-                    f"dispatch_fn returned {len(results)} results for "
-                    f"{len(items)} items"
-                )
+                job = _Launched(batch, self._dispatch_fn(items), drained,
+                                t0, seq, self.queue_depth)
+                overlap = self._overlaps()
+                if not overlap:
+                    results = self._finish(job)
         except Exception as e:  # noqa: BLE001 — fail the batch, not the loop
-            for _, fut, _ in batch:
-                if not fut.done():
-                    fut.set_exception(e)
-            log.error("batch dispatch failed (%d queries): %s",
-                      len(items), e)
+            self._fail(batch, e)
             return
+        if overlap:
+            with tracing.span("serve/handoff"):
+                self._hand_off(job)
+        else:
+            self._reply(job, results)
+
+    def _overlaps(self) -> bool:
+        """Whether the batch just launched goes to the completion
+        thread: another batch is out (answers keep their order), or a
+        whole batch waits behind it, whose forming and encoding then
+        runs while this one's search is on the device."""
+        with self._slot_cv:
+            out = self._slot is not None
+        return out or self.queue_depth >= self.cfg.max_batch
+
+    def _hand_off(self, job) -> None:
+        """Put ``job`` in the slot once the completion thread has
+        answered the batch before it."""
+        with self._slot_cv:
+            while self._slot is not None:
+                self._slot_cv.wait()
+            self._slot = job
+            self._slot_cv.notify_all()
+
+    def _finish_loop(self) -> None:
+        """The completion thread: each launched batch in turn, finished
+        and answered; the slot empties only then."""
+        while True:
+            with self._slot_cv:
+                while self._slot is None:
+                    self._slot_cv.wait()
+                job = self._slot
+            if job is _STOP:
+                return
+            try:
+                with tracing.tagged(batch=job.seq, **self._tags):
+                    try:
+                        results = self._finish(job)
+                    except Exception as e:  # noqa: BLE001 — fail the batch, not the loop
+                        self._fail(job.batch, e)
+                    else:
+                        self._reply(job, results)
+            finally:
+                with self._slot_cv:
+                    self._slot = None
+                    self._slot_cv.notify_all()
+
+    @staticmethod
+    def _finish(job: "_Launched") -> Sequence[Any]:
+        """The batch's second phase: one result per item."""
+        with tracing.span("serve/finish", size=len(job.batch)):
+            results = (job.pending() if callable(job.pending)
+                       else job.pending)
+        if len(results) != len(job.batch):
+            raise RuntimeError(
+                f"dispatch_fn returned {len(results)} results for "
+                f"{len(job.batch)} items"
+            )
+        return results
+
+    def _reply(self, job: "_Launched", results: Sequence[Any]) -> None:
+        batch = job.batch
         now = time.perf_counter()
         # Done-callbacks (a closed-loop caller's next submit) run
-        # inline on this thread: the time belongs to the dispatcher.
-        with tracing.span("serve/reply", size=len(items)):
+        # inline on the thread that answers: the completion thread's
+        # under a backlog, off the dispatcher's path.
+        with tracing.span("serve/reply", size=len(batch)):
             for (_, fut, _), res in zip(batch, results):
                 fut.set_result(res)
         self.batches += 1
-        self.dispatched += len(items)
+        self.dispatched += len(batch)
         if self._on_batch is not None:
             self._on_batch({
-                "size": len(items),
-                "dispatch_ms": (now - t0) * 1e3,
-                "oldest_wait_ms": (t0 - batch[0][2]) * 1e3,
-                "queue_depth": self.queue_depth,
-                "drained": drained,
+                "size": len(batch),
+                "dispatch_ms": (now - job.t0) * 1e3,
+                "oldest_wait_ms": (job.t0 - batch[0][2]) * 1e3,
+                "queue_depth": job.depth,
+                "drained": job.drained,
             })
+
+    @staticmethod
+    def _fail(batch, e: Exception) -> None:
+        for _, fut, _ in batch:
+            if not fut.done():
+                fut.set_exception(e)
+        log.error("batch dispatch failed (%d queries): %s", len(batch), e)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Launched:
+    """A batch between its phases: (item, future, submit time) triples,
+    what ``dispatch_fn`` returned, and how and when it was launched."""
+
+    batch: list
+    pending: Any
+    drained: int
+    t0: float
+    seq: int
+    depth: int

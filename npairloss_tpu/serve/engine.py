@@ -380,18 +380,105 @@ def _finalize_topk(s, r, k: int):
     return s, r
 
 
+@dataclasses.dataclass(frozen=True)
+class _Chunk:
+    """One bucket chunk of a launched top-k: the jitted call's device
+    outputs, the true rows among the bucket's, and the index's host
+    label / id arrays as they were read at launch."""
+
+    out: tuple
+    n: int
+    bucket: int
+    labels: np.ndarray
+    ids: np.ndarray
+    t_score: float  # perf_counter at launch
+
+
+class PendingSearch:
+    """A top-k on the device.  :meth:`collect` waits for it and maps the
+    winning rows to labels and ids through the arrays read AT LAUNCH: a
+    republish (``add()``, a swapped index) landing between the two
+    phases never maps one gallery's rows through another's labels.  It
+    touches no engine state, so it may run on another thread than the
+    launch while the engine launches the next batch."""
+
+    def __init__(self, chunks: Sequence[_Chunk], top_k: int,
+                 stages: Optional[Dict[str, Any]] = None):
+        self._chunks = list(chunks)
+        self._k = top_k
+        self._stages = stages
+
+    def collect(self) -> Dict[str, np.ndarray]:
+        """``{"scores", "rows", "labels", "ids"}``, each (B, top_k)."""
+        if not self._chunks:
+            k = self._k
+            return {
+                "scores": np.zeros((0, k), np.float32),
+                "rows": np.zeros((0, k), np.int32),
+                "labels": np.zeros((0, k), np.int32),
+                "ids": np.zeros((0, k), np.int64),
+            }
+        outs = [self._collect(c) for c in self._chunks]
+        return {key: np.concatenate([o[key] for o in outs])
+                for key in outs[0]}
+
+    def _collect(self, c: _Chunk) -> Dict[str, np.ndarray]:
+        n = c.n
+        with tracing.span("serve/topk", rows=n, bucket=c.bucket):
+            scores, rows, *scan = c.out
+            with tracing.span("serve/topk/wait"):
+                scores = np.asarray(scores)[:n]
+                rows = np.asarray(rows)[:n]
+                scan = [np.asarray(x) for x in scan]
+            if scan:
+                # The flat scan's own count of turns walked and merged
+                # (an IVF probe returns none): known only now, so a span
+                # of its own carries it into the tracer and the
+                # profiler's host plane.
+                turns, merged = (int(x) for x in scan[0])
+                with tracing.span("serve/topk/scan", scan_blocks=turns,
+                                  scan_blocks_merged=merged):
+                    pass
+        t_score1 = time.perf_counter()
+        with tracing.span("serve/gather", rows=n):
+            out = {
+                "scores": scores,
+                "rows": rows,
+                "labels": c.labels[rows],
+                "ids": c.ids[rows],
+            }
+        stages = self._stages
+        if stages is not None:
+            # Device scoring vs host gather (the qtrace score /
+            # topk_merge split), widened across bucket chunks; the
+            # score runs from the launch to the end of the wait.
+            stages["score_at"] = (
+                stages.get("score_at", (c.t_score,))[0], t_score1)
+            stages["gather_at"] = (
+                stages.get("gather_at", (t_score1,))[0],
+                time.perf_counter())
+        return out
+
+
 class QueryEngine:
     """Answers ``(B, D)`` query embeddings with the gallery's top-k.
 
     ``model``/``state`` (a Flax module + the ``restore_for_inference``
     tree) enable :meth:`encode` for raw-input queries; embedding-only
-    serving needs neither.  Every dispatch records ``serve/encode`` /
-    ``serve/topk`` spans (each with its ``/wait`` child around the
-    blocking device-to-host copy) and ``serve/gather`` through
+    serving needs neither.  A search runs in two phases: :meth:`launch`
+    (normalize, host-to-device, the jitted top-k's launch: span
+    ``serve/launch``) returns a :class:`PendingSearch` whose
+    ``collect()`` waits for the device (``serve/topk`` ⊃ ``/wait``) and
+    gathers labels and ids (``serve/gather``); :meth:`query` is the two
+    in one call.  ``serve/encode`` holds its ``/wait`` child around the
+    blocking device-to-host copy; spans go through
     ``obs.tracing.span``; ``telemetry`` is accepted for the callers
-    that pass it and reads nothing.  Thread-safety: dispatches are serialized by the
-    MicroBatcher (one dispatcher thread); the engine itself keeps no
-    per-call mutable state beyond the compile counters.
+    that pass it and reads nothing.  Thread-safety: launches and
+    encodes are serialized by the MicroBatcher (one dispatcher thread);
+    a pending search's ``collect`` may run on its completion thread at
+    the same time, and reads no engine state.  The engine keeps no
+    per-call mutable state beyond the compile and token counters, which
+    only the launching thread writes.
     """
 
     def __init__(
@@ -778,17 +865,33 @@ class QueryEngine:
     def query(
         self, embeddings: np.ndarray, normalize: bool = True,
         stages: Optional[Dict[str, float]] = None,
+        launched: Optional["PendingSearch"] = None,
     ) -> Dict[str, np.ndarray]:
-        """Top-k for ``(B, D)`` query embeddings.
+        """Top-k for ``(B, D)`` query embeddings: :meth:`launch`, then
+        its ``collect()``.  Returns ``{"scores", "rows", "labels",
+        "ids"}``, each (B, top_k).  ``launched`` is what an earlier
+        :meth:`launch` of these embeddings returned: only its collect
+        runs here (a two-phase caller launches on one thread and
+        collects on another)."""
+        if launched is None:
+            launched = self.launch(embeddings, normalize, stages)
+        return launched.collect()
+
+    def launch(
+        self, embeddings: np.ndarray, normalize: bool = True,
+        stages: Optional[Dict[str, float]] = None,
+    ) -> PendingSearch:
+        """Launch the top-k for ``(B, D)`` query embeddings; returns
+        the :class:`PendingSearch` that collects it.
 
         Pads B to the smallest bucket (chunking batches above the
-        largest), dispatches the jitted streamed/sharded top-k, and maps
-        winning gallery rows to labels/ids host-side.  Returns
-        ``{"scores", "rows", "labels", "ids"}``, each (B, top_k).
+        largest), copies to the device and launches the jitted
+        streamed/sharded top-k; the collect maps winning gallery rows
+        to labels/ids host-side.
 
         ``stages`` (optional) is a per-call accumulator the qtrace
-        layer passes in: WHEN the top-k call (host->device, launch,
-        device, device->host) and the host label/id gather ran lands in
+        layer passes in: WHEN the top-k (launch to the end of the
+        device-to-host copy) and the host label/id gather ran lands in
         ``score_at`` / ``gather_at``, each ``(start, end)`` in
         ``perf_counter`` seconds, first bucket chunk's start to last
         chunk's end.  Per-call (not an engine
@@ -802,29 +905,18 @@ class QueryEngine:
                 f"queries {q.shape} do not match gallery dim "
                 f"{self.index.dim}"
             )
-        if q.shape[0] == 0:
-            k = self.cfg.top_k
-            return {
-                "scores": np.zeros((0, k), np.float32),
-                "rows": np.zeros((0, k), np.int32),
-                "labels": np.zeros((0, k), np.int32),
-                "ids": np.zeros((0, k), np.int64),
-            }
-        if normalize:
-            q = l2_normalize_rows(q)
-        max_b = self.cfg.buckets[-1]
-        outs = [self._query_bucketed(q[i:i + max_b], stages=stages)
-                for i in range(0, q.shape[0], max_b)]
-        return {
-            key: np.concatenate([o[key] for o in outs])
-            for key in outs[0]
-        }
+        with tracing.span("serve/launch", rows=q.shape[0]):
+            if normalize and q.shape[0]:
+                q = l2_normalize_rows(q)
+            max_b = self.cfg.buckets[-1]
+            chunks = [self._query_bucketed(q[i:i + max_b])
+                      for i in range(0, q.shape[0], max_b)]
+        return PendingSearch(chunks, self.cfg.top_k, stages)
 
-    def _topk_call(self, bucket: int):
-        """(dispatch args, compile signature) for the current index
+    def _topk_call(self, bucket: int, idx):
+        """(dispatch args, compile signature) for ``idx``'s current
         state — read ONCE per dispatch, so an IVF republish (add())
         lands between dispatches, never inside one."""
-        idx = self.index
         if self._ivf:
             layout = idx.layout
             slab, scale = idx.scored_arrays(self.cfg.scoring,
@@ -839,10 +931,9 @@ class QueryEngine:
         return ((idx.emb, idx.labels, idx.valid),
                 ("topk", bucket, idx.padded_size, idx.dim))
 
-    def _query_bucketed(
-        self, q: np.ndarray,
-        stages: Optional[Dict[str, float]] = None,
-    ) -> Dict[str, np.ndarray]:
+    def _query_bucketed(self, q: np.ndarray) -> _Chunk:
+        """Pad one chunk to its bucket, copy it to the device and launch
+        the top-k on the index as it is read here, once."""
         n = q.shape[0]
         bucket = self.bucket_for(n)
         if bucket > n:
@@ -862,43 +953,15 @@ class QueryEngine:
         if self._ivf and self.warmed and \
                 failpoints.should_fire("serve.recall_drop"):
             q = -q
-        args, sig = self._topk_call(bucket)
+        args, sig = self._topk_call(bucket, idx)
+        labels, ids = idx._host_labels, idx.ids
         n_before = self._cache_size()
         t_score = time.perf_counter()
-        with tracing.span("serve/topk", rows=n, bucket=bucket):
-            scores, rows, *scan = self._topk_fn(jnp.asarray(q), *args)
-            with tracing.span("serve/topk/wait"):
-                scores = np.asarray(scores)[:n]
-                rows = np.asarray(rows)[:n]
-                scan = [np.asarray(x) for x in scan]
-            if scan:
-                # The flat scan's own count of turns walked and merged
-                # (an IVF probe returns none): known only now, so a span
-                # of its own carries it into the tracer and the
-                # profiler's host plane.
-                turns, merged = (int(x) for x in scan[0])
-                with tracing.span("serve/topk/scan", scan_blocks=turns,
-                                  scan_blocks_merged=merged):
-                    pass
-        t_score1 = time.perf_counter()
+        out = self._topk_fn(jnp.asarray(q), *args)
+        # A compile happens inside the call, before it returns: counted
+        # here, on the launching thread, never beside another launch.
         self._count_compiles(sig, n_before)
-        t_gather = time.perf_counter()
-        with tracing.span("serve/gather", rows=n):
-            out = {
-                "scores": scores,
-                "rows": rows,
-                "labels": idx._host_labels[rows],
-                "ids": idx.ids[rows],
-            }
-        if stages is not None:
-            # Device scoring vs host gather (the qtrace score /
-            # topk_merge split), widened across bucket chunks.
-            stages["score_at"] = (
-                stages.get("score_at", (t_score,))[0], t_score1)
-            stages["gather_at"] = (
-                stages.get("gather_at", (t_gather,))[0],
-                time.perf_counter())
-        return out
+        return _Chunk(tuple(out), n, bucket, labels, ids, t_score)
 
     # -- warmup ------------------------------------------------------------
 
@@ -923,8 +986,8 @@ class QueryEngine:
         t0 = _time.perf_counter()
         for bucket in self.cfg.buckets:
             with tracing.span("serve/warmup", bucket=bucket, kind="topk"):
-                self._query_bucketed(np.zeros((bucket, idx.dim),
-                                              np.float32))
+                self.query(np.zeros((bucket, idx.dim), np.float32),
+                           normalize=False)
             if self._encode_fn is not None and self.cfg.length_buckets:
                 budget = self.cfg.token_budget
                 for width in self.cfg.length_buckets:

@@ -230,6 +230,27 @@ def _token_input(value, longest: int, vocab: Optional[int]) -> np.ndarray:
     return row.astype(np.int32, copy=False)
 
 
+@dataclasses.dataclass
+class _LaunchedBatch:
+    """A batch between its two phases: its records, the answers known
+    at launch (per-record errors), the query rows with their item
+    positions and stacked (None when no record reached the search), the
+    engine's launched top-k (None from an engine stand-in without
+    ``launch``: it searches at the finish), and what the finish needs
+    to stamp and trace the answers."""
+
+    items: List[Dict[str, Any]]
+    answers: List[Optional[Dict[str, Any]]]
+    emb_rows: List[tuple]
+    rows: Optional[np.ndarray]
+    search: Any
+    stages: Optional[Dict[str, Any]]
+    qts: List[Any]
+    tstamp: Dict[str, Any]
+    entry: Any
+    engine: Any
+
+
 class RetrievalServer:
     """N replica engines + per-replica batchers + the request/answer
     protocol (one engine is the degenerate, pre-replica-tier shape)."""
@@ -390,9 +411,11 @@ class RetrievalServer:
         (docs/RESILIENCE.md) kills THIS replica: its in-flight batch —
         and every batch still queued on it — REROUTES to a surviving
         replica (zero client-visible errors), and the router stops
-        selecting it.  Only a whole-tier loss fails the work."""
+        selecting it.  Only a whole-tier loss fails the work.  The
+        callable launches the batch and returns its finish, which the
+        batcher's completion thread calls."""
 
-        def dispatch(items: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+        def dispatch(items: List[Dict[str, Any]]) -> Callable[[], List]:
             if not replica.alive:
                 return self._reroute(replica, items)
             if failpoints.should_fire("serve.replica_crash"):
@@ -401,14 +424,14 @@ class RetrievalServer:
                           "replica(s) remain — rerouting its work",
                           replica.name, self.replicaset.alive_count)
                 return self._reroute(replica, items)
-            return self._dispatch(items, engine=replica.engine,
-                                  replica=replica.name)
+            return self._launch(items, engine=replica.engine,
+                                replica=replica.name)
 
         return dispatch
 
     def _reroute(self, dead, items: List[Dict[str, Any]]
-                 ) -> List[Dict[str, Any]]:
-        """Dispatch a dead replica's batch on a surviving replica's
+                 ) -> Callable[[], List[Dict[str, Any]]]:
+        """Launch a dead replica's batch on a surviving replica's
         engine — the ``serve.replica_crash`` containment promise: a
         replica loss stays invisible to clients while ANY replica
         survives.  Runs on the dead replica's own dispatcher thread
@@ -436,8 +459,8 @@ class RetrievalServer:
             # marker count as the replica-crash evidence).
             self.qtrace.marker("crash_reroute", dead=dead.name,
                                target=target.name, queries=len(items))
-        return self._dispatch(items, engine=target.engine,
-                              replica=target.name)
+        return self._launch(items, engine=target.engine,
+                            replica=target.name)
 
     # -- telemetry ---------------------------------------------------------
 
@@ -671,21 +694,32 @@ class RetrievalServer:
                   engine: Optional[QueryEngine] = None,
                   replica: Optional[str] = None
                   ) -> List[Dict[str, Any]]:
-        """Batcher dispatch.  Single-tenant: straight through to the
-        core.  Tenant mode: a micro-batch may coalesce queries for
-        SEVERAL galleries (the batchers are shared — that is the
-        one-tier contract), so the batch splits by tenant id and each
-        group dispatches on its tenant's engine for THIS replica; the
-        answers reassemble in item order."""
+        """A batch's two phases in one call: launch, then finish."""
+        return self._launch(items, engine=engine, replica=replica)()
+
+    def _launch(self, items: List[Dict[str, Any]],
+                engine: Optional[QueryEngine] = None,
+                replica: Optional[str] = None
+                ) -> Callable[[], List[Dict[str, Any]]]:
+        """Batcher dispatch: launch the batch, return its finish (a
+        call with no arguments -> one answer per item, in order).
+        Single-tenant: straight through to the core.  Tenant mode: a
+        micro-batch may coalesce queries for SEVERAL galleries (the
+        batchers are shared — that is the one-tier contract), so the
+        batch splits by tenant id and each group launches on its
+        tenant's engine for THIS replica; the finish answers the groups
+        in turn and reassembles the answers in item order."""
         if not self.tenants:
-            return self._dispatch_core(items, engine=engine,
-                                       replica=replica)
+            return functools.partial(
+                self._finish_core,
+                self._launch_core(items, engine=engine, replica=replica))
         ridx = self._replica_idx.get(replica, 0)
         groups: Dict[Any, List[int]] = {}
         for i, rec in enumerate(items):
             tid = rec.get("tenant") if isinstance(rec, dict) else None
             groups.setdefault(tid, []).append(i)
         answers: List[Optional[Dict[str, Any]]] = [None] * len(items)
+        launched = []
         for tid, idxs in groups.items():
             entry = self.tenants.get(tid)
             if entry is None:
@@ -699,12 +733,17 @@ class RetrievalServer:
                 continue
             eng = entry.engines[ridx if ridx < len(entry.engines)
                                 else 0]
-            group = self._dispatch_core([items[i] for i in idxs],
-                                        engine=eng, replica=replica,
-                                        entry=entry)
-            for i, ans in zip(idxs, group):
-                answers[i] = ans
-        return answers
+            launched.append((idxs, self._launch_core(
+                [items[i] for i in idxs], engine=eng, replica=replica,
+                entry=entry)))
+
+        def finish() -> List[Dict[str, Any]]:
+            for idxs, pending in launched:
+                for i, ans in zip(idxs, self._finish_core(pending)):
+                    answers[i] = ans
+            return answers
+
+        return finish
 
     def _token_coriders(self, items) -> int:
         """The batcher's ``fits`` on a token tier: how many of ``items``,
@@ -740,17 +779,18 @@ class RetrievalServer:
                 best = k
         return best
 
-    def _dispatch_core(self, items: List[Dict[str, Any]],
-                       engine: Optional[QueryEngine] = None,
-                       replica: Optional[str] = None,
-                       entry=None) -> List[Dict[str, Any]]:
-        """Coalesced query records -> per-query answers.  A malformed
+    def _launch_core(self, items: List[Dict[str, Any]],
+                     engine: Optional[QueryEngine] = None,
+                     replica: Optional[str] = None,
+                     entry=None) -> "_LaunchedBatch":
+        """Coalesced query records -> a launched top-k; its answers come
+        from :meth:`_finish_core`.  A malformed
         record (missing field, wrong embedding shape, ragged input)
         answers ``{"id", "error"}`` WITHOUT failing its co-riders — one
         hostile client must not degrade unrelated traffic sharing the
         micro-batch.  Raw-'input' records encode as ONE stacked
         dispatch (that is the batcher's whole point), then merge with
-        the embedding records for one top-k dispatch.  ``entry`` scopes
+        the embedding records for one top-k launch.  ``entry`` scopes
         freshness stamps, the shadow offer, and the answers' ``tenant``
         key to one tenant (None = the single-tenant tier)."""
         from npairloss_tpu.serve.engine import ServeCompileError
@@ -770,7 +810,7 @@ class RetrievalServer:
             # ``batch_assemble`` ends here; everything from this point
             # to the answers — parse, encode, failpoint stalls, the
             # engine call — is the ``dispatch`` stage (score/topk_merge
-            # are split back out of it below).
+            # are split back out of it at the finish).
             self.qtrace.dispatch_begin(
                 qts, replica=replica, batch=tracing.tags().get("batch"))
         stages: Optional[Dict[str, Any]] = {} if qts else None
@@ -822,14 +862,37 @@ class RetrievalServer:
                 for i, _ in enc_rows:
                     answers[i] = {"id": items[i].get("id"), **tstamp,
                                   "error": str(e)}
-        t_asm = (0.0, 0.0)
+        rows = search = None
         if emb_rows:
-            batch = np.stack([x for _, x in emb_rows])
+            rows = np.stack([x for _, x in emb_rows])
             # Only thread the stage-clock dict through when tracing is
-            # live: engine stand-ins (tests, external adapters) need not
-            # grow the kwarg to serve an untraced tier.
-            out = (engine.query(batch) if stages is None
-                   else engine.query(batch, stages=stages))
+            # live, and launch only on an engine that has the split:
+            # engine stand-ins (tests, external adapters) need grow
+            # neither to serve an untraced tier.
+            launch = getattr(engine, "launch", None)
+            if launch is not None:
+                search = (launch(rows) if stages is None
+                          else launch(rows, stages=stages))
+        return _LaunchedBatch(items, answers, emb_rows, rows, search,
+                              stages, qts, tstamp, entry, engine)
+
+    def _finish_core(self, b: "_LaunchedBatch") -> List[Dict[str, Any]]:
+        """A launched batch -> per-query answers: the top-k's collect,
+        the answers' assembly, the shadow offer and the qtrace
+        ``dispatch`` stage's end."""
+        items, answers, emb_rows, stages, qts, tstamp, entry = (
+            b.items, b.answers, b.emb_rows, b.stages, b.qts, b.tstamp,
+            b.entry)
+        t_asm = (0.0, 0.0)
+        if b.rows is not None:
+            # The answer comes out of the engine's ``query``: it
+            # collects what the launch started.
+            if b.search is not None:
+                out = b.engine.query(b.rows, launched=b.search)
+            elif stages is None:
+                out = b.engine.query(b.rows)
+            else:
+                out = b.engine.query(b.rows, stages=stages)
             # Host-side answer assembly is merge work: it joins the
             # device top-K with labels/ids/freshness into the wire
             # shape, so it lands in ``topk_merge``, not dispatch self.
@@ -896,7 +959,7 @@ class RetrievalServer:
                 # Fused probe path: the score/merge clocks came out of
                 # ONE Pallas dispatch, so the trace wraps them in a
                 # probe_fused span (the stage vocabulary is unchanged).
-                fused=getattr(engine, "probe_impl", None) == "fused")
+                fused=getattr(b.engine, "probe_impl", None) == "fused")
         return answers
 
     # -- durable ingest (docs/RESILIENCE.md §Durability) --------------------

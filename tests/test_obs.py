@@ -533,9 +533,17 @@ def _encoding_server(wait_s=0.0, qtrace=None):
 
 
 def test_dispatch_spans_share_a_batch_and_encode_ends_on_the_host(no_tracer):
-    """Every span of one dispatcher turn carries the turn's ``batch``;
-    ``serve/encode`` holds its ``wait`` child and ends only when the
-    result is on the host (the old span closed at launch: ~0 ms here)."""
+    """Every span of one batch carries the batch's ``batch``, on
+    whichever thread runs it.  ``serve/dispatch`` holds ``serve/encode``
+    (with its ``wait`` child, ending only when the result is on the
+    host: the old span closed at launch, ~0 ms here) and
+    ``serve/launch``.  A lone request has nothing to overlap: the
+    dispatcher finishes it itself (``serve/finish``, holding
+    ``serve/topk`` with its ``wait`` and ``scan``, ``serve/gather`` and
+    ``serve/assemble``, inside its dispatch) and replies.  Under a
+    backlog the batch goes through ``serve/handoff`` to the completion
+    thread, which runs the same ``serve/finish`` and then
+    ``serve/reply``."""
     tracing = no_tracer
     tr = SpanTracer()
     tracing.install(tr)
@@ -550,23 +558,29 @@ def test_dispatch_spans_share_a_batch_and_encode_ends_on_the_host(no_tracer):
     events = tr.events_since(start)[0]
     turn = [e for e in events if e.get("args", {}).get("batch") == 1]
     by = {e["name"]: e for e in turn}
+    finish = ["serve/finish", "serve/topk", "serve/topk/wait",
+              "serve/topk/scan", "serve/gather", "serve/assemble"]
     assert set(by) == {
         "serve/idle", "serve/batch", "serve/dispatch", "serve/encode",
-        "serve/encode/wait", "serve/topk", "serve/topk/wait",
-        "serve/topk/scan", "serve/gather", "serve/assemble", "serve/reply"}
+        "serve/encode/wait", "serve/launch", "serve/reply", *finish}
+    assert len(turn) == len(by)  # one of each, no hand-off
+    assert len({e["tid"] for e in turn}) == 1  # all on the dispatcher
     assert all(e["args"]["replica"] == "r0" for e in turn)
     assert by["serve/dispatch"]["args"]["size"] == 1
+    # a lone request: nothing was out when its launch began
+    assert by["serve/batch"]["args"]["inflight"] == 0
     end = lambda e: e["ts"] + e["dur"]
     inside = lambda child, parent: (
         by[parent]["ts"] <= by[child]["ts"] and end(by[child]) <= end(by[parent]))
     assert inside("serve/encode/wait", "serve/encode")
     assert inside("serve/topk/wait", "serve/topk")
     assert inside("serve/topk/scan", "serve/topk")
+    for child in ("serve/topk", "serve/gather", "serve/assemble"):
+        assert inside(child, "serve/finish")
+    for child in ("serve/encode", "serve/launch", "serve/finish"):
+        assert inside(child, "serve/dispatch")
     scan = by["serve/topk/scan"]["args"]
     assert 1 <= scan["scan_blocks_merged"] <= scan["scan_blocks"]
-    for child in ("serve/encode", "serve/topk", "serve/gather",
-                  "serve/assemble"):
-        assert inside(child, "serve/dispatch")
     assert by["serve/encode/wait"]["dur"] >= 50e3  # the copy's wait, in us
     assert by["serve/encode"]["dur"] >= by["serve/encode/wait"]["dur"]
     # the thread's turn in order, nothing overlapping
@@ -575,6 +589,38 @@ def test_dispatch_spans_share_a_batch_and_encode_ends_on_the_host(no_tracer):
     # the turn after it (the drain's) has another number
     assert {e["args"]["batch"] for e in events
             if e["name"] == "serve/idle"} == {1, 2}
+
+    # a backlog: eight queued before the start, batches of four
+    emb, server = _encoding_server(wait_s=0.05)
+    start = tr.num_events
+    futs = [server.submit({"id": i, "input": emb[i].tolist()})[0]
+            for i in range(8)]
+    server.replicaset.start()
+    server.replicaset.close(drain=True)
+    assert [f.result(timeout=0)["neighbors"][0]["row"] for f in futs] == \
+        list(range(8))
+    events = tr.events_since(start)[0]
+    turn = [e for e in events if e.get("args", {}).get("batch") == 1]
+    by = {e["name"]: e for e in turn}
+    assert set(by) == {
+        "serve/idle", "serve/batch", "serve/dispatch", "serve/encode",
+        "serve/encode/wait", "serve/launch", "serve/handoff", "serve/reply",
+        *finish}
+    assert by["serve/dispatch"]["args"]["size"] == 4
+    launch = ["serve/idle", "serve/batch", "serve/dispatch", "serve/encode",
+              "serve/encode/wait", "serve/launch", "serve/handoff"]
+    assert len({by[n]["tid"] for n in launch}) == 1
+    assert len({by[n]["tid"] for n in finish + ["serve/reply"]}) == 1
+    assert by["serve/dispatch"]["tid"] != by["serve/finish"]["tid"]
+    for child in ("serve/encode", "serve/launch"):
+        assert inside(child, "serve/dispatch")
+    for child in ("serve/topk", "serve/gather", "serve/assemble"):
+        assert inside(child, "serve/finish")
+    # each thread's turn in order; the finish starts after its launch
+    for order in (["serve/idle", "serve/batch", "serve/dispatch",
+                   "serve/handoff"],
+                  ["serve/launch", "serve/finish", "serve/reply"]):
+        assert all(end(by[a]) <= by[b]["ts"] for a, b in zip(order, order[1:]))
 
 
 def test_profiler_session_holds_the_program_spans_in_the_host_plane(

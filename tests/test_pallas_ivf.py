@@ -215,7 +215,7 @@ def test_probe_impl_is_part_of_the_compile_signature(rng):
     for impl in ("scan", "fused"):
         eng = QueryEngine(ivf, EngineConfig(top_k=3, buckets=(4,),
                                             probe_impl=impl))
-        _, sig = eng._topk_call(4)
+        _, sig = eng._topk_call(4, eng.index)
         sigs.add(sig)
     assert len(sigs) == 2
 

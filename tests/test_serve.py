@@ -303,7 +303,7 @@ def test_batcher_backlog_goes_out_in_full_batches(delay_ms):
 
 def test_batcher_closed_loop_runs_full_batches():
     """Eight callers, each sending its next request from its answer's
-    done-callback (inline, on the dispatcher thread): every turn finds
+    done-callback (inline, on the completion thread): every turn finds
     at least ``max_batch`` queued, so but for the first batch and the
     tail every dispatch is full, with ``max_delay_ms`` long passed."""
     sizes, done, total = [], threading.Event(), 80
@@ -396,6 +396,228 @@ def test_batcher_counts_how_a_batch_formed(tracer):
         (1, 0), (3, 2), (2, 1)]
     # the depth a batch leaves behind counts what was held back
     assert [s["queue_depth"] for s in stats] == [5, 2, 0]
+
+
+# -- a second batch in flight -----------------------------------------------
+
+
+def _wait_for(pred, timeout=10.0):
+    """Until ``pred()`` holds (it is made true by another thread's
+    event, not by the clock); False after ``timeout``."""
+    deadline = time.perf_counter() + timeout
+    while not pred():
+        if time.perf_counter() > deadline:
+            return False
+        time.sleep(0.002)
+    return True
+
+
+class _HeldSearch:
+    """A float engine stand-in with the launch / collect split whose
+    collect waits for ``release``: a batch stays unanswered on the
+    completion thread while the dispatcher goes on."""
+
+    def __init__(self, dim=4, k=2):
+        self.index = type("Idx", (), {"dim": dim})()
+        self.k = k
+        self.launched = []
+        self.collecting = threading.Event()
+        self.release = threading.Event()
+
+    def launch(self, q, stages=None):
+        self.launched.append(q.shape[0])
+        n, k = q.shape[0], self.k
+
+        class Pending:
+            def collect(inner):
+                self.collecting.set()
+                assert self.release.wait(timeout=10.0)
+                rows = np.tile(np.arange(k), (n, 1))
+                return {"rows": rows, "ids": rows, "labels": rows,
+                        "scores": np.ones((n, k), np.float32)}
+
+        return Pending()
+
+    def query(self, q, normalize=True, stages=None, launched=None):
+        return (launched or self.launch(q)).collect()
+
+
+def test_second_batch_launches_while_the_first_is_unanswered(tracer):
+    """Under a backlog (a whole batch queued behind the one launched),
+    batch 1 goes to the completion thread, whose top-k is held; batch
+    2's dispatch runs to its hand-off while batch 1 is unanswered, and
+    its ``serve/batch`` says so (``inflight`` 1; batch 1 found nothing
+    out: 0)."""
+    engine = _HeldSearch()
+    server = RetrievalServer(
+        engine, BatcherConfig(max_batch=1, max_delay_ms=0.0, max_queue=16),
+        ServerConfig(metrics_window=0))
+    rec = lambda i: {"id": i, "embedding": [0.5] * 4}
+    # queued before the dispatcher starts: batch 2 waits behind batch 1
+    first, _ = server.submit(rec(0))
+    second, _ = server.submit(rec(1))
+    server.replicaset.start()
+    try:
+        assert engine.collecting.wait(timeout=5.0)
+        # batch 2 launched and went as far as the hand-off ...
+        assert _wait_for(lambda: any(
+            e["name"] == "serve/handoff" and e["args"]["batch"] == 1
+            for e in tracer.events_since(0)[0]) and len(engine.launched) == 2)
+        assert _wait_for(lambda: any(
+            e["name"] == "serve/dispatch" and e["args"]["batch"] == 2
+            for e in tracer.events_since(0)[0]))
+        # ... and neither batch is answered: the first is held
+        assert not first.done() and not second.done()
+        engine.release.set()
+        assert first.result(timeout=10.0)["id"] == 0
+        assert second.result(timeout=10.0)["id"] == 1
+    finally:
+        engine.release.set()
+        server.replicaset.close(drain=True)
+    events = tracer.events_since(0)[0]
+    batch = {e["args"]["batch"]: e["args"] for e in events
+             if e["name"] == "serve/batch"}
+    assert batch[1]["inflight"] == 0 and batch[2]["inflight"] == 1
+    by = {(e["name"], e.get("args", {}).get("batch")): e for e in events}
+    end = lambda e: e["ts"] + e["dur"]
+    # the dispatcher waited at batch 2's hand-off until batch 1 was answered
+    assert by[("serve/reply", 1)]["ts"] <= end(by[("serve/handoff", 2)])
+    assert by[("serve/dispatch", 2)]["tid"] != by[("serve/finish", 1)]["tid"]
+    assert by[("serve/finish", 1)]["tid"] == by[("serve/finish", 2)]["tid"]
+
+
+def test_a_republish_between_launch_and_collect_keeps_the_launch_index(rng):
+    """A pending search maps its rows through the labels and ids of the
+    index it was launched on: a republish that lands before the collect
+    (here the engine's index swapped for one with other labels and ids)
+    answers the NEXT search, never this one."""
+    emb, lab = make_gallery(rng, ids=4, per_id=4, dim=8)
+    first = GalleryIndex.build(emb, lab)
+    other = GalleryIndex.build(emb, lab + 100,
+                               ids=np.arange(len(lab), dtype=np.int64) + 5000)
+    engine = QueryEngine(first, EngineConfig(top_k=3, buckets=(4,)))
+    want = engine.query(emb[:3])
+    pending = engine.launch(emb[:3])
+    engine.index = other
+    got = pending.collect()
+    for key in ("rows", "scores", "labels", "ids"):
+        np.testing.assert_array_equal(got[key], want[key])
+    after = engine.query(emb[:3])
+    np.testing.assert_array_equal(after["labels"], want["labels"] + 100)
+    np.testing.assert_array_equal(after["ids"], want["ids"] + 5000)
+    # growing the launch's own index in between changes nothing either
+    engine.index = first
+    pending = engine.launch(emb[:3])
+    first.add(emb[:2], np.array([7, 7], np.int32))
+    got = pending.collect()
+    for key in ("rows", "scores", "labels", "ids"):
+        np.testing.assert_array_equal(got[key], want[key])
+
+
+def _two_phase_batcher(finish_gate=None, fail_on=None):
+    """A batcher, not started, whose ``dispatch_fn`` launches nothing
+    and returns the batch's finish: it waits for ``finish_gate`` (if
+    given) and raises for a batch holding ``fail_on``.  -> (batcher,
+    entered, finished)."""
+    entered, finished = threading.Event(), []
+
+    def dispatch(items):
+        def finish():
+            entered.set()
+            if finish_gate is not None:
+                assert finish_gate.wait(timeout=10.0)
+            if fail_on in items:
+                raise RuntimeError(f"finish failed on {fail_on}")
+            finished.append(list(items))
+            return [x * 10 for x in items]
+        return finish
+
+    b = MicroBatcher(dispatch, BatcherConfig(max_batch=1, max_delay_ms=0.0,
+                                             max_queue=16))
+    return b, entered, finished
+
+
+def test_draining_close_answers_the_batch_in_finish_and_the_one_behind_it():
+    """``close(drain=True)`` with one batch held in its finish on the
+    completion thread, the next launched and waiting at the hand-off
+    and a third queued: every future is answered, in batch order,
+    before the threads exit."""
+    gate = threading.Event()
+    b, entered, finished = _two_phase_batcher(finish_gate=gate)
+    futs = [b.submit(i) for i in (1, 2, 3)]  # a backlog before the start
+    b.start()
+    assert entered.wait(timeout=5.0)
+    assert _wait_for(lambda: b.queue_depth == 1)  # batch 2 launched
+    closer = threading.Thread(target=b.close, kwargs={"drain": True})
+    closer.start()
+    assert not any(f.done() for f in futs)
+    gate.set()
+    closer.join(timeout=10.0)
+    assert not closer.is_alive()
+    assert [f.result(timeout=0) for f in futs] == [10, 20, 30]
+    assert finished == [[1], [2], [3]]
+    assert b.batches == 3 and b.dispatched == 3
+
+
+def test_an_exception_in_finish_fails_that_batch_only():
+    """A finish that raises fails its own batch's futures; the batch
+    behind it is answered."""
+    b, _entered, _finished = _two_phase_batcher(fail_on=1)
+    bad, good = b.submit(1), b.submit(2)  # batch 1 goes to the completion thread
+    b.start()
+    try:
+        with pytest.raises(RuntimeError, match="finish failed on 1"):
+            bad.result(timeout=10.0)
+        assert good.result(timeout=10.0) == 20
+    finally:
+        b.close()
+    assert b.batches == 1 and b.dispatched == 1
+
+
+def test_two_phases_under_contention_lose_no_answer_or_count():
+    """Sixteen callers (more than cores), a short switch interval, and
+    batches that sometimes hand off and sometimes finish inline: every
+    future gets its own answer, and ``batches`` / ``dispatched`` count
+    every batch launched and every item, whichever thread replied."""
+    import sys
+
+    launched = []
+    lock = threading.Lock()
+
+    def dispatch(items):
+        with lock:
+            launched.append(len(items))
+        return lambda: [x * 2 for x in items]
+
+    b = MicroBatcher(dispatch, BatcherConfig(max_batch=4, max_delay_ms=0.0,
+                                             max_queue=4096)).start()
+    got, errors = {}, []
+
+    def caller(c):
+        try:
+            for i in range(100):
+                x = c * 1000 + i
+                got[x] = b.submit(x).result(timeout=30.0)
+        except Exception as e:  # noqa: BLE001 — surfaced below
+            errors.append(e)
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=caller, args=(c,))
+                   for c in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(prev)
+        b.close()
+    assert not errors
+    assert len(got) == 1600 and all(v == 2 * x for x, v in got.items())
+    assert b.dispatched == sum(launched) == 1600
+    assert b.batches == len(launched)
 
 
 @pytest.mark.parametrize("fits,want", [

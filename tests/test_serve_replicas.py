@@ -194,6 +194,36 @@ def test_replica_crash_delayed_arming_reroutes_late_batch(rng):
     assert s["queries"] == s["answered"] + s["errors"] + s["rejected"], s
 
 
+def test_replica_crash_reroutes_through_both_phases(rng, tracer):
+    """A backlog on one replica that crashes at its first dispatch: the
+    batch launches on the survivor's engine and is finished on the
+    crashed replica's own completion thread; every record is answered,
+    and every batch launched has its finish under the same ``batch``
+    and ``replica`` tags."""
+    emb, server = _tier(rng, n_replicas=2)
+    dead = server.replicaset.replicas[0]
+    futs = [dead.batcher.submit({"id": i, "embedding": emb[i].tolist()})
+            for i in range(8)]  # two batches of four, queued
+    try:
+        failpoints.arm("serve.replica_crash", times=1)
+        server.replicaset.start()
+        answers = [f.result(timeout=30.0) for f in futs]
+    finally:
+        failpoints.reset()
+        server.replicaset.close(drain=True)
+    assert not dead.alive and server.replicaset.alive_count == 1
+    assert [a["neighbors"][0]["row"] for a in answers] == list(range(8))
+    events = tracer.events_since(0)[0]
+    key = lambda e: (e["args"]["replica"], e["args"]["batch"])
+    launched = {key(e): e["tid"] for e in events
+                if e["name"] == "serve/dispatch"}
+    finished = {key(e): e["tid"] for e in events
+                if e["name"] == "serve/finish"}
+    assert launched.keys() == finished.keys() == {("r0", 1), ("r0", 2)}
+    # the first went to the completion thread: a batch queued behind it
+    assert launched[("r0", 1)] != finished[("r0", 1)]
+
+
 def test_dead_replica_drains_queued_batches_to_survivor(rng):
     """Work already queued on a crashed replica reroutes to a live
     replica instead of failing — queued batches survive the crash."""

@@ -287,6 +287,41 @@ def test_tenant_routing_answers_from_own_gallery(rng):
         server.replicaset.close(drain=True)
 
 
+def test_tenant_groups_of_one_batch_launch_together_then_finish(
+        rng, tracer):
+    """A batch that holds two tenants' records launches both groups
+    (one ``serve/launch`` each, inside the batch's dispatch) before its
+    finish collects either (one ``serve/topk`` each, inside the
+    finish); under a backlog that finish runs on the completion thread.
+    Every answer comes from its own tenant's gallery."""
+    server, embs = _tenant_server(["acme", "bcorp"])
+    records = [_q(tid, embs[tid], i, qid=f"{tid}{i}")
+               for i in range(4) for tid in ("acme", "bcorp")]
+    start = tracer.num_events  # past the warm-up's spans
+    # queued before the dispatcher starts: two batches of four
+    futs = [server.submit(rec)[0] for rec in records]
+    server.replicaset.start()
+    server.replicaset.close(drain=True)
+    for rec, fut in zip(records, futs):
+        ans = fut.result(timeout=0)
+        assert ans["id"] == rec["id"] and ans["tenant"] == rec["tenant"]
+        # the query IS its tenant's gallery row: the exact match
+        assert ans["neighbors"][0]["row"] == int(rec["id"][-1])
+        assert ans["neighbors"][0]["score"] == pytest.approx(1.0, abs=1e-5)
+    events = tracer.events_since(start)[0]
+    spans = lambda name: [e for e in events if e["name"] == name
+                          and e.get("args", {}).get("batch") == 1]
+    (dispatch,), (finish,) = spans("serve/dispatch"), spans("serve/finish")
+    end = lambda e: e["ts"] + e["dur"]
+    inside = lambda child, parent: (
+        parent["ts"] <= child["ts"] and end(child) <= end(parent))
+    launches, topks = spans("serve/launch"), spans("serve/topk")
+    assert len(launches) == 2 and all(inside(e, dispatch) for e in launches)
+    assert len(topks) == 2 and all(inside(e, finish) for e in topks)
+    assert end(dispatch) <= finish["ts"]
+    assert dispatch["tid"] != finish["tid"]
+
+
 def test_unknown_tenant_is_an_error_not_a_query(rng):
     server, embs = _tenant_server(["acme"])
     server.replicaset.start()
